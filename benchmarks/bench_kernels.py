@@ -16,8 +16,8 @@ numbers that matter:
   the aggregate.
 
 Interpret-mode Pallas is orders of magnitude slower than compiled XLA —
-these rows gate the *harness and kernels* on CPU; the compiled GPU/TPU
-lane is the documented manual run (README "Censusing real kernels").
+these rows gate the *harness and kernels* on CPU; the compiled TPU run is
+``chip_smoke.py`` (README "Running on a TPU").
 """
 
 from __future__ import annotations
@@ -101,7 +101,7 @@ def _site_rows(out: List[str], smoke: bool) -> None:
         inst = InstanceSpec(
             index=0, uid=f"kernel_variants-{site}-n{size}-s000",
             family="kernel_variants",
-            params={"site": site, "size": size, "seed": 0, "interpret": True},
+            params={"site": site, "size": size, "seed": 0},
         )
         flops, _, build = instance_entry(inst)
         timer = WallClockTimer(build())

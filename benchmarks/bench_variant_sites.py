@@ -59,8 +59,7 @@ def run(smoke: bool, out: List[str], ctx=None) -> None:
         # interpreted Pallas matmul is the slowest site: reduced budget
         matmul = prepare_site(
             matmul_blocks_site(m=512, k=512, n=512,
-                               blocks=((128, 128, 128), (256, 256, 256)),
-                               interpret=True)
+                               blocks=((128, 128, 128), (256, 256, 256)))
         )
         matmul.max_measurements = 9
         prepared.append(matmul)

@@ -4,7 +4,7 @@ A :class:`VariantSite` is the framework's unit of algorithm choice — the
 exact object the paper's methodology ranks. Every variant carries an
 analytic FLOP count, so the FLOPs-discriminant test applies directly:
 
-* ``attention_impl``     — reference / chunked (+ Pallas kernel on TPU):
+* ``attention_impl``     — reference / chunked:
   equal math; chunked wastes masked-block FLOPs, reference materialises the
   score matrix (memory). Neither FLOPs nor bytes alone predicts the winner
   across shapes — the paper's anomaly regime.
@@ -15,7 +15,8 @@ analytic FLOP count, so the FLOPs-discriminant test applies directly:
   ~E/top_k x the FLOPs but has no scatter/gather — FLOPs *should*
   discriminate; when it doesn't, that's a textbook anomaly.
 * ``ssd_chunk``          — Mamba-2 chunk length: equal leading-order FLOPs.
-* ``matmul_blocks``      — Pallas GEMM tile shapes: equal FLOPs exactly.
+* ``matmul_blocks``      — Pallas GEMM tile shapes: equal FLOPs exactly;
+  native on a TPU, interpreted elsewhere (:func:`pallas_interpret`).
 * matrix chains          — the paper's own site (repro.expressions).
 """
 
@@ -60,6 +61,22 @@ class VariantSite:
                 thunk()
             table[v.name] = thunk
         return table
+
+
+def pallas_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas workload built now runs in the interpreter: never
+    on a TPU, always elsewhere. Timing the interpreter on a TPU, or asking
+    for a native kernel on another backend, measures the wrong program, so
+    an explicit ``interpret`` that disagrees with the backend raises."""
+    backend = jax.default_backend()
+    expected = backend != "tpu"
+    if interpret is not None and bool(interpret) != expected:
+        mode = "interpret" if interpret else "native"
+        raise ValueError(
+            f"Pallas {mode} mode on the {backend!r} backend: kernels run "
+            "natively on a TPU and in the interpreter everywhere else"
+        )
+    return expected
 
 
 def _thunk(fn, *arrays):
@@ -206,11 +223,13 @@ def matmul_blocks_site(
     m: int = 1024, k: int = 1024, n: int = 1024,
     blocks: Sequence[tuple] = ((128, 128, 128), (256, 256, 256), (512, 512, 256)),
     dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> VariantSite:
     # from the defining module: the package-level name can be shadowed by
     # the like-named subpackage after a dotted import (see repro.kernels)
     from repro.kernels.matmul.ops import matmul
+
+    interpret = pallas_interpret(interpret)
 
     def inputs(seed: int):
         ks = jax.random.split(jax.random.PRNGKey(seed), 2)

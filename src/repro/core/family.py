@@ -323,9 +323,11 @@ def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
     size = int(size)
     if site == "matmul":
         # Pallas GEMM tile shapes (+ the XLA dot baseline): 2mkn exactly,
-        # for every tiling
+        # for every tiling. A TPU block's last two dims must be multiples
+        # of (8, 128) or the whole array, so tiles are 128/256/512 capped
+        # at the size, and a smaller size is one whole-array tile
         m = k = n = size
-        blocks = [(b, b, b) for b in (16, 32, 64) if b <= size] or [(size,) * 3]
+        blocks = [(b, b, b) for b in (128, 256, 512) if b <= size] or [(size,) * 3]
         names = [f"blocks_{bm}x{bn}x{bk}" for bm, bn, bk in blocks] + ["xla_dot"]
         return {
             "names": names,
@@ -384,9 +386,10 @@ class KernelVariantsFamily(AlgorithmFamily):
     math), so the whole instance sits in ``S_F`` and **every** rank
     difference is an anomaly the explainer must attribute. Metadata is
     jax-free; only building workloads imports jax — measured through the
-    ``wall_clock`` backend (``interpret`` mode on CPU, compiled on
-    GPU/TPU), while the deterministic backends exercise the same grid
-    through the synthetic cost hooks."""
+    ``wall_clock`` backend (Pallas compiled on a TPU, interpreted on any
+    other backend: :func:`~repro.autotune.variants.pallas_interpret`),
+    while the deterministic backends exercise the same grid through the
+    synthetic cost hooks."""
 
     name = "kernel_variants"
     description = (
@@ -399,7 +402,6 @@ class KernelVariantsFamily(AlgorithmFamily):
         sites = [str(x) for x in grid.get("sites", KERNEL_SITES)]
         sizes = [int(s) for s in grid.get("sizes", ())]
         per_size = int(grid.get("per_size", 1))
-        interpret = bool(grid.get("interpret", True))
         out: List[InstanceSpec] = []
         for site in sites:
             if site not in KERNEL_SITES:
@@ -413,8 +415,7 @@ class KernelVariantsFamily(AlgorithmFamily):
                         index=0,
                         uid=f"kernel_variants-{site}-n{size}-s{s:03d}",
                         family=self.name,
-                        params={"site": site, "size": size, "seed": s,
-                                "interpret": interpret},
+                        params={"site": site, "size": size, "seed": s},
                     ))
         return out
 
@@ -424,7 +425,6 @@ class KernelVariantsFamily(AlgorithmFamily):
             "sites": sites or list(KERNEL_SITES),
             "sizes": args.sizes,
             "per_size": args.per_size,
-            "interpret": not bool(getattr(args, "kernel_native", False)),
         }
 
     def entry(self, inst: InstanceSpec) -> Entry:
@@ -440,20 +440,21 @@ class KernelVariantsFamily(AlgorithmFamily):
         )
 
         def build_workloads() -> Dict[str, Callable[[], Any]]:
-            variant_site = self._build_site(site, cfg, bool(p.get("interpret", True)))
-            return variant_site.workloads(seed=int(p["seed"]), warmup=True)
+            return self.variant_site(p).workloads(seed=int(p["seed"]), warmup=True)
 
         meta = {"size": size, "dims": None, "kernels": kernels}
         return flops, meta, build_workloads
 
     @staticmethod
-    def _build_site(site: str, cfg: Mapping[str, Any], interpret: bool):
-        """The wrapped VariantSite (imports jax — workload build time only)."""
-        kw = cfg["site_kwargs"]
+    def variant_site(params: Mapping[str, Any]):
+        """The instance's wrapped VariantSite (imports jax: workload build
+        time only)."""
+        site = str(params["site"])
+        kw = _kernel_site_config(site, int(params["size"]))["site_kwargs"]
         if site == "matmul":
             from repro.autotune.variants import matmul_blocks_site
 
-            return matmul_blocks_site(interpret=interpret, **kw)
+            return matmul_blocks_site(**kw)
         if site == "attention":
             from repro.autotune.variants import attention_site
 

@@ -145,6 +145,14 @@ class MeasurementStore:
         return store
 
 
+def device_kind() -> str:
+    """The kind of JAX's default device (``jax.devices()[0].device_kind``):
+    the device a ``wall_clock`` measurement in this process runs on."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
 class Timer:
     """Protocol: measure(name) -> one execution time in seconds."""
 

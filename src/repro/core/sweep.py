@@ -67,7 +67,14 @@ from .discriminant import flops_discriminant_test
 from .engine import ExperimentEngine
 from .family import InstanceSpec, family_names, get_family
 from .faults import FaultPlan, InjectedFault, active_plan
-from .measure import CostModelTimer, NoiseProfile, SimulatedTimer, Timer, WallClockTimer
+from .measure import (
+    CostModelTimer,
+    NoiseProfile,
+    SimulatedTimer,
+    Timer,
+    WallClockTimer,
+    device_kind,
+)
 from .retry import STORE_IO_POLICY, with_retries
 from .scores import filter_candidates, initial_hypothesis_by_time
 from .session import MeasurementSession
@@ -75,7 +82,8 @@ from .session import MeasurementSession
 __all__ = [  # InstanceSpec re-exported: it moved to repro.core.family
     "BACKENDS", "InstanceSpec", "SweepSpec", "ShardStore", "StoreDamaged",
     "instance_entry", "build_timer", "build_sweep_session",
-    "record_from_session", "run_chunked_campaign", "run_shard",
+    "record_from_session", "census_device_kind", "run_chunked_campaign",
+    "run_shard",
     "merge_shards", "write_merged", "census_summary", "sweep_progress",
 ]
 
@@ -485,9 +493,11 @@ def record_from_session(session: MeasurementSession, spec: SweepSpec) -> Dict[st
         "relative_flops": {k: float(v) for k, v in disc.relative_flops.items()},
     }
     if spec.backend == "wall_clock":
-        # the WallClockTimer's chosen inner-repeat counts (the
-        # minimum-measurable-time guard) — real-time metadata, so only on
-        # the backend whose records are never byte-compared across resumes
+        # the device that measured the record and the WallClockTimer's
+        # chosen inner-repeat counts (the minimum-measurable-time guard) —
+        # real-time metadata, so only on the backend whose records are
+        # never byte-compared across resumes
+        record["device_kind"] = device_kind()
         repeats = getattr(session.timer, "inner_repeats", None)
         if repeats:
             record["inner_repeats"] = {
@@ -495,6 +505,26 @@ def record_from_session(session: MeasurementSession, spec: SweepSpec) -> Dict[st
                 if name in meta["flops"]
             }
     return record
+
+
+def census_device_kind(spec: SweepSpec, records: Iterable[Mapping[str, Any]]) -> str:
+    """The device kind that measured a ``wall_clock`` census, read from its
+    measured records ("" for the deterministic backends, which run on no
+    device). A census whose records name no kind, or several, is refused:
+    its ranks would not belong to one device."""
+    if spec.backend != "wall_clock":
+        return ""
+    kinds = {
+        str(r.get("device_kind", "")) for r in records
+        if r.get("provenance") != "predicted"
+    }
+    if len(kinds) != 1 or "" in kinds:
+        raise ValueError(
+            f"wall_clock census {spec.name!r} names device kinds "
+            f"{sorted(kinds)} in its records; it must be measured on one "
+            "kind of device, and every record must name it"
+        )
+    return kinds.pop()
 
 
 # -------------------------------------------------------------- the store ---

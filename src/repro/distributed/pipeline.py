@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.launch.compat import shard_map
-
 Pytree = Any
 
 
@@ -89,7 +87,7 @@ def pipeline_apply(
         gathered = jax.lax.all_gather(outputs, stage_axis)   # [S, M, mb...]
         return gathered[n_stages - 1]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

@@ -30,7 +30,14 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.faults import FaultPlan, active_plan
-from repro.core.measure import CostModelTimer, NoiseProfile, SimulatedTimer, Timer, WallClockTimer
+from repro.core.measure import (
+    CostModelTimer,
+    NoiseProfile,
+    SimulatedTimer,
+    Timer,
+    WallClockTimer,
+    device_kind,
+)
 from repro.core.session import MeasurementSession
 from repro.core.sweep import (
     LINE_CRC_MISMATCH,
@@ -47,7 +54,7 @@ from repro.core.sweep import (
     synthetic_instance_model,
 )
 from repro.core.types import DEFAULT_QUANTILE_RANGES, REPORT_QUANTILE_RANGE
-from repro.roofline.terms import MachineSpec, get_machine, synthetic_machine
+from repro.roofline.terms import MachineSpec, census_machine
 
 from .attribution import AlgorithmAttribution, attribute_algorithm
 from .calibrate import load_calibrated_machine
@@ -87,7 +94,8 @@ class ExplainSpec:
     chunk_size: int = 8
     save_every: int = 25
     #: MachineSpec registry name; empty = derive from the census backend
-    #: (synthetic machine for cost_model/simulated, cpu-1core for wall_clock)
+    #: (synthetic machine for cost_model/simulated, the measuring device's
+    #: machine for wall_clock)
     machine: str = ""
     #: path to a ``calibrate`` output file; overrides ``machine`` with the
     #: fitted dispatch/efficiency-curve spec
@@ -220,19 +228,21 @@ def shard_targets(espec: ExplainSpec, targets: Sequence[Mapping[str, Any]],
     return [r for i, r in enumerate(targets) if i % espec.n_shards == shard]
 
 
-def resolve_machine(espec: ExplainSpec, sweep_spec: SweepSpec) -> MachineSpec:
+def resolve_machine(
+    espec: ExplainSpec, sweep_spec: SweepSpec, record: Mapping[str, Any]
+) -> MachineSpec:
     """The roofline floor's hardware: a calibrated machine file first, then
-    an explicit registry pick, else derived from the census backend (the
-    synthetic machine IS the cost-model census's hardware — predictions of
-    flops/flop_rate make the recovered per-kernel efficiencies equal the
-    injected factors)."""
+    the census's machine (:func:`~repro.roofline.terms.census_machine`):
+    an explicit registry pick, the synthetic machine for the deterministic
+    backends (predictions of flops/flop_rate make the recovered per-kernel
+    efficiencies equal the injected factors), or the machine of the device
+    that measured the ``wall_clock`` record."""
     if espec.machine_file:
         return load_calibrated_machine(espec.machine_file)
-    if espec.machine:
-        return get_machine(espec.machine)
-    if sweep_spec.backend in ("cost_model", "simulated"):
-        return synthetic_machine(f"sweep:{sweep_spec.name}", sweep_spec.flop_rate)
-    return get_machine("cpu-1core")
+    _, machine = census_machine(
+        sweep_spec, espec.machine, str(record.get("device_kind", ""))
+    )
+    return machine
 
 
 def record_to_instance(sweep_spec: SweepSpec, record: Mapping[str, Any]) -> InstanceSpec:
@@ -382,7 +392,15 @@ def _wall_clock_workloads(
 ) -> Dict[str, Callable[[], Any]]:
     """Whole-algorithm workloads come from the instance builders (same
     inputs as the census measured); kernel segments get fresh isolated
-    jitted workloads."""
+    jitted workloads. They run only on the kind of device that measured
+    the record: anywhere else they would explain another machine."""
+    here = device_kind()
+    if record.get("device_kind") != here:
+        raise ValueError(
+            f"{record['uid']} was measured on {record.get('device_kind')!r} "
+            f"but this process runs on {here!r}; explain it on the device "
+            "that measured it"
+        )
     inst = record_to_instance(sweep_spec, record)
     out = _whole_algorithm_workloads(inst, involved)
     seed = int(record["index"])
@@ -406,7 +424,7 @@ def build_explain_session(
     names = _measurement_names(winner, loser, kernels)
     timer = _build_timer(espec, sweep_spec, record, (winner, loser), kernels,
                          all_kernels)
-    machine = resolve_machine(espec, sweep_spec)
+    machine = resolve_machine(espec, sweep_spec, record)
     shuffle_seed = int(
         np.random.default_rng(_entropy(espec, record, 13)).integers(0, 2**31 - 1)
     )

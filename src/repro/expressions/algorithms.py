@@ -51,6 +51,16 @@ def _execute_steps(
     return last
 
 
+def algorithm_fn(alg: ChainAlgorithm) -> Callable[..., jax.Array]:
+    """The algorithm as a pure function of ``M0..M_{n-1}`` — the program
+    a jitted workload runs."""
+
+    def fn(*mats: jax.Array) -> jax.Array:
+        return _execute_steps(alg.steps, {f"M{i}": m for i, m in enumerate(mats)})
+
+    return fn
+
+
 def build_algorithm_fn(
     alg: ChainAlgorithm,
     matrices: Sequence[jax.Array],
@@ -60,11 +70,7 @@ def build_algorithm_fn(
     operands = {f"M{i}": m for i, m in enumerate(matrices)}
 
     if jit:
-        def fn(*mats: jax.Array) -> jax.Array:
-            ops = {f"M{i}": m for i, m in enumerate(mats)}
-            return _execute_steps(alg.steps, ops)
-
-        jitted = jax.jit(fn)
+        jitted = jax.jit(algorithm_fn(alg))
         mats = tuple(matrices)
 
         def run() -> jax.Array:

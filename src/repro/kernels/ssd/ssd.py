@@ -33,17 +33,24 @@ def _ssd_kernel(x_ref, l_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     xb = x_ref[0].astype(jnp.float32)          # [Q, p]
-    ld = l_ref[0].astype(jnp.float32)          # [Q]
+    ld = l_ref[0].astype(jnp.float32)          # [1, Q]
     bm = b_ref[0].astype(jnp.float32)          # [Q, n]
     cm = c_ref[0].astype(jnp.float32)          # [Q, n]
 
-    cum = jnp.cumsum(ld)                       # [Q]
-    # intra-chunk decay matrix L[i, j] = exp(cum_i - cum_j), lower-tri
-    diff = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     ltri = ii >= jj
-    decay = jnp.where(ltri, jnp.exp(diff), 0.0)
+    # cumsum as a product with the lower-triangular ones matrix (the TPU
+    # kernel compiler has no cumsum), once as a column and once as a row;
+    # HIGHEST keeps f32 sums off the bf16 MXU passes, since exp() below
+    # amplifies their error
+    ones = jnp.where(ltri, 1.0, 0.0)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(ones, ld, nt, precision=hi)       # [Q, 1]
+    cum_row = jax.lax.dot_general(ld, ones, nt, precision=hi)   # [1, Q]
+    # intra-chunk decay matrix L[i, j] = exp(cum_i - cum_j), lower-tri
+    decay = jnp.where(ltri, jnp.exp(cum - cum_row), 0.0)
 
     cb = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -54,16 +61,16 @@ def _ssd_kernel(x_ref, l_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
     )                                          # [Q, p]
 
     state = state_ref[...]                     # [p, n]
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum) * jax.lax.dot_general(
         cm, state, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                          # [Q, p]
 
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    total = cum[chunk - 1]
-    decay_to_end = jnp.exp(total - cum)        # [Q]
+    total = jnp.sum(ld, axis=1, keepdims=True)  # [1, 1]
+    decay_to_end = jnp.exp(total - cum)        # [Q, 1]
     s_chunk = jax.lax.dot_general(
-        xb * decay_to_end[:, None], bm, (((0,), (0,)), ((), ())),
+        xb * decay_to_end, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                          # [p, n]
     state_ref[...] = state * jnp.exp(total) + s_chunk
@@ -86,12 +93,14 @@ def ssd_scan_kernel(
     nc = s // chunk
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    # logda rides as [bh, 1, s]: a (1, chunk) block of [bh, s] breaks the
+    # TPU rule that a block's last two dims be (8, 128)-divisible or whole
     return pl.pallas_call(
         kernel,
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, ic: (i, ic, 0)),
-            pl.BlockSpec((1, chunk), lambda i, ic: (i, ic)),
+            pl.BlockSpec((1, 1, chunk), lambda i, ic: (i, 0, ic)),
             pl.BlockSpec((1, chunk, n), lambda i, ic: (i, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, ic: (i, ic, 0)),
         ],
@@ -99,7 +108,7 @@ def ssd_scan_kernel(
         out_shape=jax.ShapeDtypeStruct((bh, s, p), xbar.dtype),
         scratch_shapes=[_vmem((p, n), jnp.float32)],
         interpret=interpret,
-    )(xbar, logda, b_mat, c_mat)
+    )(xbar, logda[:, None, :], b_mat, c_mat)
 
 
 def _vmem(shape, dtype):

@@ -20,8 +20,26 @@ owns its full argparse tree, and the umbrella just rebrands ``prog`` so
 
 from __future__ import annotations
 
+import os
 import sys
 from typing import Callable, List, Optional, Tuple
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: names none: a fixed directory inside the checkout, because the path is
+#: part of the cache's key and a directory that moves never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else in :data:`COMPILE_CACHE_DIR`.
+    JAX reads the variable when it is imported, so call this first; it
+    imports no jax itself. Returns the directory in use."""
+    return os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", COMPILE_CACHE_DIR)
 
 
 def _census_main(argv: List[str], prog: str) -> int:
@@ -94,6 +112,7 @@ def _usage() -> str:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    use_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         print(_usage())

@@ -50,7 +50,7 @@ from repro.explain.runner import (
     write_merged_explained,
 )
 from repro.launch.cliutil import add_fsck_args, deprecated_alias, fsck_command
-from repro.launch.sweep import _int_list, _worker_env
+from repro.launch.sweep import _int_list, _worker_env, refuse_shared_device
 
 
 def spec_path(out: str) -> str:
@@ -179,7 +179,7 @@ def cmd_run(args: argparse.Namespace) -> int:
     from repro.explain.runner import explain_targets
 
     espec = load_or_plan_spec(args, announce=False)
-    _, targets = explain_targets(espec)  # parse the census once
+    sweep_spec, targets = explain_targets(espec)  # parse the census once
     prog = explain_progress(espec, args.out, targets=targets)
     print(f"# explaining {prog['anomalies']} anomalies from {espec.census} "
           f"({espec.n_shards} shards)")
@@ -187,6 +187,8 @@ def cmd_run(args: argparse.Namespace) -> int:
         print("# census has no anomalies — nothing to explain")
         write_merged_explained(espec, args.out)
         return 0
+    if refuse_shared_device(sweep_spec.backend, args.workers, "--workers"):
+        return 2
     workers = max(1, min(args.workers, espec.n_shards))
     assignment = {
         w: [s for s in range(espec.n_shards) if s % workers == w]
@@ -281,7 +283,7 @@ def cmd_calibrate(args: argparse.Namespace) -> int:
         micro_points_wall_clock,
         synthetic_truth,
     )
-    from repro.roofline.terms import MachineSpec, get_machine
+    from repro.roofline.terms import MachineSpec, get_machine, machine_for_device
 
     if args.peak_flops:
         # a custom-peak spec is NOT the registry machine: only carry the
@@ -291,9 +293,14 @@ def cmd_calibrate(args: argparse.Namespace) -> int:
             peak_flops=args.peak_flops,
             hbm_bw=args.hbm_bw,
         )
+    elif args.machine is not None:
+        base = get_machine(args.machine)
+    elif args.backend == "wall_clock":
+        from repro.core.measure import device_kind
+
+        base = machine_for_device(device_kind())  # the device measured
     else:
-        base = get_machine(args.machine if args.machine is not None
-                           else "cpu-1core")
+        base = get_machine("cpu-1core")
     sizes = _int_list(args.sizes) if args.sizes else list(DEFAULT_SIZES)
     if args.backend == "wall_clock":
         points = micro_points_wall_clock(sizes, reps=args.reps, seed=args.seed)
@@ -384,7 +391,8 @@ def main(argv: Optional[List[str]] = None, prog: Optional[str] = None) -> int:
     p.add_argument("--out-file", required=True,
                    help="where to save the calibration JSON")
     p.add_argument("--machine", default=None,
-                   help="base MachineSpec registry name (default cpu-1core; "
+                   help="base MachineSpec registry name (default: the "
+                   "measuring device's machine, cpu-1core for synthetic; "
                    "with --peak-flops: the custom spec's name, default "
                    "'custom')")
     p.add_argument("--peak-flops", type=float, default=None,

@@ -17,20 +17,20 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from repro.launch.compat import make_mesh as _compat_make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(n_pods=2 if multi_pod else 1)
 
 
 def make_mesh(n_pods: int = 1, dp: int = 16, tp: int = 16):
-    """General mesh: (pod, data, model) or (data, model) when n_pods == 1."""
+    """General mesh: (pod, data, model) or (data, model) when n_pods == 1,
+    every axis sharded automatically."""
+    shape, axes = (dp, tp), ("data", "model")
     if n_pods > 1:
-        return _compat_make_mesh((n_pods, dp, tp), ("pod", "data", "model"))
-    return _compat_make_mesh((dp, tp), ("data", "model"))
+        shape, axes = (n_pods,) + shape, ("pod",) + axes
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh(tp: Optional[int] = None):
@@ -45,7 +45,7 @@ def make_host_mesh(tp: Optional[int] = None):
         while tp * 2 <= n and tp * 2 <= 8:
             tp *= 2
     dp = max(n // tp, 1)
-    return _compat_make_mesh((dp, tp), ("data", "model"))
+    return make_mesh(1, dp, tp)
 
 
 def describe(mesh) -> str:

@@ -71,6 +71,7 @@ class SweepQueue:
         self.out = out
         self.spec = SweepSpec.load(os.path.join(out, SWEEP_SPEC))
         self.n_shards = self.spec.n_shards
+        self.backend = self.spec.backend
 
     def shard_totals(self) -> List[int]:
         totals = [0] * self.n_shards
@@ -111,6 +112,7 @@ class ExplainQueue:
         self.n_shards = self.espec.n_shards
         #: (sweep spec, anomaly work list) — parsed once per host process
         self.census = explain_targets(self.espec)
+        self.backend = self.census[0].backend
 
     def shard_totals(self) -> List[int]:
         from repro.explain.runner import shard_targets
@@ -281,16 +283,27 @@ def cmd_work(args: argparse.Namespace) -> int:
         say=lambda msg: print(f"# {msg}", flush=True),
     )
     prog = queue.progress()
-    print(f"# {owner}: {prog['completed']}/{prog['total']} complete "
-          f"({'drained' if done else 'paused'})")
+    damaged = [] if done else [
+        s for s in range(queue.n_shards)
+        if not _shard_done(queue.out, s)
+        and ShardStore(queue.out, s).open(readonly=True).damaged
+    ]
+    state = "drained" if done else "damaged" if damaged else "paused"
+    print(f"# {owner}: {prog['completed']}/{prog['total']} complete ({state})")
+    if damaged:
+        print(f"# shards {damaged} are damaged and were left undrained; "
+              f"run: python -m repro fsck --out {args.out}", file=sys.stderr)
+        return 1
     return 0
 
 
 def cmd_run(args: argparse.Namespace) -> int:
     """Simulate N hosts locally: N ``work`` subprocesses over one store."""
-    from repro.launch.sweep import _worker_env
+    from repro.launch.sweep import _worker_env, refuse_shared_device
 
     queue = open_queue(args.out)
+    if refuse_shared_device(queue.backend, args.hosts, "--hosts"):
+        return 2
     hosts = max(1, args.hosts)
     procs: List[subprocess.Popen] = []
     for h in range(hosts):

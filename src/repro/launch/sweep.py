@@ -6,6 +6,7 @@ driving its shards through resumable ExperimentEngine campaigns
 anomaly rates by family and instance size (paper Figs. 5-7).
 
     # 220-instance default census, 4 workers, resumable under DIR
+    # (a wall_clock census takes one worker: one process per device)
     PYTHONPATH=src python -m repro census run --out DIR --workers 4
 
     # inspect / continue
@@ -90,10 +91,6 @@ def add_grid_args(p: argparse.ArgumentParser) -> None:
     g.add_argument("--kernel-sites", default="matmul,attention,ssd",
                    help="kernel_variants sites (comma list); only read when "
                    "--families includes kernel_variants")
-    g.add_argument("--kernel-native", action="store_true",
-                   help="run kernel_variants Pallas kernels compiled for the "
-                   "local accelerator instead of interpret mode (the manual "
-                   "GPU/TPU lane)")
     g.add_argument("--shards", type=int, default=8)
     g.add_argument("--backend", default="cost_model",
                    choices=["cost_model", "simulated", "wall_clock"])
@@ -252,8 +249,22 @@ def _worker_env() -> Dict[str, str]:
     return env
 
 
+def refuse_shared_device(backend: str, n: int, flag: str) -> bool:
+    """True (after saying why) when ``n`` worker processes would share one
+    device: each ``wall_clock`` worker takes the accelerator, which belongs
+    to one process at a time. The launchers' parents never import jax."""
+    if backend != "wall_clock" or n <= 1:
+        return False
+    print(f"# refused: {flag} {n} on a wall_clock store — each worker "
+          "would take the device, and a device belongs to one process; "
+          f"run with {flag} 1", file=sys.stderr)
+    return True
+
+
 def cmd_run(args: argparse.Namespace) -> int:
     spec = load_or_plan_spec(args)
+    if refuse_shared_device(spec.backend, args.workers, "--workers"):
+        return 2
     workers = max(1, min(args.workers, spec.n_shards))
     assignment = {
         w: [s for s in range(spec.n_shards) if s % workers == w]

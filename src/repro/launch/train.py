@@ -21,8 +21,7 @@ import jax
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_NAMES, get_config
 from repro.data import DataConfig, SyntheticLM
-from repro.launch.compat import make_mesh as compat_make_mesh
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import ForwardOptions, init_encdec_params, init_lm_params
 from repro.train.elastic import ElasticConfig, ElasticTrainer
 from repro.train.optimizer import AdamW, cosine_schedule
@@ -59,7 +58,7 @@ def main() -> None:
         # host-count -> dp width at smoke scale
         n_dev = len(jax.devices())
         dp = max(min(n_hosts, n_dev), 1)
-        return compat_make_mesh((dp, max(n_dev // dp, 1)), ("data", "model"))
+        return make_mesh(1, dp, max(n_dev // dp, 1))
 
     trainer = ElasticTrainer(
         cfg=cfg,

@@ -34,7 +34,9 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 from repro.core.scores import filter_candidates, min_flops_set, relative_flops
 from repro.explain.decompose import kernels_from_compact
 
-from .features import census_machine, instance_features
+from repro.roofline.terms import census_machine
+
+from .features import instance_features
 from .model import ModelDrift, RidgeModel
 
 #: relative tolerance for collapsing predicted times into one rank class
@@ -124,8 +126,9 @@ class ActivePredictor:
         spec: Any,
         threshold: Optional[float] = None,
         machine: str = "",
+        device_kind: str = "",
     ) -> None:
-        name, mspec = census_machine(spec, machine)
+        name, mspec = census_machine(spec, machine, device_kind)
         if model.machine != name:
             raise ModelDrift(
                 f"model was trained against machine {model.machine!r} but "
@@ -149,8 +152,9 @@ class ActivePredictor:
         spec: Any,
         threshold: Optional[float] = None,
         machine: str = "",
+        device_kind: str = "",
     ) -> "ActivePredictor":
-        return cls(RidgeModel.load(path), spec, threshold, machine)
+        return cls(RidgeModel.load(path), spec, threshold, machine, device_kind)
 
     # ------------------------------------------------------- prediction ---
 
@@ -260,8 +264,14 @@ class ActivePredictor:
 def census_gate(spec: Any, instances: Mapping[str, Any]) -> Callable[[str], Optional[Dict[str, Any]]]:
     """The uid-keyed gate :func:`repro.core.sweep.run_shard` installs when
     ``spec.predictor_model`` is set."""
+    kind = ""
+    if spec.backend == "wall_clock":
+        from repro.core.measure import device_kind
+
+        kind = device_kind()  # the device this census measures on
     predictor = ActivePredictor.open(
-        spec.predictor_model, spec, threshold=spec.predict_threshold
+        spec.predictor_model, spec, threshold=spec.predict_threshold,
+        device_kind=kind,
     )
     return lambda uid: predictor.gate(instances[uid])
 
@@ -279,9 +289,12 @@ def prediction_errors(
     Wall-clock records score the verdict agreement only (no stored
     times)."""
     from repro.core.family import InstanceSpec
-    from repro.core.sweep import synthetic_instance_model
+    from repro.core.sweep import census_device_kind, synthetic_instance_model
 
-    predictor = ActivePredictor(model, spec, threshold=0.0, machine=machine)
+    predictor = ActivePredictor(
+        model, spec, threshold=0.0, machine=machine,
+        device_kind="" if machine else census_device_kind(spec, records),
+    )
     rows: List[Dict[str, Any]] = []
     for rec in records:
         inst = InstanceSpec(

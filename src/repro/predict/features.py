@@ -25,7 +25,7 @@ import math
 from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
 from repro.explain.decompose import KernelSpec, kernels_from_record
-from repro.roofline.terms import MACHINES, MachineSpec, get_machine, synthetic_machine
+from repro.roofline.terms import MachineSpec, census_machine
 
 #: bump when the vector layout changes — serialized models embed it and
 #: refuse to load against a different extraction (see repro.predict.model)
@@ -51,22 +51,6 @@ _LOG_FLOOR = 1e-30
 
 def _log10(x: float) -> float:
     return math.log10(max(float(x), _LOG_FLOOR))
-
-
-def census_machine(spec: Any, machine: str = "") -> Tuple[str, MachineSpec]:
-    """(label, MachineSpec) a census's predictions are costed against —
-    the serving oracle's resolution rule: an explicit registry name wins,
-    deterministic backends get the census's own pure-compute synthetic
-    machine, wall clock falls back to the pinned host core."""
-    name = machine
-    if not name:
-        if spec.backend in ("cost_model", "simulated"):
-            name = f"sweep:{spec.name}"
-        else:
-            name = "cpu-1core"
-    if name in MACHINES:
-        return name, get_machine(name)
-    return name, synthetic_machine(name, spec.flop_rate)
 
 
 def kernel_features(
@@ -135,6 +119,8 @@ def training_rows(
     counted in ``n_skipped``; callers must surface the count."""
     from repro.core.sweep import synthetic_instance_model
 
+    if spec.backend == "wall_clock":
+        return [], [], [], len(records)
     _, mspec = census_machine(spec, machine)
     X: List[List[float]] = []
     y: List[float] = []

@@ -27,7 +27,9 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .features import FEATURE_NAMES, FEATURE_VERSION, census_machine, training_rows
+from repro.roofline.terms import census_machine
+
+from .features import FEATURE_NAMES, FEATURE_VERSION, training_rows
 
 #: residual sigma floor (log10 units): a perfectly-fit training set must
 #: not produce zero flip probabilities everywhere
@@ -184,7 +186,6 @@ def train_model(
     """Fit a :class:`RidgeModel` from a merged census: features + targets
     via :func:`repro.predict.features.training_rows`, machine label via
     the serving oracle's resolution rule."""
-    name, _ = census_machine(spec, machine)
     X, y, keys, n_skipped = training_rows(spec, records, machine)
     if not X:
         raise ValueError(
@@ -192,6 +193,7 @@ def train_model(
             "(no stored per-algorithm times) — train from a "
             "cost_model/simulated census"
         )
+    name, _ = census_machine(spec, machine)
     coef, intercept, sigma = fit_ridge(X, y, alpha)
     return RidgeModel(
         coef=coef,

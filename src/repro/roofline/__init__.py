@@ -5,11 +5,14 @@ from .terms import (
     DEFAULT_MACHINE,
     HBM_BW,
     ICI_BW,
+    DEVICE_MACHINES,
     MACHINES,
     PEAK_FLOPS,
     MachineSpec,
     RooflineTerms,
+    census_machine,
     get_machine,
+    machine_for_device,
     register_machine,
     synthetic_machine,
     terms_from_counts,
@@ -17,6 +20,7 @@ from .terms import (
 
 __all__ = [
     "DEFAULT_MACHINE",
+    "DEVICE_MACHINES",
     "HBM_BW",
     "HloCounts",
     "ICI_BW",
@@ -25,7 +29,9 @@ __all__ = [
     "PEAK_FLOPS",
     "RooflineTerms",
     "analyze",
+    "census_machine",
     "get_machine",
+    "machine_for_device",
     "parse_hlo",
     "register_machine",
     "synthetic_machine",

@@ -1,8 +1,8 @@
 """Roofline terms from the compiled dry-run artifact.
 
 Hardware is a selectable :class:`MachineSpec` (the :data:`MACHINES`
-registry), defaulting to TPU v5e-class — 197 TFLOP/s bf16 per chip,
-819 GB/s HBM bandwidth, ~50 GB/s/link ICI:
+registry), defaulting to one TPU v5e chip — 197 TFLOP/s bf16, 819 GB/s
+HBM bandwidth, 200 GB/s ICI:
 
     T_comp = HLO_FLOPs_per_device / peak_FLOPs
     T_mem  = HLO_bytes_per_device / HBM_bw
@@ -20,6 +20,7 @@ runs on arbitrary CPUs and on a *synthetic* machine (the deterministic
 cost-model backend), and the AnomalyExplainer needs per-kernel roofline
 floors there — :func:`synthetic_machine` derives a spec from the sweep's
 ``flop_rate``, and ``cpu-1core`` models a pinned BLAS-on-one-core host.
+:func:`census_machine` is the one rule that picks among them.
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-from typing import Dict, Mapping, Optional, Tuple
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,15 +114,24 @@ class MachineSpec:
                       if f.name in d})
 
 
-#: Selectable hardware registry. ``tpu-v5e`` keeps the historical constants
-#: (the module-level aliases below point at it); ``cpu-1core`` models the
-#: census host: one pinned core of a ~3 GHz x86 (16 f32 FLOP/cycle FMA
+#: Selectable hardware registry (the module-level aliases below point at
+#: ``tpu-v5e``). ``tpu-v5e`` is one chip as published in Google Cloud's
+#: "TPU v5e" documentation: 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: (200 GB/s) of chip-to-chip interconnect. ``cpu-1core`` models the census
+#: host: one pinned core of a ~3 GHz x86 (16 f32 FLOP/cycle FMA
 #: throughput, one DDR channel's worth of bandwidth, ~µs JAX dispatch).
 MACHINES: Dict[str, MachineSpec] = {
     "tpu-v5e": MachineSpec("tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
-                           ici_bw=50e9),
+                           ici_bw=200e9),
     "cpu-1core": MachineSpec("cpu-1core", peak_flops=5e10, hbm_bw=2e10,
                              dispatch_overhead_s=2e-6),
+}
+
+#: JAX's ``device_kind`` -> the registry machine whose roofline a
+#: ``wall_clock`` measurement on that device is costed against
+DEVICE_MACHINES: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+    "cpu": "cpu-1core",
 }
 
 DEFAULT_MACHINE = MACHINES["tpu-v5e"]
@@ -136,6 +146,43 @@ def get_machine(name: str) -> MachineSpec:
     if name not in MACHINES:
         raise KeyError(f"unknown machine {name!r}; one of {sorted(MACHINES)}")
     return MACHINES[name]
+
+
+def machine_for_device(device_kind: str) -> MachineSpec:
+    """The machine a device of this ``device_kind`` (as JAX reports it) is
+    costed against. A kind with no entry raises: a roofline floor borrowed
+    from another device would be silently wrong."""
+    if device_kind not in DEVICE_MACHINES:
+        raise KeyError(
+            f"no roofline machine for device kind {device_kind!r}; known "
+            f"kinds {sorted(DEVICE_MACHINES)} (add its published peaks)"
+        )
+    return MACHINES[DEVICE_MACHINES[device_kind]]
+
+
+def census_machine(
+    spec: Any, machine: str = "", device_kind: str = ""
+) -> Tuple[str, MachineSpec]:
+    """(label, MachineSpec) a census is costed against: an explicit
+    registry name wins (any other name is a pure-compute synthetic at the
+    census's ``flop_rate``); otherwise the deterministic backends get the
+    census's own synthetic machine, and ``wall_clock`` gets the machine of
+    the ``device_kind`` that measured it."""
+    if not machine:
+        if spec.backend in ("cost_model", "simulated"):
+            machine = f"sweep:{spec.name}"
+        elif not device_kind:
+            raise ValueError(
+                f"wall_clock census {spec.name!r} is costed against the "
+                "device that measured it: pass that device kind, or name "
+                "a machine"
+            )
+        else:
+            found = machine_for_device(device_kind)
+            return found.name, found
+    if machine in MACHINES:
+        return machine, MACHINES[machine]
+    return machine, synthetic_machine(machine, spec.flop_rate)
 
 
 def register_machine(spec: MachineSpec) -> MachineSpec:

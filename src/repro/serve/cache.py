@@ -106,7 +106,7 @@ class OracleCacheSpec:
     explain: str = ""
     #: MachineSpec registry name; empty = derive from the census backend
     #: (the explainer's rule: synthetic machine for cost_model/simulated,
-    #: cpu-1core for wall_clock)
+    #: the measuring device's machine for wall_clock)
     machine: str = ""
     #: optional trained cost model JSON (``repro predict train``): cache
     #: misses consult it before the analytic roofline and answer with

@@ -59,10 +59,12 @@ from repro.core.sweep import (
     SweepSpec,
     _record_line,
     build_sweep_session,
+    census_device_kind,
     instance_entry,
+    merge_shards,
     record_from_session,
 )
-from repro.roofline.terms import MACHINES, MachineSpec, get_machine, synthetic_machine
+from repro.roofline.terms import MachineSpec, census_machine
 
 from .cache import (
     CONFIDENCE_BUCKETED,
@@ -81,22 +83,19 @@ MODEL_REL_TOL = 0.02
 
 
 def default_machine_name(spec: OracleCacheSpec, sweep: SweepSpec) -> str:
-    """The machine label cache keys embed — the explainer's resolution
-    rule: explicit registry pick, else the census's synthetic machine for
-    deterministic backends, else the pinned-core host."""
-    if spec.machine:
-        return spec.machine
-    if sweep.backend in ("cost_model", "simulated"):
-        return f"sweep:{sweep.name}"
-    return "cpu-1core"
+    """The machine label cache keys embed — the census's machine
+    (:func:`~repro.roofline.terms.census_machine`); a ``wall_clock``
+    census names the device kind that measured it in its records."""
+    kind = ""
+    if not spec.machine and sweep.backend == "wall_clock":
+        kind = census_device_kind(sweep, merge_shards(sweep, spec.census))
+    return census_machine(sweep, spec.machine, kind)[0]
 
 
 def resolve_machine_spec(name: str, sweep: SweepSpec) -> MachineSpec:
     """The MachineSpec behind a machine label: registry entries by name,
     anything else modelled as the census's pure-compute synthetic."""
-    if name in MACHINES:
-        return get_machine(name)
-    return synthetic_machine(name, sweep.flop_rate)
+    return census_machine(sweep, name)[1]
 
 
 def _params_token(params: Mapping[str, Any]) -> str:
@@ -296,7 +295,7 @@ class RankingOracle:
 
                 self._predictor = ActivePredictor.open(
                     self.spec.model, self.census_spec, threshold=0.0,
-                    machine=self.spec.machine,
+                    machine=self.machine_name,
                 )
         return self._predictor
 
@@ -412,6 +411,7 @@ class OracleQueue:
         self.census_spec = SweepSpec.load(
             os.path.join(self.spec.census, "spec.json")
         )
+        self.backend = self.census_spec.backend
         self.machine_name = default_machine_name(self.spec, self.census_spec)
 
     def shard_totals(self) -> List[int]:

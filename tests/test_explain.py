@@ -38,6 +38,7 @@ from repro.roofline.terms import (
     PEAK_FLOPS,
     MachineSpec,
     get_machine,
+    machine_for_device,
     synthetic_machine,
 )
 
@@ -527,12 +528,28 @@ def test_explain_targets_and_progress(census, tmp_path):
 def test_resolve_machine_follows_backend(census):
     root, spec, _ = census
     espec = ExplainSpec(census=root)
-    m = resolve_machine(espec, spec)
+    m = resolve_machine(espec, spec, {})
     assert m.peak_flops == spec.flop_rate and m.hbm_bw == 0.0
     espec2 = ExplainSpec(census=root, machine="tpu-v5e")
-    assert resolve_machine(espec2, spec).name == "tpu-v5e"
+    assert resolve_machine(espec2, spec, {}).name == "tpu-v5e"
+    # wall clock: the machine of the device kind that measured the record
     wall = _census_spec(backend="wall_clock")
-    assert resolve_machine(espec, wall).name == "cpu-1core"
+    assert resolve_machine(espec, wall, {"device_kind": "cpu"}).name == "cpu-1core"
+    assert resolve_machine(
+        espec, wall, {"device_kind": "TPU v5 lite"}).name == "tpu-v5e"
+    with pytest.raises(ValueError, match="device"):
+        resolve_machine(espec, wall, {})
+
+
+def test_unknown_device_kind_raises():
+    """A device with no published peaks has no roofline: an error, never
+    another device's floor."""
+    assert machine_for_device("TPU v5 lite") is get_machine("tpu-v5e")
+    with pytest.raises(KeyError, match="abacus"):
+        machine_for_device("abacus")
+    wall = _census_spec(backend="wall_clock")
+    with pytest.raises(KeyError, match="abacus"):
+        resolve_machine(ExplainSpec(), wall, {"device_kind": "abacus"})
 
 
 def test_explain_summary_and_tables(census, tmp_path):

@@ -126,12 +126,11 @@ def test_golden_census_byte_identical(tmp_path):
 
 # ------------------------------------------------------- kernel_variants ---
 
-def _kv_inst(site, size, seed=0, interpret=True):
+def _kv_inst(site, size, seed=0):
     return InstanceSpec(
         index=0, uid=f"kernel_variants-{site}-n{size}-s{seed:03d}",
         family="kernel_variants",
-        params={"site": site, "size": size, "seed": seed,
-                "interpret": interpret},
+        params={"site": site, "size": size, "seed": seed},
     )
 
 
@@ -145,12 +144,26 @@ def test_kernel_variants_expansion():
         "kernel_variants-ssd-n32-s000", "kernel_variants-ssd-n32-s001",
         "kernel_variants-ssd-n64-s000", "kernel_variants-ssd-n64-s001",
     ]
-    assert all(i.params["interpret"] for i in rows)
+    # the Pallas mode follows the backend, so no row carries one
+    assert all(set(i.params) == {"site", "size", "seed"} for i in rows)
     with pytest.raises(ValueError, match="unknown kernel site"):
         fam.expand_grid({"sites": ["conv"], "sizes": [32]})
     with pytest.raises(ValueError, match="chunk lengths"):
         # 24 only divides by chunk 8 -> fewer than 2 ssd variants
         fam.expand_grid({"sites": ["ssd"], "sizes": [24]})
+
+
+@pytest.mark.parametrize("size,tiles", [
+    (64, ["blocks_64x64x64"]),
+    (256, ["blocks_128x128x128", "blocks_256x256x256"]),
+    (4096, ["blocks_128x128x128", "blocks_256x256x256", "blocks_512x512x512"]),
+])
+def test_matmul_site_tiles_are_tpu_legal(size, tiles):
+    """A TPU block's last two dims are multiples of (8, 128) or the whole
+    array: tiles of 128/256/512 capped at the size, else one whole-array
+    tile, always beside the XLA dot baseline."""
+    flops, _, _ = instance_entry(_kv_inst("matmul", size))
+    assert sorted(flops) == tiles + ["xla_dot"]
 
 
 def test_kernel_variants_flop_identical_by_construction():

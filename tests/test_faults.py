@@ -492,6 +492,26 @@ def test_drain_skips_damaged_shard_and_recovers_after_fsck(tmp_path):
     queue.merge()                                  # no refusal post-fsck
 
 
+def test_queue_work_exits_nonzero_on_damaged_shards(tmp_path, capsys):
+    """A drain that leaves damaged shards behind is a failure the
+    operator must see: ``queue work`` exits 1 and names fsck."""
+    from repro.launch.queue import main as queue_main
+
+    out = str(tmp_path)
+    spec = _plan_spec(out, n_shards=2)
+    run_shard(spec, out, 0)
+    store = ShardStore(out, 0)
+    with open(store.records_path, "r+b") as fh:
+        fh.seek(5)
+        fh.write(b"\x00")
+    os.remove(store.manifest_path)
+    assert queue_main(["work", "--out", out, "--poll", "0.01"]) == 1
+    captured = capsys.readouterr()
+    assert "(damaged)" in captured.out and "fsck" in captured.err
+    fsck_store(out)
+    assert queue_main(["work", "--out", out, "--poll", "0.01"]) == 0
+
+
 # ------------------------------------------------- merge crash resilience ---
 
 def test_killed_merge_leaves_no_torn_store_and_reruns_identical(tmp_path):

@@ -9,7 +9,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.distributed.pipeline import bubble_fraction, pipeline_apply  # noqa: E402
-from repro.launch.compat import make_mesh  # noqa: E402
 
 
 def _stage_fn(params, x):
@@ -18,7 +17,9 @@ def _stage_fn(params, x):
 
 def test_pipeline_matches_sequential():
     n_stages, m, mb, d = 4, 6, 2, 16
-    mesh = make_mesh((n_stages,), ("stage",))
+    mesh = jax.make_mesh(
+        (n_stages,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     params = {
         "w": jax.random.normal(ks[0], (n_stages, d, d)) / np.sqrt(d),

@@ -355,3 +355,27 @@ def test_shard_counts_falls_back_on_legacy_manifest(tmp_path):
     counts = shard_counts(ShardStore(str(tmp_path), 0))
     assert counts["done"] == 1
     assert counts["by_family"]["chain"]["done"] == 1
+
+
+# ------------------------------------------------ one process per device ---
+
+@pytest.mark.parametrize("surface,argv", [
+    ("sweep", ["run", "--workers", "2"]),
+    ("queue", ["run", "--hosts", "2"]),
+])
+def test_wall_clock_store_refuses_several_processes(tmp_path, capsys,
+                                                    surface, argv):
+    """Every wall_clock worker would take the device, which belongs to one
+    process: the launcher refuses before it starts a child."""
+    from repro.launch import queue as queue_cli
+    from repro.launch import sweep as sweep_cli
+
+    out = str(tmp_path)
+    SweepSpec(
+        name="wc", backend="wall_clock", n_shards=2,
+        families={"bilinear": {"sizes": [8], "per_size": 2}},
+    ).save(os.path.join(out, "spec.json"))
+    main = {"sweep": sweep_cli.main, "queue": queue_cli.main}[surface]
+    assert main(argv[:1] + ["--out", out] + argv[1:]) == 2
+    assert "one process" in capsys.readouterr().err
+    assert not [f for f in os.listdir(out) if f.startswith("shard-")]

@@ -1,8 +1,9 @@
 """VariantSite invariants for the sites the kernel_variants census family
 wraps: analytic FLOP counts cross-checked against the explainer's roofline
-kernel table, and variant-output equivalence in Pallas interpret mode on
-CPU (the wall-clock CI lane's correctness precondition — ranking variants
-that compute different things would be meaningless)."""
+kernel table, variant-output equivalence in Pallas interpret mode on CPU
+(the wall-clock CI lane's correctness precondition — ranking variants
+that compute different things would be meaningless), and the rule that
+ties the Pallas mode to the backend."""
 
 import numpy as np
 import pytest
@@ -20,8 +21,7 @@ def _outputs(site, seed=0):
 
 def test_matmul_site_flops_match_roofline_gemm():
     m, k, n = 48, 32, 64
-    site = matmul_blocks_site(m=m, k=k, n=n, blocks=[(16, 16, 16)],
-                              interpret=True)
+    site = matmul_blocks_site(m=m, k=k, n=n, blocks=[(16, 16, 16)])
     want = KernelSpec("gemm", (m, k, n)).flops  # the roofline table's 2mkn
     assert want == 2.0 * m * k * n
     for name, f in site.flops_table().items():
@@ -30,14 +30,26 @@ def test_matmul_site_flops_match_roofline_gemm():
 
 def test_matmul_variants_equivalent_interpret():
     site = matmul_blocks_site(m=32, k=32, n=32,
-                              blocks=[(16, 16, 16), (32, 32, 32)],
-                              interpret=True)
+                              blocks=[(16, 16, 16), (32, 32, 32)])
     outs = _outputs(site)
     assert set(outs) == {"blocks_16x16x16", "blocks_32x32x32", "xla_dot"}
     ref = outs["xla_dot"]
     for name, out in outs.items():
         np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4,
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", False), ("tpu", True)])
+def test_pallas_mode_mismatch_raises(monkeypatch, backend, interpret):
+    """Pallas runs natively on a TPU and interpreted elsewhere; a site
+    asked for the other mode would time the wrong program."""
+    from repro.autotune import variants
+
+    monkeypatch.setattr(variants.jax, "default_backend", lambda: backend)
+    assert variants.pallas_interpret() is (backend != "tpu")
+    with pytest.raises(ValueError, match="Pallas"):
+        matmul_blocks_site(m=32, k=32, n=32, blocks=[(32, 32, 32)],
+                           interpret=interpret)
 
 
 # -------------------------------------------------------------- attention ---
@@ -109,7 +121,7 @@ def test_family_workloads_are_site_workloads():
     inst = InstanceSpec(
         index=0, uid="kernel_variants-matmul-n32-s000",
         family="kernel_variants",
-        params={"site": "matmul", "size": 32, "seed": 0, "interpret": True},
+        params={"site": "matmul", "size": 32, "seed": 0},
     )
     flops, _, build = instance_entry(inst)
     wl = build()

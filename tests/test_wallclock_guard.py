@@ -66,8 +66,9 @@ def test_blocking_check_still_enforced():
 
 def test_wall_clock_census_record_surfaces_inner_repeats():
     """End to end through the sweep layer: a wall_clock census record on a
-    sub-floor workload family carries the chosen counts (and deterministic
-    backends never grow the field)."""
+    sub-floor workload family carries the chosen counts and the device
+    kind that measured it (and deterministic backends never grow either
+    field)."""
     from repro.core.sweep import SweepSpec, build_sweep_session, record_from_session
 
     spec = SweepSpec(
@@ -79,6 +80,7 @@ def test_wall_clock_census_record_surfaces_inner_repeats():
     while session.step():
         pass
     record = record_from_session(session, spec)
+    assert record["device_kind"] == "cpu"  # the device that measured it
     assert "inner_repeats" in record
     assert set(record["inner_repeats"]) == set(record["flops"])
     assert all(r >= 1 for r in record["inner_repeats"].values())
@@ -90,4 +92,6 @@ def test_wall_clock_census_record_surfaces_inner_repeats():
     session = build_sweep_session(det, det.expand()[0])
     while session.step():
         pass
-    assert "inner_repeats" not in record_from_session(session, det)
+    det_record = record_from_session(session, det)
+    assert "inner_repeats" not in det_record
+    assert "device_kind" not in det_record
