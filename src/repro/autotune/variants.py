@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import named
 from repro.models import ModelConfig
 from repro.models.flops import param_counts
 
@@ -80,6 +81,10 @@ def pallas_interpret(interpret: Optional[bool] = None) -> bool:
 
 
 def _thunk(fn, *arrays):
+    """``fn`` jitted, compiled and run once; ``fn``'s name names the program
+    (:func:`repro.core.spans.named`). jax caches the compiled program by
+    ``fn`` itself, so a function built once (``_xla_dot``) is traced once per
+    process, while a closure built per instance is traced per instance."""
     jitted = jax.jit(fn)
     jax.block_until_ready(jitted(*arrays))
 
@@ -87,6 +92,11 @@ def _thunk(fn, *arrays):
         return jax.block_until_ready(jitted(*arrays))
 
     return run
+
+
+#: XLA's dot as the program ``jit_xla_dot``, built once so that every
+#: instance's jit finds it compiled
+_xla_dot = named("xla_dot", jnp.dot)
 
 
 # ------------------------------------------------------- attention site ----
@@ -111,16 +121,24 @@ def attention_site(
     f_chunk = f_scores
 
     def ref_grouped(q, k, v):
-        return _thunk(lambda q, k, v: attention_reference(q, k, v, gqa="grouped"), q, k, v)
+        return _thunk(
+            named("reference_grouped",
+                  lambda q, k, v: attention_reference(q, k, v, gqa="grouped")),
+            q, k, v,
+        )
 
     def ref_broadcast(q, k, v):
-        return _thunk(lambda q, k, v: attention_reference(q, k, v, gqa="broadcast"), q, k, v)
+        return _thunk(
+            named("reference_broadcast",
+                  lambda q, k, v: attention_reference(q, k, v, gqa="broadcast")),
+            q, k, v,
+        )
 
     def chunked(q, k, v):
         return _thunk(
-            lambda q, k, v: attention_chunked(
+            named("chunked_flash", lambda q, k, v: attention_chunked(
                 q, k, v, q_block=min(256, s), kv_block=min(512, s)
-            ),
+            )),
             q, k, v,
         )
 
@@ -163,10 +181,10 @@ def moe_dispatch_site(
     f_dense = f_expert * e + 2.0 * tokens * d * e
 
     def gather(x):
-        return _thunk(lambda x: moe_gather(cfg, params, x)[0], x)
+        return _thunk(named("gather", lambda x: moe_gather(cfg, params, x)[0]), x)
 
     def dense(x):
-        return _thunk(lambda x: moe_dense(cfg, params, x)[0], x)
+        return _thunk(named("dense", lambda x: moe_dense(cfg, params, x)[0]), x)
 
     return VariantSite(
         name=f"moe_dispatch[T{tokens} E{e} k{top_k}]",
@@ -199,7 +217,8 @@ def ssd_chunk_site(
     def make(chunk):
         def build(x, dt, a_log, bm, cm):
             return _thunk(
-                lambda x, dt, a_log, bm, cm: ssd_chunked(x, dt, a_log, bm, cm, chunk)[0],
+                named(f"chunk_{chunk}", lambda x, dt, a_log, bm, cm:
+                      ssd_chunked(x, dt, a_log, bm, cm, chunk)[0]),
                 x, dt, a_log, bm, cm,
             )
         return build
@@ -255,7 +274,7 @@ def matmul_blocks_site(
                 {"tiles": (bm, bn, bk)})
         for bm, bn, bk in blocks
     ) + (
-        Variant("xla_dot", f, lambda a, b_: _thunk(jnp.dot, a, b_)),
+        Variant("xla_dot", f, lambda a, b_: _thunk(_xla_dot, a, b_)),
     )
     return VariantSite(
         name=f"matmul[{m}x{k}x{n}]", variants=variants, make_inputs=inputs

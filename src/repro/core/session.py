@@ -25,7 +25,6 @@ sessions as one campaign.
 from __future__ import annotations
 
 import math
-import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
@@ -40,6 +39,7 @@ from .measure import (
     timer_from_dict,
     timer_to_dict,
 )
+from .spans import span
 from .types import (
     DEFAULT_QUANTILE_RANGES,
     REPORT_QUANTILE_RANGE,
@@ -234,7 +234,8 @@ class MeasurementSession:
         return self._qtable
 
     def _mean_ranks(self) -> MeanRankResult:
-        """One Procedure-3 pass over the current store, timed.
+        """One Procedure-3 pass over the current store, timed by the
+        ``session.analyse`` span.
 
         The vectorized path (default) flows the batched quantile table
         through every Procedure-2 sort of the ladder; ``vectorized=False``
@@ -242,26 +243,26 @@ class MeasurementSession:
         ``np.percentile`` pair per comparison) bit-for-bit — the golden
         tests hold the two paths equal.
         """
-        t0 = time.perf_counter()
-        if self._vectorized:
-            mr = mean_ranks(
-                self._order,
-                None,
-                quantile_ranges=self.quantile_ranges,
-                report_range=self.report_range,
-                tie_break=self.tie_break,
-                table=self._table(),
-            )
-        else:
-            mr = mean_ranks(
-                self._order,
-                self._store.as_mapping(),
-                quantile_ranges=self.quantile_ranges,
-                report_range=self.report_range,
-                tie_break=self.tie_break,
-                memoize=False,
-            )
-        self._analysis_seconds.append(time.perf_counter() - t0)
+        with span("session.analyse", "analyse_s", uid=self.name) as timed:
+            if self._vectorized:
+                mr = mean_ranks(
+                    self._order,
+                    None,
+                    quantile_ranges=self.quantile_ranges,
+                    report_range=self.report_range,
+                    tie_break=self.tie_break,
+                    table=self._table(),
+                )
+            else:
+                mr = mean_ranks(
+                    self._order,
+                    self._store.as_mapping(),
+                    quantile_ranges=self.quantile_ranges,
+                    report_range=self.report_range,
+                    tie_break=self.tie_break,
+                    memoize=False,
+                )
+        self._analysis_seconds.append(timed.seconds)
         return mr
 
     # ------------------------------------------------------------- loop ---
@@ -278,10 +279,11 @@ class MeasurementSession:
             return None
         snap = self._timer.snapshot()
         try:
-            batch = [
-                (name, self._timer.measure_many(name, self.m_per_iteration))
-                for name in self._order
-            ]
+            with span("session.sample", "sample_s", uid=self.name):
+                batch = [
+                    (name, self._timer.measure_many(name, self.m_per_iteration))
+                    for name in self._order
+                ]
         except BaseException:
             self._timer.restore(snap)
             raise

@@ -56,7 +56,6 @@ import dataclasses
 import json
 import math
 import os
-import time
 import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
@@ -78,6 +77,7 @@ from .measure import (
 from .retry import STORE_IO_POLICY, with_retries
 from .scores import filter_candidates, initial_hypothesis_by_time
 from .session import MeasurementSession
+from .spans import collect, span
 
 __all__ = [  # InstanceSpec re-exported: it moved to repro.core.family
     "BACKENDS", "InstanceSpec", "SweepSpec", "ShardStore", "StoreDamaged",
@@ -385,7 +385,8 @@ def build_timer(spec: SweepSpec, inst: InstanceSpec, flops: Mapping[str, float],
                 kernel_counts: Optional[Mapping[str, int]] = None) -> Timer:
     """The instance's measurement backend, fully derived from the spec."""
     if spec.backend == "wall_clock":
-        return WallClockTimer(build_workloads())
+        with span("session.warmup", "warmup_s", uid=inst.uid):
+            return WallClockTimer(build_workloads())
     model = synthetic_instance_model(spec, inst.index, flops, kernel_counts)
     noise_seed = np.random.default_rng(
         _instance_entropy(spec, inst, 2)
@@ -415,11 +416,12 @@ def build_sweep_session(spec: SweepSpec, inst: InstanceSpec) -> MeasurementSessi
     flops, desc, build_workloads = instance_entry(inst)
     kernel_counts = {alg: len(ks) for alg, ks in desc["kernels"].items()}
     timer = build_timer(spec, inst, flops, build_workloads, kernel_counts)
-    single = {name: timer.measure(name) for name in flops}
-    cand = filter_candidates(
-        flops, single,
-        rt_threshold=spec.rt_threshold, flops_rel_tol=spec.flops_rel_tol,
-    )
+    with span("session.first", "first_s", uid=inst.uid):
+        single = {name: timer.measure(name) for name in flops}
+        cand = filter_candidates(
+            flops, single,
+            rt_threshold=spec.rt_threshold, flops_rel_tol=spec.flops_rel_tol,
+        )
     h0 = [n for n in initial_hypothesis_by_time(single) if n in cand.names]
     shuffle_seed = int(
         np.random.default_rng(_instance_entropy(spec, inst, 3)).integers(0, 2**31 - 1)
@@ -1003,12 +1005,22 @@ def run_chunked_campaign(
     shape. An exception it raises (``LeaseLost``) aborts the shard BEFORE
     the commit, so a taken-over shard never gets records from two owners.
 
-    ``timings``, if given, accumulates wall-clock stage seconds in place:
-    ``build_s`` (session construction — decomposition, workload setup),
-    ``step_s`` (engine measurement + mean-rank analysis), ``record_s``
-    (record_fn — discriminant / classification), ``append_s`` (store I/O),
-    plus ``steps`` / ``records`` counts. Pure observability — nothing here
-    feeds back into measurements or records.
+    ``timings``, if given, accumulates wall-clock stage seconds in place,
+    each from the :mod:`repro.core.spans` span of the same stage, which
+    also lands on a running profiler's trace: ``build_s`` (session
+    construction, span ``campaign.build``: decomposition, workload setup),
+    ``step_s`` (``campaign.step``: engine measurement + mean-rank
+    analysis), ``save_s`` (``campaign.save``: engine-state saves),
+    ``record_s`` (``campaign.record``: record_fn — discriminant /
+    classification), ``append_s`` (``campaign.append``: store I/O) and
+    ``predict_s`` (``campaign.predict``), plus what the sessions' own spans
+    add inside them: ``warmup_s`` (``session.warmup``: inputs, jit and
+    warm-up calls of a wall-clock timer's workloads) and ``first_s``
+    (``session.first``: the one timed call per algorithm and the candidate
+    filter) inside ``build_s``; ``sample_s`` (``session.sample``: the
+    timer's samples) and ``analyse_s`` (``session.analyse``: Procedure 2-3)
+    inside ``step_s``. Also ``steps`` / ``records`` counts. Pure
+    observability — nothing here feeds back into measurements or records.
 
     ``faults`` is the chaos hook: the ``campaign.step`` injection site is
     poked once per engine step (sigkill / stall ops — see
@@ -1029,112 +1041,110 @@ def run_chunked_campaign(
     say = progress or (lambda msg: None)
     beat = heartbeat or (lambda *a: None)
     t = timings if timings is not None else {}
-    completed = set(store.completed_uids())
-    total = len(todo_uids)
-    todo = [u for u in todo_uids if u not in completed]
-    steps_left = max_steps
+    with collect(t):
+        completed = set(store.completed_uids())
+        total = len(todo_uids)
+        todo = [u for u in todo_uids if u not in completed]
+        steps_left = max_steps
 
-    if predictor is not None and todo:
-        t0 = time.perf_counter()
-        predicted: List[Dict[str, Any]] = []
-        remaining: List[str] = []
-        for uid in todo:
-            beat()
-            rec = predictor(uid)
-            if rec is None:
-                remaining.append(uid)
+        if predictor is not None and todo:
+            predicted: List[Dict[str, Any]] = []
+            remaining: List[str] = []
+            with span("campaign.predict", "predict_s"):
+                for uid in todo:
+                    beat()
+                    rec = predictor(uid)
+                    if rec is None:
+                        remaining.append(uid)
+                    else:
+                        predicted.append(rec)
+            if predicted:
+                beat(True)  # prove ownership right before the commit
+                with span("campaign.append", "append_s"):
+                    store.append_records(predicted)
+                t["predicted"] = t.get("predicted", 0.0) + len(predicted)
+                completed.update(r["uid"] for r in predicted)
+                say(f"{label}: {len(predicted)}/{total} instances predicted "
+                    f"without measurement ({len(remaining)} to measure)")
+            todo = remaining
+
+        while True:
+            engine: Optional[ExperimentEngine] = None
+            if store.has_engine_state():
+                try:
+                    with open(store.engine_path) as fh:
+                        state = json.load(fh)
+                    timers = None
+                    if rebuild_timers is not None:
+                        names = [s["name"] for s in state["sessions"]]
+                        timers = rebuild_timers(names)
+                    engine = ExperimentEngine.load(store.engine_path, timers=timers)
+                except (ValueError, KeyError, TypeError):
+                    # corrupt in-flight state (bitrot; engine.save is atomic so
+                    # a kill can't cause this): rebuilding the chunk from the
+                    # todo list replays it bit-identically for the
+                    # deterministic backends — drop the state, warn, rebuild
+                    say(f"{label}: corrupt engine state discarded (chunk will "
+                        "be re-run deterministically)")
+                    store.clear_engine_state()
+                    continue
+                chunk_uids = engine.session_names
+                if all(uid in completed for uid in chunk_uids):
+                    # killed between record append and state cleanup
+                    store.clear_engine_state()
+                    continue
+                say(f"{label}: resuming chunk of {len(chunk_uids)}")
             else:
-                predicted.append(rec)
-        t["predict_s"] = t.get("predict_s", 0.0) + (time.perf_counter() - t0)
-        if predicted:
-            beat(True)  # prove ownership right before the commit
-            t0 = time.perf_counter()
-            store.append_records(predicted)
-            t["append_s"] = t.get("append_s", 0.0) + (time.perf_counter() - t0)
-            t["predicted"] = t.get("predicted", 0.0) + len(predicted)
-            completed.update(r["uid"] for r in predicted)
-            say(f"{label}: {len(predicted)}/{total} instances predicted "
-                f"without measurement ({len(remaining)} to measure)")
-        todo = remaining
+                chunk = todo[:chunk_size]
+                if not chunk:
+                    break
+                engine = ExperimentEngine(policy=policy)
+                for uid in chunk:
+                    beat()
+                    with span("campaign.build", "build_s", uid=uid):
+                        engine.add_session(build_session(uid))
+                with span("campaign.save", "save_s"):
+                    engine.save(store.engine_path)
+                chunk_uids = engine.session_names
+                say(f"{label}: new chunk of {len(chunk)} "
+                    f"({len(completed)}/{total} done)")
 
-    while True:
-        engine: Optional[ExperimentEngine] = None
-        if store.has_engine_state():
-            try:
-                with open(store.engine_path) as fh:
-                    state = json.load(fh)
-                timers = None
-                if rebuild_timers is not None:
-                    names = [s["name"] for s in state["sessions"]]
-                    timers = rebuild_timers(names)
-                engine = ExperimentEngine.load(store.engine_path, timers=timers)
-            except (ValueError, KeyError, TypeError):
-                # corrupt in-flight state (bitrot; engine.save is atomic so
-                # a kill can't cause this): rebuilding the chunk from the
-                # todo list replays it bit-identically for the
-                # deterministic backends — drop the state, warn, rebuild
-                say(f"{label}: corrupt engine state discarded (chunk will "
-                    "be re-run deterministically)")
-                store.clear_engine_state()
-                continue
-            chunk_uids = engine.session_names
-            if all(uid in completed for uid in chunk_uids):
-                # killed between record append and state cleanup
-                store.clear_engine_state()
-                continue
-            say(f"{label}: resuming chunk of {len(chunk_uids)}")
-        else:
-            chunk = todo[:chunk_size]
-            if not chunk:
-                break
-            engine = ExperimentEngine(policy=policy)
-            t0 = time.perf_counter()
-            for uid in chunk:
+            since_save = 0
+            while not engine.done:
+                if steps_left is not None and steps_left <= 0:
+                    with span("campaign.save", "save_s"):
+                        engine.save(store.engine_path)
+                    say(f"{label}: paused (step budget)")
+                    return False
+                if faults is not None:
+                    faults.poke("campaign.step")
                 beat()
-                engine.add_session(build_session(uid))
-            t["build_s"] = t.get("build_s", 0.0) + (time.perf_counter() - t0)
-            engine.save(store.engine_path)
-            chunk_uids = engine.session_names
-            say(f"{label}: new chunk of {len(chunk)} "
-                f"({len(completed)}/{total} done)")
+                with span("campaign.step", "step_s"):
+                    stepped = engine.step()
+                if stepped is None:
+                    break
+                t["steps"] = t.get("steps", 0.0) + 1
+                since_save += 1
+                if steps_left is not None:
+                    steps_left -= 1
+                if since_save >= save_every:
+                    with span("campaign.save", "save_s"):
+                        engine.save(store.engine_path)
+                    since_save = 0
 
-        since_save = 0
-        while not engine.done:
-            if steps_left is not None and steps_left <= 0:
-                engine.save(store.engine_path)
-                say(f"{label}: paused (step budget)")
-                return False
-            if faults is not None:
-                faults.poke("campaign.step")
-            beat()
-            t0 = time.perf_counter()
-            stepped = engine.step()
-            t["step_s"] = t.get("step_s", 0.0) + (time.perf_counter() - t0)
-            if stepped is None:
-                break
-            t["steps"] = t.get("steps", 0.0) + 1
-            since_save += 1
-            if steps_left is not None:
-                steps_left -= 1
-            if since_save >= save_every:
-                engine.save(store.engine_path)
-                since_save = 0
+            with span("campaign.record", "record_s"):
+                records = [record_fn(engine.session(uid)) for uid in chunk_uids]
+            t["records"] = t.get("records", 0.0) + len(records)
+            beat(True)  # prove ownership right before the commit
+            with span("campaign.append", "append_s"):
+                store.append_records(records)
+            store.clear_engine_state()
+            completed.update(chunk_uids)
+            todo = [u for u in todo if u not in completed]
 
-        t0 = time.perf_counter()
-        records = [record_fn(engine.session(uid)) for uid in chunk_uids]
-        t["record_s"] = t.get("record_s", 0.0) + (time.perf_counter() - t0)
-        t["records"] = t.get("records", 0.0) + len(records)
-        beat(True)  # prove ownership right before the commit
-        t0 = time.perf_counter()
-        store.append_records(records)
-        t["append_s"] = t.get("append_s", 0.0) + (time.perf_counter() - t0)
-        store.clear_engine_state()
-        completed.update(chunk_uids)
-        todo = [u for u in todo if u not in completed]
-
-    store.write_manifest(done=True)
-    say(f"{label}: done ({len(completed)}/{total})")
-    return True
+        store.write_manifest(done=True)
+        say(f"{label}: done ({len(completed)}/{total})")
+        return True
 
 
 def run_shard(

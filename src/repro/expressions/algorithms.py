@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import named
+
 from .chain import ChainAlgorithm, Step
 
 
@@ -70,7 +72,7 @@ def build_algorithm_fn(
     operands = {f"M{i}": m for i, m in enumerate(matrices)}
 
     if jit:
-        jitted = jax.jit(algorithm_fn(alg))
+        jitted = jax.jit(named(f"chain_{alg.name}", algorithm_fn(alg)))
         mats = tuple(matrices)
 
         def run() -> jax.Array:

@@ -32,6 +32,8 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import named
+
 
 @dataclass(frozen=True)
 class ExpressionVariant:
@@ -63,10 +65,11 @@ class ExpressionFamily:
         return table
 
 
-def _jit_thunk(fn: Callable[..., Any], *arrays: Any) -> Callable[[], Any]:
+def _jit_thunk(name: str, fn: Callable[..., Any], *arrays: Any) -> Callable[[], Any]:
+    """``fn`` jitted as the program ``jit_<name>``, compiled and run once."""
     import jax
 
-    jitted = jax.jit(fn)
+    jitted = jax.jit(named(name, fn))
     jax.block_until_ready(jitted(*arrays))  # compile outside timed region
 
     def run() -> Any:
@@ -92,16 +95,16 @@ def gram_family(n: int, k: int) -> ExpressionFamily:
         return [a, b]
 
     def left_first(a: Any, b: Any) -> Callable[[], Any]:
-        return _jit_thunk(lambda a, b: (a @ a.T) @ b, a, b)
+        return _jit_thunk("gram_left", lambda a, b: (a @ a.T) @ b, a, b)
 
     def right_first(a: Any, b: Any) -> Callable[[], Any]:
-        return _jit_thunk(lambda a, b: a @ (a.T @ b), a, b)
+        return _jit_thunk("gram_right", lambda a, b: a @ (a.T @ b), a, b)
 
     def left_syrk(a: Any, b: Any) -> Callable[[], Any]:
         # Symmetric rank-k update semantics: same math; in BLAS syrk halves
         # the FLOPs of AAᵀ. XLA has no syrk — the *analytic* count differs,
         # which is the interesting case for the discriminant test.
-        return _jit_thunk(lambda a, b: (a @ a.T) @ b, a, b)
+        return _jit_thunk("gram_left_syrk", lambda a, b: (a @ a.T) @ b, a, b)
 
     # FLOP accounting at the nominal size n (scaled at measurement time the
     # ratios are invariant, which is all RF needs).
@@ -135,10 +138,10 @@ def distributive_family(n: int) -> ExpressionFamily:
         ]
 
     def factored(a, b, c):
-        return _jit_thunk(lambda a, b, c: (a + b) @ c, a, b, c)
+        return _jit_thunk("dist_factored", lambda a, b, c: (a + b) @ c, a, b, c)
 
     def expanded(a, b, c):
-        return _jit_thunk(lambda a, b, c: a @ c + b @ c, a, b, c)
+        return _jit_thunk("dist_expanded", lambda a, b, c: a @ c + b @ c, a, b, c)
 
     variants = (
         ExpressionVariant("dist_factored", "(A+B)C", n * n + 2 * n**3, factored),
@@ -165,12 +168,12 @@ def solve_family(n: int) -> ExpressionFamily:
     def via_inverse(a, b):
         import jax.numpy as jnp
 
-        return _jit_thunk(lambda a, b: jnp.linalg.inv(a) @ b, a, b)
+        return _jit_thunk("solve_inverse", lambda a, b: jnp.linalg.inv(a) @ b, a, b)
 
     def via_solve(a, b):
         import jax.numpy as jnp
 
-        return _jit_thunk(lambda a, b: jnp.linalg.solve(a, b), a, b)
+        return _jit_thunk("solve_lu", lambda a, b: jnp.linalg.solve(a, b), a, b)
 
     def via_cholesky(a, b):
         import jax.scipy
@@ -181,7 +184,7 @@ def solve_family(n: int) -> ExpressionFamily:
             y = jax.scipy.linalg.solve_triangular(l, b, lower=True)
             return jax.scipy.linalg.solve_triangular(l.T, y, lower=False)
 
-        return _jit_thunk(f, a, b)
+        return _jit_thunk("solve_chol", f, a, b)
 
     variants = (
         ExpressionVariant("solve_inverse", "inv(A)b", 2.0 * n**3 + 2.0 * n * n, via_inverse),
@@ -207,10 +210,10 @@ def bilinear_family(n: int) -> ExpressionFamily:
         return [u, m, v]
 
     def left(u, m, v):
-        return _jit_thunk(lambda u, m, v: (u @ m) @ v, u, m, v)
+        return _jit_thunk("bilinear_left", lambda u, m, v: (u @ m) @ v, u, m, v)
 
     def right(u, m, v):
-        return _jit_thunk(lambda u, m, v: u @ (m @ v), u, m, v)
+        return _jit_thunk("bilinear_right", lambda u, m, v: u @ (m @ v), u, m, v)
 
     f = 2.0 * n * n + 2.0 * n
     variants = (

@@ -75,6 +75,7 @@ def matmul_kernel(
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[_vmem((bm, bn), jnp.float32)],
         interpret=interpret,
+        name=f"matmul_{bm}x{bn}x{bk}",
     )(a_p, b_p)
     return out[:m, :n]
 
