@@ -1019,8 +1019,10 @@ def run_chunked_campaign(
     (``session.first``: the one timed call per algorithm and the candidate
     filter) inside ``build_s``; ``sample_s`` (``session.sample``: the
     timer's samples) and ``analyse_s`` (``session.analyse``: Procedure 2-3)
-    inside ``step_s``. Also ``steps`` / ``records`` counts. Pure
-    observability — nothing here feeds back into measurements or records.
+    inside ``step_s``. Also ``steps`` / ``records`` counts, and the
+    ``programs_built`` / ``programs_reused`` counts of the chain and
+    generalized builders' program caches. Pure observability — nothing
+    here feeds back into measurements or records.
 
     ``faults`` is the chaos hook: the ``campaign.step`` injection site is
     poked once per engine step (sigkill / stall ops — see

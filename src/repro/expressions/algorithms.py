@@ -5,6 +5,11 @@ Each :class:`~repro.expressions.chain.ChainAlgorithm` lowers to a sequence of
 returns a zero-argument callable that blocks on the result
 (``block_until_ready``), suitable for :class:`repro.core.WallClockTimer`.
 
+An algorithm's jitted program depends only on its name and its steps, never
+on the dims or the data, so each is built once per process
+(:func:`chain_program`) and every instance of the same chain length shares
+it; ``jax.jit`` still compiles one executable per shape signature.
+
 Note on instruction order under XLA: independent GEMMs inside one jitted
 function may be reordered by the compiler, so two instruction orders of the
 same parenthesization typically compile to identical HLO — i.e. they are
@@ -22,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.spans import named
+from repro.core.spans import ProgramCache, named
 
 from .chain import ChainAlgorithm, Step
 
@@ -56,11 +61,27 @@ def _execute_steps(
 def algorithm_fn(alg: ChainAlgorithm) -> Callable[..., jax.Array]:
     """The algorithm as a pure function of ``M0..M_{n-1}`` — the program
     a jitted workload runs."""
+    steps = alg.steps
 
     def fn(*mats: jax.Array) -> jax.Array:
-        return _execute_steps(alg.steps, {f"M{i}": m for i, m in enumerate(mats)})
+        return _execute_steps(steps, {f"M{i}": m for i, m in enumerate(mats)})
 
     return fn
+
+
+#: Keyed by ``(alg.name, alg.steps)``: the 6 algorithms of a 4-matrix chain
+#: are 6 keys, and the chains of 6-8 matrices a few hundred.
+_PROGRAMS = ProgramCache(maxsize=256)
+
+
+def chain_program(alg: ChainAlgorithm) -> Callable[..., jax.Array]:
+    """``jax.jit`` of the algorithm as the program ``jit_chain_<name>``,
+    built once per process for each ``(alg.name, alg.steps)`` (at most 256
+    kept, the least recently used dropped first). The name is in the key
+    because the device trace reads it."""
+    return _PROGRAMS.get(
+        (alg.name, alg.steps),
+        lambda: jax.jit(named(f"chain_{alg.name}", algorithm_fn(alg))))
 
 
 def build_algorithm_fn(
@@ -68,11 +89,13 @@ def build_algorithm_fn(
     matrices: Sequence[jax.Array],
     jit: bool = True,
 ) -> Callable[[], jax.Array]:
-    """Zero-arg callable running one algorithm to completion."""
+    """Zero-arg callable running one algorithm to completion: with ``jit``
+    the process's shared :func:`chain_program` on these matrices, else the
+    steps eagerly in order."""
     operands = {f"M{i}": m for i, m in enumerate(matrices)}
 
     if jit:
-        jitted = jax.jit(named(f"chain_{alg.name}", algorithm_fn(alg)))
+        jitted = chain_program(alg)
         mats = tuple(matrices)
 
         def run() -> jax.Array:

@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.spans import named
+from repro.core.spans import ProgramCache, named
 
 
 @dataclass(frozen=True)
@@ -65,11 +65,17 @@ class ExpressionFamily:
         return table
 
 
+#: Keyed by the program's name, which names one body in this module.
+_PROGRAMS = ProgramCache(maxsize=64)
+
+
 def _jit_thunk(name: str, fn: Callable[..., Any], *arrays: Any) -> Callable[[], Any]:
-    """``fn`` jitted as the program ``jit_<name>``, compiled and run once."""
+    """``fn`` jitted as the program ``jit_<name>``, compiled and run once.
+    The jitted program is built once per process for each name: a later
+    ``fn`` under the same name is not looked at."""
     import jax
 
-    jitted = jax.jit(named(name, fn))
+    jitted = _PROGRAMS.get(name, lambda: jax.jit(named(name, fn)))
     jax.block_until_ready(jitted(*arrays))  # compile outside timed region
 
     def run() -> Any:

@@ -13,7 +13,7 @@ import sys
 
 import pytest
 
-from repro.core.spans import collect, named, span
+from repro.core.spans import ProgramCache, collect, count, named, span
 from repro.core.sweep import SweepSpec, run_shard
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -79,6 +79,38 @@ def test_a_span_that_raises_still_records():
     assert t["sample_s"] == s.seconds >= 0
 
 
+def test_count_adds_to_the_active_sink_only():
+    count("programs_built")
+    t = {}
+    with collect(t):
+        count("programs_built")
+        count("programs_reused", 3)
+        count("programs_reused")
+    assert t == {"programs_built": 1, "programs_reused": 4}
+
+
+def test_a_program_cache_builds_once_per_key_and_drops_the_oldest():
+    cache, built, t = ProgramCache(maxsize=2), [], {}
+
+    def build(key):
+        return lambda: built.append(key) or object()
+
+    with collect(t):
+        a = cache.get("a", build("a"))
+        assert cache.get("a", build("a")) is a
+        cache.get("b", build("b"))
+        cache.get("a", build("a"))       # "a" is now the newest
+        cache.get("c", build("c"))       # drops "b"
+        assert cache.get("a", build("a")) is a
+        cache.get("b", build("b"))
+    assert built == ["a", "b", "c", "b"]
+    assert t == {"programs_built": 4, "programs_reused": 3}
+    cache.clear()
+    with collect(t):
+        assert cache.get("a", build("a")) is not a
+    assert t["programs_built"] == 5
+
+
 def test_named_renames_the_program():
     def fn(a, b):
         return a + b
@@ -112,6 +144,87 @@ def test_a_site_built_again_traces_nothing_again():
     assert traced == []
 
 
+def _traced_while(build):
+    """``build()``'s result, its counters and the names of the functions it
+    traced."""
+    import jax
+
+    traced, t = [], {}
+
+    def listen(event, secs, fun_name="", **_):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            traced.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        with collect(t):
+            out = build()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    return out, t, traced
+
+
+def _chain_workloads(lo, hi, seed):
+    from repro.core.family import InstanceSpec, get_family
+
+    inst = InstanceSpec(index=0, uid=f"chain-{seed}", family="chain",
+                        params={"n_matrices": 4, "lo": lo, "hi": hi, "seed": seed})
+    flops, _, build = get_family("chain").entry(inst)
+    assert len(flops) == 6
+    return build
+
+
+def test_a_second_chain_instance_traces_nothing():
+    """The six programs of a 4-matrix chain are built once per process: a
+    second instance of the same dims, with other data, traces nothing."""
+    from repro.expressions import algorithms
+
+    algorithms._PROGRAMS.clear()
+    first, t1, traced1 = _traced_while(_chain_workloads(32, 32, seed=1))
+    second, t2, traced2 = _traced_while(_chain_workloads(32, 32, seed=2))
+    assert t1 == {"programs_built": 6}
+    assert sorted(n for n in traced1 if n.startswith("chain_")) == sorted(
+        f"chain_{name}" for name in first)
+    assert t2 == {"programs_reused": 6} and traced2 == []
+    assert set(first) == set(second) and len(second) == 6
+
+
+def test_a_shared_chain_program_retraces_per_shape():
+    """Other dims reuse the programs kept under the same name and steps
+    (names follow the FLOPs order, so a few steps differ), which trace again
+    for the new shapes and still compute the chain's product."""
+    import numpy as np
+
+    from repro.expressions.algorithms import make_chain_inputs, reference_product
+    from repro.expressions.instances import random_instance
+
+    _traced_while(_chain_workloads(32, 32, seed=1))
+    table, t, traced = _traced_while(_chain_workloads(8, 24, seed=3))
+    assert t["programs_reused"] >= 1 and t["programs_built"] + t["programs_reused"] == 6
+    assert sorted(n for n in traced if n.startswith("chain_")) == sorted(
+        f"chain_{name}" for name in table)
+    dims = random_instance(4, 8, 24, seed=3).dims
+    assert len(set(dims)) > 1
+    ref = np.asarray(reference_product(make_chain_inputs(dims, seed=3)))
+    for name, fn in table.items():
+        out = np.asarray(fn())
+        assert out.shape == (dims[0], dims[-1]), name
+        np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_a_generalized_family_built_again_traces_nothing():
+    """The generalized families' programs are built once per name: a second
+    build of one family with another seed traces nothing."""
+    from repro.expressions import generalized
+
+    generalized._PROGRAMS.clear()
+    family = generalized.FAMILIES["gram"](n=32)
+    _, t1, traced1 = _traced_while(lambda: family.workloads(32, seed=1))
+    _, t2, traced2 = _traced_while(lambda: family.workloads(32, seed=2))
+    assert t1 == {"programs_built": 3} and traced1
+    assert t2 == {"programs_reused": 3} and traced2 == []
+
+
 # -------------------------------------------------------------- campaign ---
 
 def test_campaign_timings_hold_the_stage_keys(tmp_path):
@@ -122,6 +235,7 @@ def test_campaign_timings_hold_the_stage_keys(tmp_path):
         t = json.load(fh)
     assert OLD_KEYS | NEW_KEYS <= set(t)
     assert t["records"] == 2 and t["steps"] == 4
+    assert t.get("programs_built", 0) + t.get("programs_reused", 0) == 4  # 2 x 2 algorithms
     assert t["sample_s"] + t["analyse_s"] <= t["step_s"]
     assert t["warmup_s"] + t["first_s"] <= t["build_s"]
     assert all(t[k] > 0 for k in NEW_KEYS)
