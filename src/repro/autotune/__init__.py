@@ -27,6 +27,7 @@ _EXPORTS = {
     # variants (imports jax at module scope)
     "Variant": "variants",
     "VariantSite": "variants",
+    "attention_layer_site": "variants",
     "attention_site": "variants",
     "matmul_blocks_site": "variants",
     "moe_dispatch_site": "variants",
@@ -66,6 +67,7 @@ if TYPE_CHECKING:  # pragma: no cover - static analysis only
     from .variants import (
         Variant,
         VariantSite,
+        attention_layer_site,
         attention_site,
         matmul_blocks_site,
         moe_dispatch_site,

@@ -8,6 +8,11 @@ analytic FLOP count, so the FLOPs-discriminant test applies directly:
   equal math; chunked wastes masked-block FLOPs, reference materialises the
   score matrix (memory). Neither FLOPs nor bytes alone predicts the winner
   across shapes — the paper's anomaly regime.
+* ``attention_layer``    — one causal attention layer of a model at its
+  widths (sliding-window or full): the Pallas flash kernel at three tilings,
+  the jnp scans, each with its own executed FLOPs (live blocks only for the
+  kernel, the window's span for ``local_chunked``, the rectangle for the
+  rest).
 * ``gqa_mode``           — grouped vs broadcast: EQUAL FLOPs, different
   memory traffic (K/V repeated g times). Pure equal-FLOPs regime
   (paper Instance B analogue).
@@ -23,13 +28,14 @@ analytic FLOP count, so the FLOPs-discriminant test applies directly:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence
+import functools
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.spans import named
+from repro.core.spans import ProgramCache, count, named
 from repro.models import ModelConfig
 from repro.models.flops import param_counts
 
@@ -80,18 +86,23 @@ def pallas_interpret(interpret: Optional[bool] = None) -> bool:
     return expected
 
 
+def _warm(program, *arrays):
+    """The jitted ``program`` run once on ``arrays`` (compiling it), and the
+    zero-arg thunk that runs it again and waits for the device."""
+    jax.block_until_ready(program(*arrays))
+
+    def run():
+        return jax.block_until_ready(program(*arrays))
+
+    return run
+
+
 def _thunk(fn, *arrays):
     """``fn`` jitted, compiled and run once; ``fn``'s name names the program
     (:func:`repro.core.spans.named`). jax caches the compiled program by
     ``fn`` itself, so a function built once (``_xla_dot``) is traced once per
     process, while a closure built per instance is traced per instance."""
-    jitted = jax.jit(fn)
-    jax.block_until_ready(jitted(*arrays))
-
-    def run():
-        return jax.block_until_ready(jitted(*arrays))
-
-    return run
+    return _warm(jax.jit(fn), *arrays)
 
 
 #: XLA's dot as the program ``jit_xla_dot``, built once so that every
@@ -101,10 +112,25 @@ _xla_dot = named("xla_dot", jnp.dot)
 
 # ------------------------------------------------------- attention site ----
 
+#: the attention site's programs, one per variant and static arguments
+_ATTENTION_PROGRAMS = ProgramCache(64)
+
+
+def _attention_program(name: str, fn: Callable[..., Any], **static: Any):
+    """``fn(q, k, v, **static)`` as the jitted program ``jit_attention_<name>``,
+    built once per process: a second instance finds it built, and ``jax.jit``
+    compiles it once per shape."""
+    key = (name, fn, tuple(sorted(static.items())))
+    return _ATTENTION_PROGRAMS.get(
+        key, lambda: jax.jit(named(f"attention_{name}", functools.partial(fn, **static))))
+
+
 def attention_site(
     b: int = 2, s: int = 1024, h: int = 8, kv: int = 2, d: int = 64,
     dtype=jnp.float32,
 ) -> VariantSite:
+    """The toy attention site: the jnp variants at one shared-math FLOP
+    count (the score rectangle), whatever each computes."""
     from repro.models.attention import attention_chunked, attention_reference
 
     def inputs(seed: int):
@@ -114,42 +140,178 @@ def attention_site(
         v = jax.random.normal(ks[2], (b, s, kv, d), dtype)
         return [q, k, v]
 
-    # score FLOPs: rectangle for both impls (masked blocks computed);
-    # the Pallas kernel variant (TPU) would halve this — listed via meta.
     f_scores = 2.0 * b * h * s * s * d * 2
-    f_ref = f_scores
-    f_chunk = f_scores
 
-    def ref_grouped(q, k, v):
-        return _thunk(
-            named("reference_grouped",
-                  lambda q, k, v: attention_reference(q, k, v, gqa="grouped")),
-            q, k, v,
-        )
-
-    def ref_broadcast(q, k, v):
-        return _thunk(
-            named("reference_broadcast",
-                  lambda q, k, v: attention_reference(q, k, v, gqa="broadcast")),
-            q, k, v,
-        )
-
-    def chunked(q, k, v):
-        return _thunk(
-            named("chunked_flash", lambda q, k, v: attention_chunked(
-                q, k, v, q_block=min(256, s), kv_block=min(512, s)
-            )),
-            q, k, v,
-        )
+    def build(name, fn, **static):
+        return lambda q, k, v: _warm(_attention_program(name, fn, **static), q, k, v)
 
     return VariantSite(
         name=f"attention[b{b} s{s} h{h}kv{kv} d{d}]",
         variants=(
-            Variant("reference_grouped", f_ref, ref_grouped),
-            Variant("reference_broadcast", f_ref, ref_broadcast,
+            Variant("reference_grouped", f_scores,
+                    build("reference_grouped", attention_reference, gqa="grouped")),
+            Variant("reference_broadcast", f_scores,
+                    build("reference_broadcast", attention_reference, gqa="broadcast"),
                     {"extra_traffic": "K/V repeated to H heads"}),
-            Variant("chunked_flash", f_chunk, chunked,
+            Variant("chunked_flash", f_scores,
+                    build("chunked_flash", attention_chunked,
+                          q_block=min(256, s), kv_block=min(512, s)),
                     {"memory": "O(s*block) not O(s^2)"}),
+        ),
+        make_inputs=inputs,
+    )
+
+
+#: the flash kernel's tilings (block_q, block_k) ranked at a layer's widths
+FLASH_TILES = ((128, 512), (256, 512), (512, 1024))
+#: q block of ``local_chunked``: each block attends to window + q block keys
+LOCAL_Q_BLOCK = 256
+#: (q block, kv block) of ``chunked``, the jnp online-softmax scan
+CHUNK_BLOCKS = (256, 512)
+#: standard deviation of the attention layer's q and k entries (v's is 1):
+#: the scores q k^T / sqrt(d) then spread with standard deviation 4, so a
+#: row's softmax is peaked as a trained model's is; at 1 it is nearly flat,
+#: and scores rounded to bfloat16 barely move the answer
+QK_STD = 2.0
+#: the largest all-heads float32 score buffer a ``reference_*`` variant may
+#: materialise; past it the variant is left out (at s=8192 and 32 heads one
+#: buffer is 8.6 GB of a v5e's 16)
+SCORE_BUFFER_BYTES = 2**30
+
+
+def attention_algorithms(b: int, s: int, h: int, window: Optional[int]) -> List[str]:
+    """The variants of one attention layer: the flash kernel at each tiling,
+    ``local_chunked`` for a windowed layer, the ``chunked`` scan, and the
+    ``reference_*`` pair where its score buffer fits."""
+    names = [f"flash_{bq}x{bk}" for bq, bk in FLASH_TILES]
+    if window is not None:
+        names.append("local_chunked")
+    names.append("chunked")
+    if 4 * b * h * s * s <= SCORE_BUFFER_BYTES:
+        names += ["reference_grouped", "reference_broadcast"]
+    return names
+
+
+def _blocks(name: str, s: int, window: Optional[int]) -> Tuple[int, int]:
+    """(q block, kv block) of a variant, capped at the sequence as the
+    variant caps them; the kv block of ``local_chunked`` is its key span."""
+    if name.startswith("flash_"):
+        bq, bk = (int(x) for x in name[len("flash_"):].split("x"))
+        return min(bq, s), min(bk, s)
+    if name == "local_chunked":
+        qb = min(LOCAL_Q_BLOCK, s)
+        return qb, min(window + qb, s)
+    if name == "chunked":
+        return min(CHUNK_BLOCKS[0], s), min(CHUNK_BLOCKS[1], s)
+    if name.startswith("reference_"):
+        return s, s
+    raise ValueError(f"unknown attention variant {name!r}")
+
+
+def attention_score_tiles(name: str, s: int, window: Optional[int]) -> Tuple[int, int]:
+    """(rows, cols): variant ``name`` computes rows x cols score entries
+    (query, key pairs) in each (batch, head) row of a causal layer of ``s``
+    tokens, ``window`` keys wide or full. The counting rule of the site's
+    FLOP table and of its kernel decomposition:
+
+    - ``flash_<bq>x<bk>``: (live grid steps x bq, bk), the live steps counted
+      with the kernel's own predicate (:func:`grid_steps`); a live block is
+      computed whole, its masked entries too, and a dead one not at all;
+    - ``local_chunked``: (s, window + q_block), the static key span each q
+      block slices (s x s where that span reaches s, as the variant then runs
+      the full scan);
+    - ``chunked`` and ``reference_*``: the s x s rectangle, masked entries
+      computed and discarded.
+    """
+    # from the defining module: the package-level name can be shadowed by
+    # the like-named subpackage after a dotted import (see repro.kernels)
+    from repro.kernels.flash_attention.flash_attention import grid_steps
+
+    bq, bk = _blocks(name, s, window)
+    if name.startswith("flash_"):
+        live, _ = grid_steps(s, s, block_q=bq, block_k=bk, causal=True, window=window)
+        return live * bq, bk
+    if name == "local_chunked" and bk < s:
+        return s, bk
+    return s, s
+
+
+def attention_flops(name: str, *, b: int, s: int, h: int, d: int,
+                    window: Optional[int]) -> float:
+    """Executed FLOPs of one call: each score entry costs 2d in q @ k^T and
+    2d in p @ v (the paper counts multiply-adds of the GEMMs; the softmax's
+    exponentials, maxima and sums are not counted)."""
+    rows, cols = attention_score_tiles(name, s, window)
+    return 4.0 * b * h * d * rows * cols
+
+
+def check_attention_size(s: int, window: Optional[int]) -> None:
+    """Raise unless every variant's blocks divide ``s``."""
+    for name in attention_algorithms(1, s, 1, window):
+        bq, bk = _blocks(name, s, window)
+        if s % bq or (name != "local_chunked" and s % bk):
+            raise ValueError(f"attention size {s} is not a multiple of {name}'s "
+                             f"blocks ({bq}, {bk})")
+
+
+def attention_layer_site(
+    s: int, h: int, kv: int, d: int, window: Optional[int], b: int = 1,
+    interpret: Optional[bool] = None,
+) -> VariantSite:
+    """One causal attention layer at a model's widths, ``window`` keys wide
+    (sliding) or full: q [b, s, h, d] and k, v [b, s, kv, d] in bfloat16
+    (normal, q and k at :data:`QK_STD`), GQA by index. Every variant keeps
+    the precision contract of :mod:`repro.models.attention` and carries its
+    own executed FLOPs
+    (:func:`attention_flops`). Building a flash variant counts its grid
+    steps into the active span sink (``flash_grid_steps``,
+    ``flash_live_steps``, all heads)."""
+    from repro.kernels.flash_attention.flash_attention import grid_steps
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.models.attention import (attention_chunked, attention_local_chunked,
+                                        attention_reference)
+
+    check_attention_size(s, window)
+    interpret = pallas_interpret(interpret)
+
+    def inputs(seed: int):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        shapes = ((b, s, h, d), (b, s, kv, d), (b, s, kv, d))
+        return [(jax.random.normal(key, shape, jnp.float32) * std).astype(jnp.bfloat16)
+                for key, shape, std in zip(ks, shapes, (QK_STD, QK_STD, 1.0))]
+
+    def build(name):
+        bq, bk = _blocks(name, s, window)
+        if name.startswith("flash_"):
+            program = _attention_program(name, flash_attention, causal=True, window=window,
+                                         block_q=bq, block_k=bk, interpret=interpret)
+        elif name == "local_chunked":
+            program = _attention_program(name, attention_local_chunked, window=window,
+                                         q_block=bq)
+        elif name == "chunked":
+            program = _attention_program(name, attention_chunked, causal=True,
+                                         window=window, q_block=bq, kv_block=bk)
+        else:
+            program = _attention_program(name, attention_reference, causal=True,
+                                         window=window, gqa=name[len("reference_"):])
+
+        def make(q, k, v):
+            if name.startswith("flash_"):
+                live, total = grid_steps(s, s, block_q=bq, block_k=bk, causal=True,
+                                         window=window)
+                count("flash_grid_steps", b * h * total)
+                count("flash_live_steps", b * h * live)
+            return _warm(program, q, k, v)
+
+        return make
+
+    kind = "full" if window is None else f"swa{window}"
+    return VariantSite(
+        name=f"attention[{kind} b{b} s{s} h{h}kv{kv} d{d}]",
+        variants=tuple(
+            Variant(name, attention_flops(name, b=b, s=s, h=h, d=d, window=window),
+                    build(name), {"blocks": _blocks(name, s, window)})
+            for name in attention_algorithms(b, s, h, window)
         ),
         make_inputs=inputs,
     )
@@ -224,7 +386,6 @@ def ssd_chunk_site(
         return build
 
     def flops(q):
-        per_tok = 2.0 * q * (n + h * p / h) + 4.0 * h * p * n / h
         return b * s * h * (2.0 * q * n + 2.0 * q * p + 4.0 * p * n)
 
     return VariantSite(

@@ -28,6 +28,7 @@ _MODULES = {
     "whisper-tiny": "whisper_tiny",
     "jamba-v0.1-52b": "jamba_v01_52b",
     "mamba2-1.3b": "mamba2_1_3b",
+    "trinity-mini": "trinity_mini",
 }
 
 ARCH_NAMES: List[str] = list(_MODULES)

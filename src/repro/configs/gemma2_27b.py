@@ -17,7 +17,7 @@ FULL = ModelConfig(
     d_ff=36864,
     vocab_size=256000,
     activation="geglu",
-    local_global_alternating=True,
+    global_attn_every_n_layers=2,
     sliding_window=4096,
     attn_logit_softcap=50.0,
     final_logit_softcap=30.0,

@@ -312,11 +312,14 @@ KERNEL_SITES = ("matmul", "attention", "ssd")
 def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
     """Pure (no-jax) per-site metadata at one grid size: algorithm names,
     the shared-math kernel decomposition, and the VariantSite constructor
+    (``site``, a function of :mod:`repro.autotune.variants`) and its
     arguments. The decomposition describes the *shared math* once — every
     variant computes the same function, so every variant carries the same
     kernel list and the same analytic FLOP count (FLOP-identical by
     construction; implementation overhead — masked blocks, chunk-quadratic
-    terms, tile padding — is exactly what the census measures).
+    terms, tile padding — is exactly what the census measures). A model
+    layer's attention site (:func:`_attention_layer_config`) counts each
+    variant's own FLOPs instead.
     """
     from repro.explain.decompose import KernelSpec
 
@@ -332,6 +335,7 @@ def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
         return {
             "names": names,
             "kernels": [KernelSpec("gemm", (m, k, n))],
+            "site": "matmul_blocks_site",
             "site_kwargs": {"m": m, "k": k, "n": n, "blocks": blocks},
         }
     if site == "attention":
@@ -347,6 +351,7 @@ def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
                 KernelSpec("gemm", (b * h * s, d, s)),   # scores  Q @ K^T
                 KernelSpec("gemm", (b * h * s, s, d)),   # output  P @ V
             ],
+            "site": "attention_site",
             "site_kwargs": {"b": b, "s": s, "h": h, "kv": kv, "d": d},
         }
     if site == "ssd":
@@ -371,23 +376,93 @@ def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
                 KernelSpec("gemm", (b * h * s, n, p)),   # state    B^T @ X
                 KernelSpec("gemm", (b * h * s, p, n)),   # output   S @ C
             ],
+            "site": "ssd_chunk_site",
             "site_kwargs": {"b": b, "s": s, "h": h, "p": p, "n": n,
                             "chunks": chunks},
         }
     raise ValueError(f"unknown kernel site {site!r}; one of {KERNEL_SITES}")
 
 
+def attention_layer_kinds(config: str) -> List[str]:
+    """The attention layer kinds of a registered model config, in the order
+    its pattern unit first has them: ``sliding`` (window attention) and
+    ``full``."""
+    from repro.configs import get_config
+    from repro.models.config import LayerKind
+
+    names = {LayerKind.ATTN_LOCAL: "sliding", LayerKind.ATTN: "full"}
+    kinds: List[str] = []
+    for spec in get_config(config).pattern_unit():
+        if spec.kind in names and names[spec.kind] not in kinds:
+            kinds.append(names[spec.kind])
+    return kinds
+
+
+#: instance params that restate a model layer's attention widths
+_WIDTH_PARAMS = ("heads", "kv_heads", "head_dim", "window")
+
+
+def _attention_layer_config(params: Mapping[str, Any]) -> Dict[str, Any]:
+    """Metadata of one attention layer of a registered model config at its
+    published widths (params ``config``, ``layer``, ``size`` = sequence
+    length; batch 1): algorithm names, each variant's own kernel
+    decomposition (executed FLOPs, by the site's counting rule
+    :func:`repro.autotune.variants.attention_score_tiles`), the shapes
+    (``dims``), and the site with its arguments. Params that restate a
+    width must agree with the config. Reading a model config imports the
+    model stack, and so jax."""
+    from repro.autotune.variants import (attention_algorithms, attention_score_tiles,
+                                         check_attention_size)
+    from repro.configs import get_config
+    from repro.explain.decompose import decompose_attention
+
+    config, layer, s = str(params["config"]), str(params["layer"]), int(params["size"])
+    kinds = attention_layer_kinds(config)
+    if layer not in kinds:
+        raise ValueError(f"{config} has no {layer!r} attention layer; one of {kinds}")
+    model = get_config(config)
+    b, h, kv, d = 1, model.n_heads, model.n_kv_heads, model.resolved_head_dim
+    window = model.sliding_window if layer == "sliding" else None
+    widths = {"heads": h, "kv_heads": kv, "head_dim": d, "window": window}
+    stated = {k: params[k] for k in _WIDTH_PARAMS if k in params}
+    if any(stated[k] != widths[k] for k in stated):
+        raise ValueError(f"instance widths {stated} disagree with {config}'s {widths}")
+    check_attention_size(s, window)
+    names = attention_algorithms(b, s, h, window)
+    return {
+        "names": names,
+        "kernels": {n: decompose_attention(b, h, d, *attention_score_tiles(n, s, window))
+                    for n in names},
+        "dims": {"b": b, "s": s, **widths},
+        "site": "attention_layer_site",
+        "site_kwargs": {"s": s, "h": h, "kv": kv, "d": d, "window": window, "b": b},
+    }
+
+
+def _instance_site_config(params: Mapping[str, Any]) -> Dict[str, Any]:
+    """The site metadata of one instance row, with ``kernels`` per variant."""
+    site = str(params["site"])
+    if site == "attention" and "config" in params:
+        return _attention_layer_config(params)
+    cfg = _kernel_site_config(site, int(params["size"]))
+    return {**cfg, "kernels": {n: list(cfg["kernels"]) for n in cfg["names"]}, "dims": None}
+
+
 class KernelVariantsFamily(AlgorithmFamily):
     """The repo's own kernels as a census family: every algorithm is a
     kernel variant of the same math (Pallas matmul tile shapes, fused vs
     unfused attention blocks, SSD chunk lengths), wrapping the autotuner's
-    :func:`~repro.autotune.variants` sites. All variants of an instance
-    share one analytic FLOP count and one kernel decomposition (the shared
-    math), so the whole instance sits in ``S_F`` and **every** rank
-    difference is an anomaly the explainer must attribute. Metadata is
-    jax-free; only building workloads imports jax — measured through the
-    ``wall_clock`` backend (Pallas compiled on a TPU, interpreted on any
-    other backend: :func:`~repro.autotune.variants.pallas_interpret`),
+    :func:`~repro.autotune.variants` sites. At the toy widths all variants
+    of an instance share one analytic FLOP count and one kernel
+    decomposition (the shared math), so the whole instance sits in ``S_F``
+    and **every** rank difference is an anomaly the explainer must
+    attribute. An attention instance that names a model ``config`` runs one
+    of its layers at the published widths, and each variant carries the
+    FLOPs it executes. Toy metadata is jax-free (a named model config
+    imports the model stack); only building workloads runs jax — measured
+    through the ``wall_clock`` backend (Pallas compiled on a TPU,
+    interpreted on any other backend:
+    :func:`~repro.autotune.variants.pallas_interpret`),
     while the deterministic backends exercise the same grid through the
     synthetic cost hooks."""
 
@@ -395,19 +470,27 @@ class KernelVariantsFamily(AlgorithmFamily):
     description = (
         "the repo's Pallas/JAX kernel variants (matmul tiles, fused vs "
         "unfused attention, SSD chunk lengths) — FLOP-identical by "
-        "construction, censused on wall clock"
+        "construction, except a model's attention layers, whose variants "
+        "carry the FLOPs they execute — censused on wall clock"
     )
 
     def expand_grid(self, grid: Mapping[str, Any]) -> List[InstanceSpec]:
+        """``sites`` x ``sizes`` x ``per_size`` seeds; with a ``config`` (a
+        registered model config) the attention site runs that model's
+        layers instead, one instance per attention layer kind and seed."""
         sites = [str(x) for x in grid.get("sites", KERNEL_SITES)]
         sizes = [int(s) for s in grid.get("sizes", ())]
         per_size = int(grid.get("per_size", 1))
+        config = grid.get("config")
         out: List[InstanceSpec] = []
         for site in sites:
             if site not in KERNEL_SITES:
                 raise ValueError(
                     f"unknown kernel site {site!r}; one of {KERNEL_SITES}"
                 )
+            if site == "attention" and config:
+                out.extend(self._layer_instances(str(config), sizes, per_size))
+                continue
             for size in sizes:
                 _kernel_site_config(site, size)  # validate shape constraints
                 for s in range(per_size):
@@ -419,53 +502,60 @@ class KernelVariantsFamily(AlgorithmFamily):
                     ))
         return out
 
+    def _layer_instances(self, config: str, sizes: Sequence[int],
+                         per_size: int) -> List[InstanceSpec]:
+        out: List[InstanceSpec] = []
+        for size in sizes:
+            for layer in attention_layer_kinds(config):
+                params = {"site": "attention", "config": config, "layer": layer,
+                          "size": size}
+                dims = _attention_layer_config(params)["dims"]
+                widths = {k: dims[k] for k in _WIDTH_PARAMS}
+                for s in range(per_size):
+                    out.append(InstanceSpec(
+                        index=0,
+                        uid=f"kernel_variants-attention-{config}-{layer}-n{size}-s{s:03d}",
+                        family=self.name,
+                        params={**params, "seed": s, **widths},
+                    ))
+        return out
+
     def grid_from_args(self, args: Any) -> Optional[Dict[str, Any]]:
         sites = [s for s in getattr(args, "kernel_sites", "").split(",") if s]
-        return {
+        grid = {
             "sites": sites or list(KERNEL_SITES),
             "sizes": args.sizes,
             "per_size": args.per_size,
         }
+        if getattr(args, "kernel_config", ""):
+            grid["config"] = args.kernel_config
+        return grid
 
     def entry(self, inst: InstanceSpec) -> Entry:
         from repro.explain.decompose import kernels_to_compact
 
         p = inst.params
-        site, size = str(p["site"]), int(p["size"])
-        cfg = _kernel_site_config(site, size)
-        shared = sum(k.flops for k in cfg["kernels"])
-        flops = {name: shared for name in cfg["names"]}
-        kernels = kernels_to_compact(
-            {name: list(cfg["kernels"]) for name in cfg["names"]}
-        )
+        cfg = _instance_site_config(p)
+        flops = {name: sum(k.flops for k in ks) for name, ks in cfg["kernels"].items()}
+        kernels = kernels_to_compact(cfg["kernels"])
 
         def build_workloads() -> Dict[str, Callable[[], Any]]:
             return self.variant_site(p).workloads(seed=int(p["seed"]), warmup=True)
 
-        meta = {"size": size, "dims": None, "kernels": kernels}
+        meta = {"size": int(p["size"]), "dims": cfg["dims"], "kernels": kernels}
         return flops, meta, build_workloads
 
     @staticmethod
     def variant_site(params: Mapping[str, Any]):
         """The instance's wrapped VariantSite (imports jax: workload build
         time only)."""
-        site = str(params["site"])
-        kw = _kernel_site_config(site, int(params["size"]))["site_kwargs"]
-        if site == "matmul":
-            from repro.autotune.variants import matmul_blocks_site
+        from repro.autotune import variants
 
-            return matmul_blocks_site(**kw)
-        if site == "attention":
-            from repro.autotune.variants import attention_site
-
-            return attention_site(**kw)
-        from repro.autotune.variants import ssd_chunk_site
-
-        return ssd_chunk_site(**kw)
+        cfg = _instance_site_config(params)
+        return getattr(variants, cfg["site"])(**cfg["site_kwargs"])
 
     def decompose(self, params: Mapping[str, Any]) -> Dict[str, Any]:
-        cfg = _kernel_site_config(str(params["site"]), int(params["size"]))
-        return {name: list(cfg["kernels"]) for name in cfg["names"]}
+        return _instance_site_config(params)["kernels"]
 
 
 # ------------------------------------------------------- the default seam ---

@@ -39,7 +39,7 @@ from .measure import (
     timer_from_dict,
     timer_to_dict,
 )
-from .spans import span
+from .spans import instance_args, span
 from .types import (
     DEFAULT_QUANTILE_RANGES,
     REPORT_QUANTILE_RANGE,
@@ -204,6 +204,9 @@ class MeasurementSession:
         """Loop condition of Procedure 4: converged or budget spent."""
         return self._converged or self.measurements_per_alg >= self.max_measurements
 
+    def _span_args(self) -> Dict[str, Any]:
+        return instance_args(self.name, self.meta.get("params", {}))
+
     def attach_timer(self, timer: Timer) -> None:
         """Re-attach a measurement backend (after :meth:`from_dict` of a
         session whose timer was not serializable, e.g. wall-clock)."""
@@ -243,7 +246,7 @@ class MeasurementSession:
         ``np.percentile`` pair per comparison) bit-for-bit — the golden
         tests hold the two paths equal.
         """
-        with span("session.analyse", "analyse_s", uid=self.name) as timed:
+        with span("session.analyse", "analyse_s", **self._span_args()) as timed:
             if self._vectorized:
                 mr = mean_ranks(
                     self._order,
@@ -279,7 +282,7 @@ class MeasurementSession:
             return None
         snap = self._timer.snapshot()
         try:
-            with span("session.sample", "sample_s", uid=self.name):
+            with span("session.sample", "sample_s", **self._span_args()):
                 batch = [
                     (name, self._timer.measure_many(name, self.m_per_iteration))
                     for name in self._order

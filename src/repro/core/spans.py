@@ -23,7 +23,7 @@ import time
 from collections import OrderedDict
 from contextlib import contextmanager
 from contextvars import ContextVar
-from typing import Any, Callable, Dict, Hashable, Iterator, Optional
+from typing import Any, Callable, Dict, Hashable, Iterator, Mapping, Optional
 
 _SINK: ContextVar[Optional[Dict[str, float]]] = ContextVar("repro_span_sink", default=None)
 
@@ -64,6 +64,12 @@ class span:
         sink = _SINK.get()
         if self.key is not None and sink is not None:
             sink[self.key] = sink.get(self.key, 0.0) + self.seconds
+
+
+def instance_args(uid: str, params: Mapping[str, Any]) -> Dict[str, Any]:
+    """The stats of a census instance's spans: its ``uid``, and the model
+    ``layer`` where the instance is one."""
+    return {"uid": uid, **({"layer": params["layer"]} if "layer" in params else {})}
 
 
 def count(key: str, n: float = 1) -> None:

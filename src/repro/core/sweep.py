@@ -77,7 +77,7 @@ from .measure import (
 from .retry import STORE_IO_POLICY, with_retries
 from .scores import filter_candidates, initial_hypothesis_by_time
 from .session import MeasurementSession
-from .spans import collect, span
+from .spans import collect, instance_args, span
 
 __all__ = [  # InstanceSpec re-exported: it moved to repro.core.family
     "BACKENDS", "InstanceSpec", "SweepSpec", "ShardStore", "StoreDamaged",
@@ -385,7 +385,7 @@ def build_timer(spec: SweepSpec, inst: InstanceSpec, flops: Mapping[str, float],
                 kernel_counts: Optional[Mapping[str, int]] = None) -> Timer:
     """The instance's measurement backend, fully derived from the spec."""
     if spec.backend == "wall_clock":
-        with span("session.warmup", "warmup_s", uid=inst.uid):
+        with span("session.warmup", "warmup_s", **instance_args(inst.uid, inst.params)):
             return WallClockTimer(build_workloads())
     model = synthetic_instance_model(spec, inst.index, flops, kernel_counts)
     noise_seed = np.random.default_rng(
@@ -416,7 +416,7 @@ def build_sweep_session(spec: SweepSpec, inst: InstanceSpec) -> MeasurementSessi
     flops, desc, build_workloads = instance_entry(inst)
     kernel_counts = {alg: len(ks) for alg, ks in desc["kernels"].items()}
     timer = build_timer(spec, inst, flops, build_workloads, kernel_counts)
-    with span("session.first", "first_s", uid=inst.uid):
+    with span("session.first", "first_s", **instance_args(inst.uid, inst.params)):
         single = {name: timer.measure(name) for name in flops}
         cand = filter_candidates(
             flops, single,

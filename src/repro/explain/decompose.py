@@ -190,6 +190,17 @@ def _decompose_generalized_cached(
     raise ValueError(f"unknown family {family!r}")
 
 
+def decompose_attention(b: int, h: int, d: int, rows: int, cols: int) -> List[KernelSpec]:
+    """Kernels of one attention variant that computes ``rows x cols`` score
+    entries in each of its ``b * h`` (batch, head) rows (the site's counting
+    rule, :func:`repro.autotune.variants.attention_score_tiles`): the scores
+    GEMM q @ k^T and the output GEMM p @ v, batch and heads folded into the
+    GEMM rows. The softmax between them is not a kernel here, as it carries
+    no FLOPs in the paper's accounting."""
+    return [KernelSpec("gemm", (b * h * rows, d, cols)),   # scores  Q @ K^T
+            KernelSpec("gemm", (b * h * rows, cols, d))]   # output  P @ V
+
+
 def decompose_chain_dims(dims: Sequence[int]) -> Dict[str, List[KernelSpec]]:
     """Kernels of EVERY algorithm of a chain instance (lazy import: the
     enumeration layer is pure python). Memoized per dims tuple — an

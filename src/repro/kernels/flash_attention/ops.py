@@ -1,7 +1,8 @@
 """Jit'd public wrapper for the flash-attention kernel.
 
-Handles model-layout plumbing: GQA broadcast (kv heads -> q heads), the
-[b, s, h, d] <-> [bh, s, d] flattening, and padding to block multiples.
+Handles model-layout plumbing: the [b, s, h, d] <-> [bh, s, d] flattening
+and GQA (the kernel reads kv head ``h // group`` by index; the oracle gets
+the kv heads repeated to the q heads).
 ``use_kernel=False`` routes to the pure-jnp oracle — both paths share this
 wrapper so tests sweep them identically.
 """
@@ -41,14 +42,13 @@ def flash_attention(
 ) -> jax.Array:
     b, sq, h, d = q.shape
     kh = k.shape[2]
-    if h != kh:
-        g = h // kh
-        k = jnp.repeat(k, g, axis=2)
-        v = jnp.repeat(v, g, axis=2)
+    if not use_kernel and h != kh:
+        k = jnp.repeat(k, h // kh, axis=2)
+        v = jnp.repeat(v, h // kh, axis=2)
 
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, k.shape[1], d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, v.shape[1], d)
+    kf = k.transpose(0, 2, 1, 3).reshape(-1, k.shape[1], d)
+    vf = v.transpose(0, 2, 1, 3).reshape(-1, v.shape[1], d)
 
     if use_kernel:
         of = flash_attention_kernel(

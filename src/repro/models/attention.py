@@ -3,7 +3,9 @@ mathematically equivalent implementations (the autotune variant site), plus
 KV-cache decode.
 
 Variants (all produce identical outputs up to fp reassociation — exactly the
-paper's "equivalent algorithms" regime):
+paper's "equivalent algorithms" regime). Each keeps one precision contract:
+q·k and p·v multiply the stored dtype and accumulate in f32, so scores and
+softmax are f32, and p is rounded to the stored dtype for p·v:
 
 * ``reference``  — materialises [.., sq, skv] scores. Minimal HLO ops; O(s²)
   memory. Used for small sequences and as the correctness oracle.
@@ -41,6 +43,7 @@ from .layers import (
 )
 
 NEG_INF = -2.0e38  # f32-safe mask value
+F32 = jnp.float32
 
 
 # ---------------------------------------------------------------- params ---
@@ -126,17 +129,16 @@ def attention_reference(
     if gqa == "broadcast":
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-        scores = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32) * scale
+        scores = jnp.einsum("bqhk,bshk->bhqs", q, k, preferred_element_type=F32) * scale
         scores = softcap(scores, logit_cap) + bias[None, None]
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
-        return out
+        return jnp.einsum("bhqs,bshk->bqhk", probs, v, preferred_element_type=F32).astype(q.dtype)
     # grouped: keep K/V at kv-head granularity
     qg = q.reshape(b, sq, kheads, g, hd)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=F32) * scale
     scores = softcap(scores, logit_cap) + bias[None, None, None]
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v, preferred_element_type=F32).astype(q.dtype)
     return out.reshape(b, sq, h, hd)
 
 
@@ -180,7 +182,7 @@ def attention_chunked(
             m, l, acc = carry
             kj, vj, j = kj_vj_j
             kv_pos = jnp.arange(kv_block) + j * kv_block
-            s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj).astype(jnp.float32) * scale
+            s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj, preferred_element_type=F32) * scale
             s = softcap(s, logit_cap)
             allowed = jnp.ones((q_block, kv_block), dtype=bool)
             if causal:
@@ -195,7 +197,7 @@ def attention_chunked(
             p = jnp.where(allowed[None, None, None], p, 0.0)
             alpha = jnp.where(m <= NEG_INF * 0.5, 0.0, jnp.exp(m - m_safe))
             l_new = l * alpha + jnp.sum(p, axis=-1)
-            pv = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(qi.dtype), vj).astype(jnp.float32)
+            pv = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(qi.dtype), vj, preferred_element_type=F32)
             acc_new = acc * alpha[..., None] + pv
             return (m_new, l_new, acc_new), None
 
@@ -255,14 +257,15 @@ def attention_local_chunked(
         vj = jax.lax.dynamic_slice_in_dim(v, start, span, axis=1)
         q_pos = jnp.arange(q_block) + q_start + q_offset
         kv_pos = jnp.arange(span) + start + q_offset
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj).astype(jnp.float32) * scale
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj, preferred_element_type=F32) * scale
         s = softcap(s, logit_cap)
         allowed = (kv_pos[None, :] <= q_pos[:, None]) & (
             kv_pos[None, :] > q_pos[:, None] - window
         )
         s = jnp.where(allowed[None, None, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(qi.dtype), vj)
+        out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(qi.dtype), vj,
+                         preferred_element_type=F32).astype(qi.dtype)
         return None, jnp.moveaxis(out, 3, 1)  # [b, qb, K, g, hd]
 
     _, blocks = jax.lax.scan(q_step, None, (jnp.moveaxis(qb, 1, 0), jnp.arange(nq)))

@@ -54,7 +54,8 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None   # gemma2: 50.0
     final_logit_softcap: Optional[float] = None  # gemma2: 30.0
     sliding_window: Optional[int] = None  # window for ATTN_LOCAL sublayers
-    local_global_alternating: bool = False  # gemma2: unit = [local, global]
+    # unit = [local] * (n - 1) + [global]: gemma2 2, trinity 4; 0 = one kind
+    global_attn_every_n_layers: int = 0
     rope_theta: float = 10000.0
     attn_bias: bool = False
     parallel_block: bool = False         # command-r: attn and FFN in parallel
@@ -146,8 +147,8 @@ class ModelConfig:
 
     def _unit_len(self) -> int:
         candidates = [1]
-        if self.local_global_alternating:
-            candidates.append(2)
+        if self.global_attn_every_n_layers:
+            candidates.append(self.global_attn_every_n_layers)
         if self.attn_layer_period > 1:
             candidates.append(self.attn_layer_period)
         if self.is_moe and self.moe_layer_period > 1:
@@ -172,8 +173,9 @@ class ModelConfig:
             )
         elif self.family == "ssm":
             kind = LayerKind.MAMBA
-        elif self.local_global_alternating:
-            kind = LayerKind.ATTN_LOCAL if pos % 2 == 0 else LayerKind.ATTN
+        elif self.global_attn_every_n_layers:
+            n = self.global_attn_every_n_layers
+            kind = LayerKind.ATTN if pos % n == n - 1 else LayerKind.ATTN_LOCAL
         elif self.sliding_window is not None:
             kind = LayerKind.ATTN_LOCAL
         else:

@@ -197,6 +197,46 @@ def test_kernel_variants_decompose_via_decompose_instance():
     assert total == pytest.approx(2.0 * b * h * s * s * d * 2)
 
 
+def test_kernel_config_expands_the_models_attention_layers():
+    """With a model config the attention site runs one instance per
+    attention layer kind of the model and seed, the widths in the row; the
+    other sites are as before."""
+    fam = get_family("kernel_variants")
+    rows = fam.expand_grid({"sites": ["attention", "matmul"], "sizes": [8192],
+                            "per_size": 2, "config": "trinity-mini"})
+    assert [i.uid for i in rows] == [
+        "kernel_variants-attention-trinity-mini-sliding-n8192-s000",
+        "kernel_variants-attention-trinity-mini-sliding-n8192-s001",
+        "kernel_variants-attention-trinity-mini-full-n8192-s000",
+        "kernel_variants-attention-trinity-mini-full-n8192-s001",
+        "kernel_variants-matmul-n8192-s000", "kernel_variants-matmul-n8192-s001",
+    ]
+    assert rows[0].params == {"site": "attention", "config": "trinity-mini",
+                              "layer": "sliding", "size": 8192, "seed": 0, "heads": 32,
+                              "kv_heads": 4, "head_dim": 128, "window": 2048}
+    assert rows[2].params["window"] is None
+    with pytest.raises(ValueError, match="not a multiple"):
+        fam.expand_grid({"sites": ["attention"], "sizes": [1000], "config": "trinity-mini"})
+
+
+def test_census_plan_names_a_kernel_config(tmp_path):
+    """The normal CLI path: ``census plan --kernel-config`` writes a spec
+    whose grid censuses the model's layer kinds."""
+    out = str(tmp_path / "census")
+    run = subprocess.run(
+        [sys.executable, "-m", "repro", "census", "plan", "--out", out, "--chains", "0",
+         "--families", "kernel_variants", "--kernel-sites", "attention",
+         "--kernel-config", "trinity-mini", "--sizes", "8192", "--per-size", "1",
+         "--shards", "1", "--backend", "wall_clock"],
+        env=_env(), capture_output=True, text=True, timeout=300)
+    assert run.returncode == 0, run.stderr
+    with open(os.path.join(out, "spec.json")) as fh:
+        spec = SweepSpec(**{k: v for k, v in json.load(fh).items()
+                            if k in SweepSpec.__dataclass_fields__})
+    assert spec.families["kernel_variants"]["config"] == "trinity-mini"
+    assert [i.params["layer"] for i in spec.expand()] == ["sliding", "full"]
+
+
 def test_kernel_variants_metadata_needs_no_jax():
     """A cost-model census worker building kernel_variants sessions (and
     stepping them) must never import jax — the family's FLOP tables and

@@ -55,6 +55,73 @@ def test_flash_ops_gqa_broadcast():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
+def _brute_force_steps(sq, skv, bq, bk, causal, window):
+    """(live, total) blocks of a [bq, bk] tiling holding a query, key pair
+    the mask lets through, counted pair by pair."""
+    i = np.arange(sq)[:, None] + (skv - sq if causal else 0)
+    j = np.arange(skv)[None, :]
+    seen = np.ones((sq, skv), bool)
+    if causal:
+        seen &= j <= i
+    if window is not None:
+        seen &= j > i - window
+    blocks = seen.reshape(sq // bq, bq, skv // bk, bk).any(axis=(1, 3))
+    return int(blocks.sum()), blocks.size
+
+
+@pytest.mark.parametrize(
+    "sq,skv,bq,bk,causal,window",
+    [
+        (8192, 8192, 128, 512, True, 2048),
+        (8192, 8192, 256, 512, True, 2048),
+        (8192, 8192, 512, 1024, True, 2048),
+        (8192, 8192, 128, 512, True, None),
+        (512, 512, 128, 512, True, 128),
+        (256, 256, 64, 64, True, 64),
+        (128, 512, 64, 128, True, None),
+        (256, 256, 64, 128, False, None),
+    ],
+)
+def test_grid_steps_match_a_brute_force_count(sq, skv, bq, bk, causal, window):
+    from repro.kernels.flash_attention.flash_attention import grid_steps
+
+    got = grid_steps(sq, skv, block_q=bq, block_k=bk, causal=causal, window=window)
+    assert got == _brute_force_steps(sq, skv, bq, bk, causal, window)
+
+
+def test_grid_steps_at_trinity_widths():
+    """The live share of each tiling at s=8192: 27.3% sliding (window
+    2048), 53.1-56.3% full."""
+    from repro.kernels.flash_attention.flash_attention import grid_steps
+
+    assert grid_steps(8192, 8192, block_q=128, block_k=512, window=2048) == (280, 1024)
+    assert grid_steps(8192, 8192, block_q=512, block_k=1024, window=2048) == (42, 128)
+    assert grid_steps(8192, 8192, block_q=512, block_k=1024) == (72, 128)
+
+
+def test_flash_pallas_call_names_its_mask_and_blocks():
+    from repro.kernels.flash_attention.flash_attention import kernel_name
+
+    assert kernel_name(True, 2048, 128, 512) == "flash_swa2048_128x512"
+    assert kernel_name(True, None, 512, 1024) == "flash_causal_512x1024"
+    q = jnp.zeros((2, 256, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention_kernel(
+        q, k, v, window=64, block_q=64, block_k=128, interpret=True))(q, kv, kv)
+    assert "flash_swa64_64x128" in str(jaxpr)
+
+
+def test_flash_kernel_reads_kv_heads_by_index():
+    """q rows i read k/v row i // group: the same as repeating the kv heads."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (4, 256, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 256, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 256, 64), jnp.float32)
+    out = flash_attention_kernel(q, k, v, window=96, block_q=64, block_k=64, interpret=True)
+    ref = flash_attention_ref(q, jnp.repeat(k, 2, axis=0), jnp.repeat(v, 2, axis=0), window=96)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
+
+
 # ----------------------------------------------------------------- matmul --
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 2e-2)])

@@ -248,3 +248,22 @@ def test_training_flops_scale_linearly_in_tokens():
     f1 = training_flops(cfg, 8, 1024)
     f2 = training_flops(cfg, 16, 1024)
     assert abs(f2 / f1 - 2.0) < 1e-6
+
+
+def test_global_attention_period_places_global_last_in_the_unit():
+    """Trinity-Mini's ``global_attn_every_n_layers`` 4: three sliding
+    layers, then one full, 8 units over 32 layers; gemma2's period 2 keeps
+    its [local, global] unit."""
+    from repro.models.config import LayerKind
+
+    local, full = LayerKind.ATTN_LOCAL, LayerKind.ATTN
+    trinity = get_config("trinity-mini")
+    assert [sp.kind for sp in trinity.pattern_unit()] == [local, local, local, full]
+    assert trinity.n_units == 8 and trinity.global_attn_every_n_layers == 4
+    gemma = get_config("gemma2-27b")
+    assert [sp.kind for sp in gemma.pattern_unit()] == [local, full]
+    assert gemma.global_attn_every_n_layers == 2 and gemma.n_units == 23
+    assert get_config("granite-8b").global_attn_every_n_layers == 0
+    assert {sp.kind for sp in get_config("granite-8b").pattern_unit()} == {full}
+    with pytest.raises(ValueError, match="pattern unit"):
+        trinity.replace(n_layers=30).pattern_unit()

@@ -225,6 +225,44 @@ def test_a_generalized_family_built_again_traces_nothing():
     assert t2 == {"programs_reused": 3} and traced2 == []
 
 
+def test_a_layer_instances_spans_carry_its_layer():
+    from repro.core.session import MeasurementSession
+    from repro.core.spans import instance_args
+    from repro.core.measure import CostModelTimer
+
+    assert instance_args("u", {"size": 8}) == {"uid": "u"}
+    assert instance_args("u", {"layer": "full"}) == {"uid": "u", "layer": "full"}
+    session = MeasurementSession("u", ["a"], CostModelTimer({"a": 1.0}),
+                                 meta={"params": {"layer": "sliding"}})
+    assert session._span_args() == {"uid": "u", "layer": "sliding"}
+
+
+def test_a_second_attention_layer_instance_builds_nothing(tiny_attention_model):
+    """The attention layer site's programs are built once per process: a
+    second instance of the layer, with other data, builds and traces
+    nothing; each flash variant's build counts its grid steps."""
+    from repro.autotune import variants
+    from repro.core.family import InstanceSpec, get_family
+
+    def layer(seed):
+        inst = InstanceSpec(index=0, uid=f"attn-{seed}", family="kernel_variants", params={
+            "site": "attention", "config": "tiny-attention", "layer": "sliding",
+            "size": 512, "seed": seed})
+        return get_family("kernel_variants").entry(inst)[2]
+
+    variants._ATTENTION_PROGRAMS.clear()
+    first, t1, traced1 = _traced_while(layer(1))
+    second, t2, traced2 = _traced_while(layer(2))
+    n = len(first)
+    assert n == 7 and t1["programs_built"] == n
+    assert sorted(x for x in traced1 if x.startswith("attention_")) == sorted(
+        f"attention_{name}" for name in first)
+    assert t2.pop("programs_reused") == n and "programs_built" not in t2 and traced2 == []
+    # 8 heads; 4 + 2 + 1 steps of the capped tilings, all live at s=512
+    assert t2 == {"flash_grid_steps": 56, "flash_live_steps": 56} == {
+        k: t1[k] for k in ("flash_grid_steps", "flash_live_steps")}
+
+
 # -------------------------------------------------------------- campaign ---
 
 def test_campaign_timings_hold_the_stage_keys(tmp_path):

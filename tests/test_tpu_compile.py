@@ -73,6 +73,25 @@ def test_flash_attention_compiles_at_granite_8b_widths(chip_shape):
     assert "tpu_custom_call" in _compiled_text(flash_attention, q, kv, kv)
 
 
+@pytest.mark.parametrize("window", [2048, None])
+@pytest.mark.parametrize("blocks", [(128, 512), (512, 1024)])
+def test_flash_attention_compiles_at_trinity_mini_widths(chip_shape, blocks, window):
+    """The attention layer site's flash tilings at Trinity-Mini's widths:
+    32 query heads over 4 KV heads of 128 read by index, 8192 tokens,
+    sliding (2048) and full, bf16; the Pallas call carries its name."""
+    import functools
+
+    from repro.kernels.flash_attention.flash_attention import kernel_name
+    from repro.kernels.flash_attention.ops import flash_attention
+
+    bq, bk = blocks
+    q = chip_shape((1, 8192, 32, 128), jnp.bfloat16)
+    kv = chip_shape((1, 8192, 4, 128), jnp.bfloat16)
+    fn = functools.partial(flash_attention, window=window, block_q=bq, block_k=bk)
+    text = _compiled_text(fn, q, kv, kv)
+    assert "tpu_custom_call" in text and kernel_name(True, window, bq, bk) in text
+
+
 def test_ssd_kernel_compiles_at_mamba2_1p3b_widths(chip_shape):
     """64 heads of p=64 with state n=128, chunk 256, 2048 tokens."""
     from repro.kernels.ssd.ssd import ssd_scan_kernel

@@ -5,6 +5,7 @@ kernel table, variant-output equivalence in Pallas interpret mode on CPU
 that compute different things would be meaningless), and the rule that
 ties the Pallas mode to the backend."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -75,6 +76,84 @@ def test_attention_variants_equivalent():
     for name, out in outs.items():
         np.testing.assert_allclose(out, ref, rtol=1e-3, atol=1e-3,
                                    err_msg=name)
+
+
+# ------------------------------------------------- attention layer site ---
+
+# Trinity-Mini at s=8192: executed FLOPs over the 4 s^2 h d rectangle
+TRINITY_SHARES = {
+    "sliding": {"flash_128x512": 280 / 1024, "flash_256x512": 140 / 512,
+                "flash_512x1024": 42 / 128, "local_chunked": 2304 / 8192, "chunked": 1.0},
+    "full": {"flash_128x512": 544 / 1024, "flash_256x512": 272 / 512,
+             "flash_512x1024": 72 / 128, "chunked": 1.0},
+}
+
+
+def _layer(config, layer, size):
+    from repro.core.family import InstanceSpec, get_family
+
+    params = {"site": "attention", "config": config, "layer": layer, "size": size, "seed": 0}
+    fam = get_family("kernel_variants")
+    flops, meta, _ = fam.entry(InstanceSpec(0, "x", "kernel_variants", params))
+    return flops, meta, fam.decompose(params)
+
+
+@pytest.mark.parametrize("layer", ["sliding", "full"])
+def test_attention_layer_flops_match_decomposition_at_trinity_widths(layer):
+    """Each variant's executed FLOPs, as the site counts them, are the sum
+    of the explainer's GEMM decomposition, and at Trinity-Mini's widths
+    they are the shares of the rectangle the kernel's predicate gives."""
+    from repro.autotune.variants import attention_flops
+
+    s, h, d = 8192, 32, 128
+    flops, meta, decomp = _layer("trinity-mini", layer, s)
+    assert meta["dims"] == {"b": 1, "s": s, "heads": h, "kv_heads": 4, "head_dim": d,
+                            "window": 2048 if layer == "sliding" else None}
+    assert set(flops) == set(decomp) == set(TRINITY_SHARES[layer])
+    for name, f in flops.items():
+        assert f == sum(k.flops for k in decomp[name]) == attention_flops(
+            name, b=1, s=s, h=h, d=d, window=meta["dims"]["window"]), name
+        assert f == 4.0 * s * s * h * d * TRINITY_SHARES[layer][name], name
+
+
+@pytest.mark.parametrize("layer", ["sliding", "full"])
+def test_attention_layer_site_flops_match_family_table(tiny_attention_model, layer):
+    from repro.autotune.variants import attention_layer_site
+
+    flops, meta, decomp = _layer("tiny-attention", layer, 512)
+    site = attention_layer_site(s=512, h=8, kv=1, d=128, window=meta["dims"]["window"])
+    assert site.flops_table() == flops
+    for name, ks in decomp.items():
+        assert sum(k.flops for k in ks) == flops[name]
+    # the score buffer of 8 heads at s=512 fits: the reference pair runs
+    assert {"reference_grouped", "reference_broadcast"} <= set(flops)
+
+
+def test_attention_layer_site_stores_bf16_and_matches_the_oracle(tiny_attention_model):
+    """Every variant of a sliding layer agrees with the kernel package's
+    pure-jnp oracle (GQA repeated, f32 softmax) to bf16 output rounding."""
+    from repro.autotune.variants import attention_layer_site
+    from repro.kernels.flash_attention.ops import flash_attention
+
+    site = attention_layer_site(s=512, h=8, kv=1, d=128, window=128)
+    q, k, v = site.make_inputs(3)
+    assert {x.dtype for x in (q, k, v)} == {jnp.dtype(jnp.bfloat16)}
+    ref = np.asarray(flash_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                     window=128, use_kernel=False))
+    for name, out in _outputs(site, seed=3).items():
+        out = np.asarray(out, np.float32)
+        err = np.linalg.norm(out - ref) / np.linalg.norm(ref)
+        assert err < 3e-3, (name, err)
+
+
+def test_attention_layer_rejects_widths_its_config_does_not_have():
+    with pytest.raises(ValueError, match="disagree"):
+        from repro.core.family import _attention_layer_config
+
+        _attention_layer_config({"site": "attention", "config": "trinity-mini",
+                                 "layer": "sliding", "size": 8192, "heads": 16})
+    with pytest.raises(ValueError, match="no 'sliding'"):
+        _layer("granite-8b", "sliding", 8192)
 
 
 # -------------------------------------------------------------------- ssd ---
