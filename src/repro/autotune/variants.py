@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.measure import warm
 from repro.core.spans import ProgramCache, count, named
 from repro.models import ModelConfig
 from repro.models.flops import param_counts
@@ -46,7 +47,7 @@ Thunk = Callable[[], Any]
 class Variant:
     name: str
     flops: float                     # analytic, per workload execution
-    build: Callable[..., Thunk]      # (*arrays) -> zero-arg timed thunk
+    build: Callable[..., Thunk]      # (*arrays) -> zero-arg timed thunk, unwarmed
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -60,13 +61,13 @@ class VariantSite:
         return {v.name: v.flops for v in self.variants}
 
     def workloads(self, seed: int = 0, warmup: bool = True) -> Dict[str, Thunk]:
+        """name -> thunk on the inputs of ``seed``; with ``warmup`` each
+        thunk runs once here (:func:`repro.core.measure.warm`), the only
+        call before the timer's."""
         arrays = self.make_inputs(seed)
-        table: Dict[str, Thunk] = {}
-        for v in self.variants:
-            thunk = v.build(*arrays)
-            if warmup:
-                thunk()
-            table[v.name] = thunk
+        table = {v.name: v.build(*arrays) for v in self.variants}
+        if warmup:
+            warm(table)
         return table
 
 
@@ -86,10 +87,10 @@ def pallas_interpret(interpret: Optional[bool] = None) -> bool:
     return expected
 
 
-def _warm(program, *arrays):
-    """The jitted ``program`` run once on ``arrays`` (compiling it), and the
-    zero-arg thunk that runs it again and waits for the device."""
-    jax.block_until_ready(program(*arrays))
+def _runner(program, *arrays):
+    """The zero-arg thunk that runs ``program`` on ``arrays`` and waits for
+    the device, returned unwarmed: it has not run, and compiles on its first
+    call, the table builder's warm run (:meth:`VariantSite.workloads`)."""
 
     def run():
         return jax.block_until_ready(program(*arrays))
@@ -98,16 +99,15 @@ def _warm(program, *arrays):
 
 
 def _thunk(fn, *arrays):
-    """``fn`` jitted, compiled and run once; ``fn``'s name names the program
-    (:func:`repro.core.spans.named`). jax caches the compiled program by
-    ``fn`` itself, so a function built once (``_xla_dot``) is traced once per
-    process, while a closure built per instance is traced per instance."""
-    return _warm(jax.jit(fn), *arrays)
+    """:func:`_runner` of ``fn`` jitted, unwarmed; ``fn``'s name names the
+    program (:func:`repro.core.spans.named`). A closure built per instance
+    is traced per instance."""
+    return _runner(jax.jit(fn), *arrays)
 
 
-#: XLA's dot as the program ``jit_xla_dot``, built once so that every
-#: instance's jit finds it compiled
-_xla_dot = named("xla_dot", jnp.dot)
+#: XLA's dot as the program ``jit_xla_dot``, jitted once so that every
+#: instance finds it compiled
+_xla_dot = jax.jit(named("xla_dot", jnp.dot))
 
 
 # ------------------------------------------------------- attention site ----
@@ -143,7 +143,7 @@ def attention_site(
     f_scores = 2.0 * b * h * s * s * d * 2
 
     def build(name, fn, **static):
-        return lambda q, k, v: _warm(_attention_program(name, fn, **static), q, k, v)
+        return lambda q, k, v: _runner(_attention_program(name, fn, **static), q, k, v)
 
     return VariantSite(
         name=f"attention[b{b} s{s} h{h}kv{kv} d{d}]",
@@ -301,7 +301,7 @@ def attention_layer_site(
                                          window=window)
                 count("flash_grid_steps", b * h * total)
                 count("flash_live_steps", b * h * live)
-            return _warm(program, q, k, v)
+            return _runner(program, q, k, v)
 
         return make
 
@@ -420,22 +420,16 @@ def matmul_blocks_site(
     f = 2.0 * m * k * n
 
     def make(bm, bn, bk):
-        def build(a, b_):
-            def run():
-                return jax.block_until_ready(
-                    matmul(a, b_, block_m=bm, block_n=bn, block_k=bk,
-                           use_kernel=True, interpret=interpret)
-                )
-            run()  # warm
-            return run
-        return build
+        program = functools.partial(matmul, block_m=bm, block_n=bn, block_k=bk,
+                                    use_kernel=True, interpret=interpret)
+        return lambda a, b_: _runner(program, a, b_)
 
     variants = tuple(
         Variant(f"blocks_{bm}x{bn}x{bk}", f, make(bm, bn, bk),
                 {"tiles": (bm, bn, bk)})
         for bm, bn, bk in blocks
     ) + (
-        Variant("xla_dot", f, lambda a, b_: _thunk(_xla_dot, a, b_)),
+        Variant("xla_dot", f, lambda a, b_: _runner(_xla_dot, a, b_)),
     )
     return VariantSite(
         name=f"matmul[{m}x{k}x{n}]", variants=variants, make_inputs=inputs

@@ -235,7 +235,7 @@ class ChainFamily(AlgorithmFamily):
         of them to extract a winner/loser pair would dominate every
         wall-clock explanation, so chains build the involved thunks
         selectively."""
-        from repro.expressions.algorithms import build_algorithm_fn, make_chain_inputs
+        from repro.expressions.algorithms import build_workloads, make_chain_inputs
         from repro.expressions.instances import random_instance
 
         p = inst.params
@@ -244,12 +244,7 @@ class ChainFamily(AlgorithmFamily):
         )
         algs = {a.name: a for a in chain.algorithms()}
         mats = make_chain_inputs(chain.dims, seed=int(p["seed"]))
-        out: Dict[str, Callable[[], Any]] = {}
-        for alg in involved:
-            fn = build_algorithm_fn(algs[alg], mats, jit=True)
-            fn()  # warm up: jit compilation must not land in a timed region
-            out[alg] = fn
-        return out
+        return build_workloads([algs[alg] for alg in involved], mats)
 
 
 # ---------------------------------------------------- generalized families ---

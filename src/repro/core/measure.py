@@ -27,6 +27,8 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
 
+from .spans import count
+
 
 def rng_state(rng: np.random.Generator) -> Dict[str, Any]:
     """JSON-serializable state of a numpy Generator (exact-resume support)."""
@@ -175,6 +177,16 @@ class Timer:
 
     def restore(self, snap: Any) -> None:
         return None
+
+
+def warm(workloads: Mapping[str, Callable[[], object]]) -> None:
+    """The single warm run per algorithm (paper Sec. I step 1): each
+    workload called once, untimed, so that compilation ("library
+    overheads") never lands in a timed region. Counts ``warm_calls`` into
+    the active span sink, one per workload."""
+    for fn in workloads.values():
+        fn()
+        count("warm_calls")
 
 
 class WallClockTimer(Timer):

@@ -1014,15 +1014,16 @@ def run_chunked_campaign(
     ``record_s`` (``campaign.record``: record_fn — discriminant /
     classification), ``append_s`` (``campaign.append``: store I/O) and
     ``predict_s`` (``campaign.predict``), plus what the sessions' own spans
-    add inside them: ``warmup_s`` (``session.warmup``: inputs, jit and
-    warm-up calls of a wall-clock timer's workloads) and ``first_s``
-    (``session.first``: the one timed call per algorithm and the candidate
-    filter) inside ``build_s``; ``sample_s`` (``session.sample``: the
-    timer's samples) and ``analyse_s`` (``session.analyse``: Procedure 2-3)
-    inside ``step_s``. Also ``steps`` / ``records`` counts, and the
-    ``programs_built`` / ``programs_reused`` counts of the chain and
-    generalized builders' program caches. Pure observability — nothing
-    here feeds back into measurements or records.
+    add inside them: ``warmup_s`` (``session.warmup``: inputs, jit and the
+    one warm call per algorithm of a wall-clock timer's workloads) and
+    ``first_s`` (``session.first``: the one timed call per algorithm and the
+    candidate filter) inside ``build_s``; ``sample_s`` (``session.sample``:
+    the timer's samples) and ``analyse_s`` (``session.analyse``: Procedure
+    2-3) inside ``step_s``. Also ``steps`` / ``records`` counts, the
+    ``programs_built`` / ``programs_reused`` counts of the program caches,
+    and ``warm_calls``, one per algorithm warmed
+    (:func:`repro.core.measure.warm`). Pure observability — nothing here
+    feeds back into measurements or records.
 
     ``faults`` is the chaos hook: the ``campaign.step`` injection site is
     poked once per engine step (sigkill / stall ops — see

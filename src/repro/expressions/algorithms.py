@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.measure import warm
 from repro.core.spans import ProgramCache, named
 
 from .chain import ChainAlgorithm, Step
@@ -117,16 +118,13 @@ def build_workloads(
 ) -> Dict[str, Callable[[], jax.Array]]:
     """name -> callable table for :class:`repro.core.WallClockTimer`.
 
-    With ``warmup=True`` each callable is executed once here so that jit
-    compilation ("library overheads", paper Sec. I step 1) never lands inside
-    a timed region.
+    With ``warmup=True`` each callable is executed once here
+    (:func:`repro.core.measure.warm`) so that jit compilation ("library
+    overheads", paper Sec. I step 1) never lands inside a timed region.
     """
-    table: Dict[str, Callable[[], jax.Array]] = {}
-    for alg in algs:
-        fn = build_algorithm_fn(alg, matrices, jit=jit)
-        if warmup:
-            fn()
-        table[alg.name] = fn
+    table = {alg.name: build_algorithm_fn(alg, matrices, jit=jit) for alg in algs}
+    if warmup:
+        warm(table)
     return table
 
 

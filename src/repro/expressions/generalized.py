@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.measure import warm
 from repro.core.spans import ProgramCache, named
 
 
@@ -40,7 +41,7 @@ class ExpressionVariant:
     name: str
     label: str
     flops: float
-    build: Callable[..., Callable[[], Any]]  # (*arrays) -> thunk
+    build: Callable[..., Callable[[], Any]]  # (*arrays) -> thunk, unwarmed
 
 
 @dataclass(frozen=True)
@@ -55,13 +56,13 @@ class ExpressionFamily:
     def workloads(
         self, size: int, seed: int = 0, warmup: bool = True
     ) -> Dict[str, Callable[[], Any]]:
+        """name -> thunk on the inputs of ``(size, seed)``; with ``warmup``
+        each thunk runs once here (:func:`repro.core.measure.warm`), the only
+        call before the timer's."""
         arrays = self.make_inputs(size, seed)
-        table: Dict[str, Callable[[], Any]] = {}
-        for v in self.variants:
-            thunk = v.build(*arrays)
-            if warmup:
-                thunk()
-            table[v.name] = thunk
+        table = {v.name: v.build(*arrays) for v in self.variants}
+        if warmup:
+            warm(table)
         return table
 
 
@@ -70,13 +71,14 @@ _PROGRAMS = ProgramCache(maxsize=64)
 
 
 def _jit_thunk(name: str, fn: Callable[..., Any], *arrays: Any) -> Callable[[], Any]:
-    """``fn`` jitted as the program ``jit_<name>``, compiled and run once.
-    The jitted program is built once per process for each name: a later
-    ``fn`` under the same name is not looked at."""
+    """The thunk that runs ``fn`` jitted as the program ``jit_<name>`` and
+    waits for the device, returned unwarmed: it compiles on its first call,
+    the family's warm run (:meth:`ExpressionFamily.workloads`). The jitted
+    program is built once per process for each name: a later ``fn`` under
+    the same name is not looked at."""
     import jax
 
     jitted = _PROGRAMS.get(name, lambda: jax.jit(named(name, fn)))
-    jax.block_until_ready(jitted(*arrays))  # compile outside timed region
 
     def run() -> Any:
         return jax.block_until_ready(jitted(*arrays))

@@ -182,10 +182,10 @@ def test_a_second_chain_instance_traces_nothing():
     algorithms._PROGRAMS.clear()
     first, t1, traced1 = _traced_while(_chain_workloads(32, 32, seed=1))
     second, t2, traced2 = _traced_while(_chain_workloads(32, 32, seed=2))
-    assert t1 == {"programs_built": 6}
+    assert t1 == {"programs_built": 6, "warm_calls": 6}
     assert sorted(n for n in traced1 if n.startswith("chain_")) == sorted(
         f"chain_{name}" for name in first)
-    assert t2 == {"programs_reused": 6} and traced2 == []
+    assert t2 == {"programs_reused": 6, "warm_calls": 6} and traced2 == []
     assert set(first) == set(second) and len(second) == 6
 
 
@@ -221,8 +221,8 @@ def test_a_generalized_family_built_again_traces_nothing():
     family = generalized.FAMILIES["gram"](n=32)
     _, t1, traced1 = _traced_while(lambda: family.workloads(32, seed=1))
     _, t2, traced2 = _traced_while(lambda: family.workloads(32, seed=2))
-    assert t1 == {"programs_built": 3} and traced1
-    assert t2 == {"programs_reused": 3} and traced2 == []
+    assert t1 == {"programs_built": 3, "warm_calls": 3} and traced1
+    assert t2 == {"programs_reused": 3, "warm_calls": 3} and traced2 == []
 
 
 def test_a_layer_instances_spans_carry_its_layer():
@@ -258,6 +258,7 @@ def test_a_second_attention_layer_instance_builds_nothing(tiny_attention_model):
     assert sorted(x for x in traced1 if x.startswith("attention_")) == sorted(
         f"attention_{name}" for name in first)
     assert t2.pop("programs_reused") == n and "programs_built" not in t2 and traced2 == []
+    assert t2.pop("warm_calls") == n == t1["warm_calls"]
     # 8 heads; 4 + 2 + 1 steps of the capped tilings, all live at s=512
     assert t2 == {"flash_grid_steps": 56, "flash_live_steps": 56} == {
         k: t1[k] for k in ("flash_grid_steps", "flash_live_steps")}
@@ -274,6 +275,7 @@ def test_campaign_timings_hold_the_stage_keys(tmp_path):
     assert OLD_KEYS | NEW_KEYS <= set(t)
     assert t["records"] == 2 and t["steps"] == 4
     assert t.get("programs_built", 0) + t.get("programs_reused", 0) == 4  # 2 x 2 algorithms
+    assert t["warm_calls"] == 4
     assert t["sample_s"] + t["analyse_s"] <= t["step_s"]
     assert t["warmup_s"] + t["first_s"] <= t["build_s"]
     assert all(t[k] > 0 for k in NEW_KEYS)
