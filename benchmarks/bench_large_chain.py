@@ -21,10 +21,9 @@ from repro.core import (
     measure_and_rank,
 )
 from repro.expressions import (
-    build_workloads,
+    chain_site,
     flops_table,
     generate_chain_algorithms,
-    make_chain_inputs,
 )
 
 
@@ -35,8 +34,7 @@ def run(smoke: bool, out: List[str], ctx=None) -> None:
     dims = tuple(d * scale for d in (48, 96, 12, 128, 24, 96, 48))
     algs = generate_chain_algorithms(dims)
     flops = flops_table(algs)
-    mats = make_chain_inputs(dims, seed=0)
-    workloads = build_workloads(algs, mats, warmup=True)
+    workloads = chain_site(dims).workloads(seed=0)
     timer = WallClockTimer(workloads)
 
     single = {n: timer.measure(n) for n in workloads}

@@ -78,7 +78,7 @@ def run(smoke: bool, out: List[str], ctx=None) -> None:
     for fam_name in fams:
         size = int(512 * scale) if fam_name != "bilinear" else int(1024 * scale)
         fam = FAMILIES[fam_name](size)
-        workloads = fam.workloads(size=size)
+        workloads = fam.workloads()
         flops_by_fam[fam_name] = fam.flops_table()
         timer = WallClockTimer(workloads)
         single = {n: timer.measure(n) for n in workloads}

@@ -26,10 +26,9 @@ from repro.core import (
 )
 from repro.expressions import (
     ChainInstance,
-    build_workloads,
+    chain_site,
     flops_table,
     get_instance,
-    make_chain_inputs,
 )
 
 
@@ -84,8 +83,7 @@ def chain_setup(instance_name: str, smoke: bool, seed: int = 0):
     """(instance, algorithms, workloads table, flops table)."""
     inst = get_instance(instance_name, smoke=smoke)
     algs = inst.algorithms()
-    mats = make_chain_inputs(inst.dims, seed=seed)
-    workloads = build_workloads(algs, mats, jit=True, warmup=True)
+    workloads = chain_site(inst.dims).workloads(seed)
     return inst, algs, workloads, flops_table(algs)
 
 

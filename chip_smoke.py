@@ -143,23 +143,11 @@ REFERENCES: Dict[str, Callable[..., np.ndarray]] = {
 
 def instance_inputs(inst: Any) -> List[Any]:
     """The device arrays an instance's workloads are built on, made again
-    from its seed by the builders the families use."""
+    from its seed by the family's site."""
+    from repro.core.family import get_family
+
     p = inst.params
-    if inst.family == "kernel_variants":
-        from repro.core.family import get_family
-
-        return get_family(inst.family).variant_site(p).make_inputs(int(p["seed"]))
-    if inst.family == "chain":
-        from repro.expressions.algorithms import make_chain_inputs
-        from repro.expressions.instances import random_instance
-
-        chain = random_instance(int(p["n_matrices"]), int(p["lo"]), int(p["hi"]),
-                                seed=int(p["seed"]))
-        return make_chain_inputs(chain.dims, seed=int(p["seed"]))
-    from repro.expressions.generalized import FAMILIES as GENERALIZED
-
-    size = int(p["size"])
-    return GENERALIZED[inst.family](n=size).make_inputs(size, int(p["seed"]))
+    return list(get_family(inst.family).variant_site(p).make_inputs(int(p["seed"])))
 
 
 def verify(instances: List[Any]) -> Dict[str, float]:

@@ -17,10 +17,9 @@ from repro.core import (
     relative_flops,
 )
 from repro.expressions import (
-    build_workloads,
+    chain_site,
     flops_table,
     get_instance,
-    make_chain_inputs,
 )
 
 
@@ -34,8 +33,7 @@ def main() -> None:
     algs = inst.algorithms()
     print(f"instance {inst.name} dims={inst.dims}: {len(algs)} algorithms")
 
-    mats = make_chain_inputs(inst.dims)
-    workloads = build_workloads(algs, mats, warmup=True)
+    workloads = chain_site(inst.dims).workloads(seed=0)
     flops = flops_table(algs)
     rf = relative_flops(flops)
 

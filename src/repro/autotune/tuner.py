@@ -42,7 +42,7 @@ from repro.core import (
     initial_hypothesis_by_time,
 )
 
-from .variants import VariantSite
+from repro.core.programs import VariantSite
 
 
 @dataclasses.dataclass
@@ -96,7 +96,7 @@ def prepare_site(
 ) -> CampaignSite:
     """Paper Sec. I steps 1-4 on a variant site: warm runs, RT filtering,
     initial hypothesis by single-run time."""
-    workloads = site.workloads(seed=seed, warmup=True)
+    workloads = site.workloads(seed)
     timer = WallClockTimer(workloads)
     single = {name: timer.measure(name) for name in workloads}
     flops = dict(site.flops_table())

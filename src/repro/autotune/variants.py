@@ -1,8 +1,7 @@
-"""Variant sites: sets of mathematically equivalent implementations.
-
-A :class:`VariantSite` is the framework's unit of algorithm choice — the
-exact object the paper's methodology ranks. Every variant carries an
-analytic FLOP count, so the FLOPs-discriminant test applies directly:
+"""Variant sites of the repo's kernels and model layers: sets of
+mathematically equivalent implementations, each a
+:class:`~repro.core.programs.VariantSite` whose variants carry an analytic
+FLOP count, so the FLOPs-discriminant test applies directly:
 
 * ``attention_impl``     — reference / chunked:
   equal math; chunked wastes masked-block FLOPs, reference materialises the
@@ -22,53 +21,21 @@ analytic FLOP count, so the FLOPs-discriminant test applies directly:
 * ``ssd_chunk``          — Mamba-2 chunk length: equal leading-order FLOPs.
 * ``matmul_blocks``      — Pallas GEMM tile shapes: equal FLOPs exactly;
   native on a TPU, interpreted elsewhere (:func:`pallas_interpret`).
-* matrix chains          — the paper's own site (repro.expressions).
+
+Every variant's program comes from :func:`repro.core.programs.program`,
+keyed by its static values, so a second instance of a site builds nothing.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.measure import warm
-from repro.core.spans import ProgramCache, count, named
-from repro.models import ModelConfig
-from repro.models.flops import param_counts
-
-Thunk = Callable[[], Any]
-
-
-@dataclasses.dataclass(frozen=True)
-class Variant:
-    name: str
-    flops: float                     # analytic, per workload execution
-    build: Callable[..., Thunk]      # (*arrays) -> zero-arg timed thunk, unwarmed
-    meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-
-@dataclasses.dataclass(frozen=True)
-class VariantSite:
-    name: str
-    variants: tuple
-    make_inputs: Callable[[int], List[jax.Array]]   # seed -> arrays
-
-    def flops_table(self) -> Dict[str, float]:
-        return {v.name: v.flops for v in self.variants}
-
-    def workloads(self, seed: int = 0, warmup: bool = True) -> Dict[str, Thunk]:
-        """name -> thunk on the inputs of ``seed``; with ``warmup`` each
-        thunk runs once here (:func:`repro.core.measure.warm`), the only
-        call before the timer's."""
-        arrays = self.make_inputs(seed)
-        table = {v.name: v.build(*arrays) for v in self.variants}
-        if warmup:
-            warm(table)
-        return table
+from repro.core.programs import Variant, VariantSite, program, runner
+from repro.core.spans import count
 
 
 def pallas_interpret(interpret: Optional[bool] = None) -> bool:
@@ -87,42 +54,13 @@ def pallas_interpret(interpret: Optional[bool] = None) -> bool:
     return expected
 
 
-def _runner(program, *arrays):
-    """The zero-arg thunk that runs ``program`` on ``arrays`` and waits for
-    the device, returned unwarmed: it has not run, and compiles on its first
-    call, the table builder's warm run (:meth:`VariantSite.workloads`)."""
-
-    def run():
-        return jax.block_until_ready(program(*arrays))
-
-    return run
-
-
-def _thunk(fn, *arrays):
-    """:func:`_runner` of ``fn`` jitted, unwarmed; ``fn``'s name names the
-    program (:func:`repro.core.spans.named`). A closure built per instance
-    is traced per instance."""
-    return _runner(jax.jit(fn), *arrays)
-
-
-#: XLA's dot as the program ``jit_xla_dot``, jitted once so that every
-#: instance finds it compiled
-_xla_dot = jax.jit(named("xla_dot", jnp.dot))
-
-
-# ------------------------------------------------------- attention site ----
-
-#: the attention site's programs, one per variant and static arguments
-_ATTENTION_PROGRAMS = ProgramCache(64)
-
-
-def _attention_program(name: str, fn: Callable[..., Any], **static: Any):
-    """``fn(q, k, v, **static)`` as the jitted program ``jit_attention_<name>``,
-    built once per process: a second instance finds it built, and ``jax.jit``
-    compiles it once per shape."""
-    key = (name, fn, tuple(sorted(static.items())))
-    return _ATTENTION_PROGRAMS.get(
-        key, lambda: jax.jit(named(f"attention_{name}", functools.partial(fn, **static))))
+def _attention(name: str, fn, **static):
+    """The builder of attention variant ``name``: an unwarmed runner of
+    ``fn(q, k, v, **static)`` as the program ``jit_attention_<name>``, kept
+    under its static values."""
+    return lambda q, k, v: runner(
+        program(f"attention_{name}", functools.partial(fn, **static), *sorted(static.items())),
+        q, k, v)
 
 
 def attention_site(
@@ -142,21 +80,18 @@ def attention_site(
 
     f_scores = 2.0 * b * h * s * s * d * 2
 
-    def build(name, fn, **static):
-        return lambda q, k, v: _runner(_attention_program(name, fn, **static), q, k, v)
-
     return VariantSite(
         name=f"attention[b{b} s{s} h{h}kv{kv} d{d}]",
         variants=(
             Variant("reference_grouped", f_scores,
-                    build("reference_grouped", attention_reference, gqa="grouped")),
+                    _attention("reference_grouped", attention_reference, gqa="grouped")),
+            # K/V repeated to H heads: more traffic, the same FLOPs
             Variant("reference_broadcast", f_scores,
-                    build("reference_broadcast", attention_reference, gqa="broadcast"),
-                    {"extra_traffic": "K/V repeated to H heads"}),
+                    _attention("reference_broadcast", attention_reference, gqa="broadcast")),
+            # O(s * block) memory, not O(s^2)
             Variant("chunked_flash", f_scores,
-                    build("chunked_flash", attention_chunked,
-                          q_block=min(256, s), kv_block=min(512, s)),
-                    {"memory": "O(s*block) not O(s^2)"}),
+                    _attention("chunked_flash", attention_chunked,
+                               q_block=min(256, s), kv_block=min(512, s))),
         ),
         make_inputs=inputs,
     )
@@ -283,34 +218,30 @@ def attention_layer_site(
     def build(name):
         bq, bk = _blocks(name, s, window)
         if name.startswith("flash_"):
-            program = _attention_program(name, flash_attention, causal=True, window=window,
-                                         block_q=bq, block_k=bk, interpret=interpret)
-        elif name == "local_chunked":
-            program = _attention_program(name, attention_local_chunked, window=window,
-                                         q_block=bq)
-        elif name == "chunked":
-            program = _attention_program(name, attention_chunked, causal=True,
-                                         window=window, q_block=bq, kv_block=bk)
-        else:
-            program = _attention_program(name, attention_reference, causal=True,
-                                         window=window, gqa=name[len("reference_"):])
+            make = _attention(name, flash_attention, causal=True, window=window,
+                              block_q=bq, block_k=bk, interpret=interpret)
+            live, total = grid_steps(s, s, block_q=bq, block_k=bk, causal=True, window=window)
 
-        def make(q, k, v):
-            if name.startswith("flash_"):
-                live, total = grid_steps(s, s, block_q=bq, block_k=bk, causal=True,
-                                         window=window)
+            def counted(q, k, v):
                 count("flash_grid_steps", b * h * total)
                 count("flash_live_steps", b * h * live)
-            return _runner(program, q, k, v)
+                return make(q, k, v)
 
-        return make
+            return counted
+        if name == "local_chunked":
+            return _attention(name, attention_local_chunked, window=window, q_block=bq)
+        if name == "chunked":
+            return _attention(name, attention_chunked, causal=True, window=window,
+                              q_block=bq, kv_block=bk)
+        return _attention(name, attention_reference, causal=True, window=window,
+                          gqa=name[len("reference_"):])
 
     kind = "full" if window is None else f"swa{window}"
     return VariantSite(
         name=f"attention[{kind} b{b} s{s} h{h}kv{kv} d{d}]",
         variants=tuple(
             Variant(name, attention_flops(name, b=b, s=s, h=h, d=d, window=window),
-                    build(name), {"blocks": _blocks(name, s, window)})
+                    build(name))
             for name in attention_algorithms(b, s, h, window)
         ),
         make_inputs=inputs,
@@ -342,17 +273,17 @@ def moe_dispatch_site(
     f_gather = f_expert * top_k * cfg.moe_capacity_factor + 2.0 * tokens * d * e
     f_dense = f_expert * e + 2.0 * tokens * d * e
 
-    def gather(x):
-        return _thunk(named("gather", lambda x: moe_gather(cfg, params, x)[0]), x)
-
-    def dense(x):
-        return _thunk(named("dense", lambda x: moe_dense(cfg, params, x)[0]), x)
+    def build(name, dispatch):
+        # the expert weights are arguments, so one program serves every
+        # instance of the same config
+        return lambda x: runner(
+            program(name, lambda params, x: dispatch(cfg, params, x)[0], cfg), params, x)
 
     return VariantSite(
         name=f"moe_dispatch[T{tokens} E{e} k{top_k}]",
         variants=(
-            Variant("gather", f_gather, gather, {"traffic": "scatter/gather"}),
-            Variant("dense", f_dense, dense, {"flops": f"{e/top_k:.0f}x active"}),
+            Variant("gather", f_gather, build("gather", moe_gather)),
+            Variant("dense", f_dense, build("dense", moe_dense)),
         ),
         make_inputs=inputs,
     )
@@ -377,13 +308,10 @@ def ssd_chunk_site(
         return [x, dt, a_log, bm, cm]
 
     def make(chunk):
-        def build(x, dt, a_log, bm, cm):
-            return _thunk(
-                named(f"chunk_{chunk}", lambda x, dt, a_log, bm, cm:
-                      ssd_chunked(x, dt, a_log, bm, cm, chunk)[0]),
-                x, dt, a_log, bm, cm,
-            )
-        return build
+        def fn(x, dt, a_log, bm, cm):
+            return ssd_chunked(x, dt, a_log, bm, cm, chunk)[0]
+
+        return lambda *arrays: runner(program(f"chunk_{chunk}", fn, chunk), *arrays)
 
     def flops(q):
         return b * s * h * (2.0 * q * n + 2.0 * q * p + 4.0 * p * n)
@@ -391,7 +319,7 @@ def ssd_chunk_site(
     return VariantSite(
         name=f"ssd_chunk[s{s} h{h} p{p} n{n}]",
         variants=tuple(
-            Variant(f"chunk_{q}", flops(q), make(q), {"chunk": q}) for q in chunks
+            Variant(f"chunk_{q}", flops(q), make(q)) for q in chunks
         ),
         make_inputs=inputs,
     )
@@ -405,9 +333,7 @@ def matmul_blocks_site(
     dtype=jnp.float32,
     interpret: Optional[bool] = None,
 ) -> VariantSite:
-    # from the defining module: the package-level name can be shadowed by
-    # the like-named subpackage after a dotted import (see repro.kernels)
-    from repro.kernels.matmul.ops import matmul
+    from repro.kernels.matmul.matmul import matmul_kernel
 
     interpret = pallas_interpret(interpret)
 
@@ -420,16 +346,15 @@ def matmul_blocks_site(
     f = 2.0 * m * k * n
 
     def make(bm, bn, bk):
-        program = functools.partial(matmul, block_m=bm, block_n=bn, block_k=bk,
-                                    use_kernel=True, interpret=interpret)
-        return lambda a, b_: _runner(program, a, b_)
+        # every tiling is the program ``jit_matmul``, kept under its tiles
+        kernel = functools.partial(matmul_kernel, block_m=bm, block_n=bn, block_k=bk,
+                                   interpret=interpret)
+        return lambda a, b_: runner(program("matmul", kernel, bm, bn, bk, interpret), a, b_)
 
     variants = tuple(
-        Variant(f"blocks_{bm}x{bn}x{bk}", f, make(bm, bn, bk),
-                {"tiles": (bm, bn, bk)})
-        for bm, bn, bk in blocks
+        Variant(f"blocks_{bm}x{bn}x{bk}", f, make(bm, bn, bk)) for bm, bn, bk in blocks
     ) + (
-        Variant("xla_dot", f, lambda a, b_: _runner(_xla_dot, a, b_)),
+        Variant("xla_dot", f, lambda a, b_: runner(program("xla_dot", jnp.dot), a, b_)),
     )
     return VariantSite(
         name=f"matmul[{m}x{k}x{n}]", variants=variants, make_inputs=inputs

@@ -23,23 +23,22 @@ An :class:`AlgorithmFamily` supplies, for one family name:
     hooks (:func:`repro.core.sweep.synthetic_instance_model`) consume only
     the FLOP table and kernel counts, so cost-model census workers never
     build a single jax array.
+``variant_site``
+    the one build hook: the instance's algorithms as a
+    :class:`~repro.core.programs.VariantSite`. The builder ``entry``
+    returns is its ``workloads(seed)``, and ``explain_workloads`` builds
+    the same table for only the algorithms an explanation involves.
 ``decompose``
     kernels per algorithm purely from the instance's ``params`` row — the
     explainer's offline rebuild path (no jax, no re-measurement).
-``explain_workloads``
-    jitted+warmed whole-algorithm workloads for only the algorithms an
-    explanation involves (families with large enumerations override this
-    to build selectively).
 ``grid_from_args``
     the family's slice of the planner's CLI namespace (None = the family
     is not part of this plan).
 
-Five synthetic families (the paper's chain plus the beyond-chain identity
-families) are registered here bit-identically to their pre-registry
-implementations, alongside ``kernel_variants`` — the first *measured*
-family, whose algorithms are kernel variants (Pallas matmul tile shapes,
-fused vs unfused attention, SSD chunk lengths) of the same math, wrapping
-the autotuner's :class:`~repro.autotune.variants.VariantSite` objects.
+Registered here: the paper's chain, the four beyond-chain identity
+families, and ``kernel_variants``, whose algorithms are kernel variants
+(Pallas matmul tile shapes, fused vs unfused attention, SSD chunk lengths)
+of the same math, the sites of :mod:`repro.autotune.variants`.
 """
 
 from __future__ import annotations
@@ -107,6 +106,12 @@ class AlgorithmFamily:
         calling the returned builder may import jax."""
         raise NotImplementedError
 
+    def variant_site(self, params: Mapping[str, Any]):
+        """The instance's algorithms as a
+        :class:`~repro.core.programs.VariantSite`; may import jax (workload
+        build time only)."""
+        raise NotImplementedError
+
     def decompose(self, params: Mapping[str, Any]) -> Dict[str, Any]:
         """KernelSpecs per algorithm, purely from the params row."""
         raise NotImplementedError
@@ -114,13 +119,11 @@ class AlgorithmFamily:
     def explain_workloads(
         self, inst: InstanceSpec, involved: Sequence[str]
     ) -> Dict[str, Callable[[], Any]]:
-        """Jitted+warmed workloads for ONLY the involved algorithms.
-        Default: build the full instance and filter — fine for families
-        with a handful of variants; families that enumerate dozens of
-        algorithms override this to compile selectively."""
-        _, _, build_workloads = self.entry(inst)
-        whole = build_workloads()
-        return {alg: whole[alg] for alg in involved}
+        """Warmed workloads of ONLY the involved algorithms, on the inputs
+        the census measured: a chain enumerates dozens of algorithms, and
+        an explanation needs its winner and loser."""
+        site = self.variant_site(inst.params).only(involved)
+        return site.workloads(int(inst.params["seed"]))
 
 
 # --------------------------------------------------------------- registry ---
@@ -208,16 +211,16 @@ class ChainFamily(AlgorithmFamily):
         kernels = kernels_to_compact(
             {a.name: decompose_chain(dims, a.steps) for a in algs}
         )
-
-        def build_workloads() -> Dict[str, Callable[[], Any]]:
-            from repro.expressions.algorithms import build_workloads as bw
-            from repro.expressions.algorithms import make_chain_inputs
-
-            mats = make_chain_inputs(chain.dims, seed=int(p["seed"]))
-            return bw(algs, mats, warmup=True)
-
         meta = {"size": size, "dims": dims, "kernels": kernels}
-        return flops, meta, build_workloads
+        return flops, meta, lambda: self.variant_site(p).workloads(int(p["seed"]))
+
+    def variant_site(self, params: Mapping[str, Any]):
+        from repro.expressions.algorithms import chain_site
+        from repro.expressions.instances import random_instance
+
+        return chain_site(random_instance(
+            int(params["n_matrices"]), int(params["lo"]), int(params["hi"]),
+            seed=int(params["seed"])).dims)
 
     def decompose(self, params: Mapping[str, Any]) -> Dict[str, Any]:
         from repro.explain.decompose import _chain_instance_dims, decompose_chain_dims
@@ -227,24 +230,6 @@ class ChainFamily(AlgorithmFamily):
             int(params["seed"]),
         )
         return decompose_chain_dims(dims)
-
-    def explain_workloads(
-        self, inst: InstanceSpec, involved: Sequence[str]
-    ) -> Dict[str, Callable[[], Any]]:
-        """A chain instance enumerates dozens of algorithms; compiling all
-        of them to extract a winner/loser pair would dominate every
-        wall-clock explanation, so chains build the involved thunks
-        selectively."""
-        from repro.expressions.algorithms import build_workloads, make_chain_inputs
-        from repro.expressions.instances import random_instance
-
-        p = inst.params
-        chain = random_instance(
-            int(p["n_matrices"]), int(p["lo"]), int(p["hi"]), seed=int(p["seed"])
-        )
-        algs = {a.name: a for a in chain.algorithms()}
-        mats = make_chain_inputs(chain.dims, seed=int(p["seed"]))
-        return build_workloads([algs[alg] for alg in involved], mats)
 
 
 # ---------------------------------------------------- generalized families ---
@@ -278,19 +263,18 @@ class GeneralizedFamily(AlgorithmFamily):
 
     def entry(self, inst: InstanceSpec) -> Entry:
         from repro.explain.decompose import decompose_generalized, kernels_to_compact
-        from repro.expressions.generalized import FAMILIES as GEN
 
         p = inst.params
         size = int(p["size"])
-        family = GEN[inst.family](n=size)
-        flops = family.flops_table()
+        flops = self.variant_site(p).flops_table()
         kernels = kernels_to_compact(decompose_generalized(inst.family, size))
-
-        def build_workloads() -> Dict[str, Callable[[], Any]]:
-            return family.workloads(size, seed=int(p["seed"]), warmup=True)
-
         meta = {"size": size, "dims": None, "kernels": kernels}
-        return flops, meta, build_workloads
+        return flops, meta, lambda: self.variant_site(p).workloads(int(p["seed"]))
+
+    def variant_site(self, params: Mapping[str, Any]):
+        from repro.expressions.generalized import FAMILIES as GEN
+
+        return GEN[self.name](n=int(params["size"]))
 
     def decompose(self, params: Mapping[str, Any]) -> Dict[str, Any]:
         from repro.explain.decompose import decompose_generalized
@@ -534,16 +518,10 @@ class KernelVariantsFamily(AlgorithmFamily):
         flops = {name: sum(k.flops for k in ks) for name, ks in cfg["kernels"].items()}
         kernels = kernels_to_compact(cfg["kernels"])
 
-        def build_workloads() -> Dict[str, Callable[[], Any]]:
-            return self.variant_site(p).workloads(seed=int(p["seed"]), warmup=True)
-
         meta = {"size": int(p["size"]), "dims": cfg["dims"], "kernels": kernels}
-        return flops, meta, build_workloads
+        return flops, meta, lambda: self.variant_site(p).workloads(int(p["seed"]))
 
-    @staticmethod
-    def variant_site(params: Mapping[str, Any]):
-        """The instance's wrapped VariantSite (imports jax: workload build
-        time only)."""
+    def variant_site(self, params: Mapping[str, Any]):
         from repro.autotune import variants
 
         cfg = _instance_site_config(params)

@@ -4,9 +4,9 @@ The paper measures wall-clock execution times of Julia/MKL programs; the
 methodology itself is agnostic to *where* the numbers come from. We keep the
 measurement layer pluggable:
 
-* :class:`WallClockTimer` — times a callable with ``time.perf_counter``
-  (used at CPU/smoke scale; includes a warm-up phase "to exclude library
-  overheads", paper Sec. I step 1 — for JAX this absorbs jit compilation).
+* :class:`WallClockTimer` — times a callable with ``time.perf_counter``;
+  its workloads come warmed "to exclude library overheads" (paper Sec. I
+  step 1; :func:`repro.core.programs.warm` absorbs jit compilation).
 * :class:`SimulatedTimer` — draws from controlled distributions. Used by the
   benchmarks to reproduce the paper's turbo-boost study: a *bimodal* profile
   models a processor alternating between frequency levels (paper Fig. 6).
@@ -26,8 +26,6 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
-
-from .spans import count
 
 
 def rng_state(rng: np.random.Generator) -> Dict[str, Any]:
@@ -164,10 +162,6 @@ class Timer:
     def measure_many(self, name: str, m: int) -> List[float]:
         return [self.measure(name) for _ in range(m)]
 
-    def warmup(self, name: str, reps: int = 1) -> None:
-        for _ in range(reps):
-            self.measure(name)
-
     def snapshot(self) -> Any:
         """Opaque rollback token for transactional measurement batches
         (None for stateless backends). Stateful backends (RNG-driven)
@@ -179,16 +173,6 @@ class Timer:
         return None
 
 
-def warm(workloads: Mapping[str, Callable[[], object]]) -> None:
-    """The single warm run per algorithm (paper Sec. I step 1): each
-    workload called once, untimed, so that compilation ("library
-    overheads") never lands in a timed region. Counts ``warm_calls`` into
-    the active span sink, one per workload."""
-    for fn in workloads.values():
-        fn()
-        count("warm_calls")
-
-
 class WallClockTimer(Timer):
     """Times real callables.
 
@@ -197,8 +181,8 @@ class WallClockTimer(Timer):
     workloads:
         name -> zero-arg callable executing the algorithm once. For JAX
         workloads the callable must block on the result
-        (``jax.block_until_ready``) — :mod:`repro.expressions.algorithms`
-        builders do this. The first measurement of each workload verifies
+        (``jax.block_until_ready``) — :func:`repro.core.programs.runner`
+        does this. The first measurement of each workload verifies
         the contract (see below); ``check_blocking=False`` opts out.
 
     A workload that dispatches asynchronously and returns before the result

@@ -7,9 +7,9 @@ plane beside the device's operations; ``args`` (a ``uid``, say) become event
 stats. Where ``collect(timings)`` made a dict the active sink and ``key`` is
 given, the span's seconds are added to ``timings[key]``, so code deep in the
 call tree reports into the campaign's dict without new parameters;
-``count(key)`` adds a count there the same way. ``ProgramCache`` keeps the
-jitted programs of a process and counts ``programs_built`` and
-``programs_reused``.
+``count(key)`` adds a count there the same way; the program cache
+(:mod:`repro.core.programs`) counts ``programs_built`` and
+``programs_reused`` through it.
 
 JAX is never imported here: the annotation is emitted only once something
 else has imported ``jax``, so the ``cost_model`` census stays jax-free.
@@ -18,12 +18,10 @@ else has imported ``jax``, so the ``cost_model`` census stays jax-free.
 from __future__ import annotations
 
 import sys
-import threading
 import time
-from collections import OrderedDict
 from contextlib import contextmanager
 from contextvars import ContextVar
-from typing import Any, Callable, Dict, Hashable, Iterator, Mapping, Optional
+from typing import Any, Dict, Iterator, Mapping, Optional
 
 _SINK: ContextVar[Optional[Dict[str, float]]] = ContextVar("repro_span_sink", default=None)
 
@@ -77,54 +75,3 @@ def count(key: str, n: float = 1) -> None:
     sink = _SINK.get()
     if sink is not None:
         sink[key] = sink.get(key, 0.0) + n
-
-
-def named(name: str, fn: Callable[..., Any]) -> Callable[..., Any]:
-    """``fn`` under ``name``, so that ``jax.jit`` calls its program
-    ``jit_<name>`` and the device trace says which algorithm ran. Each call
-    makes a new function, and ``jax.jit`` caches compiled programs by
-    function: name a shared function once and keep the result, as
-    :class:`ProgramCache` does for the chain and generalized programs."""
-
-    def program(*args: Any) -> Any:
-        return fn(*args)
-
-    program.__name__ = program.__qualname__ = name
-    return program
-
-
-class ProgramCache:
-    """The jitted programs of a process, one per key, least recently used
-    first out beyond ``maxsize``.
-
-    ``get(key, build)`` returns the program kept under ``key``, or keeps and
-    returns ``build()``; it counts ``programs_built`` or ``programs_reused``
-    into the active sink. Keep under one key only programs that differ in
-    nothing but their arguments: ``jax.jit`` keeps one executable per shape
-    signature under each, so nothing else belongs in the key."""
-
-    def __init__(self, maxsize: int) -> None:
-        self.maxsize = maxsize
-        self._programs: "OrderedDict[Hashable, Any]" = OrderedDict()
-        self._lock = threading.Lock()
-
-    def get(self, key: Hashable, build: Callable[[], Any]) -> Any:
-        with self._lock:
-            program = self._programs.get(key)
-            if program is not None:
-                self._programs.move_to_end(key)
-        if program is not None:
-            count("programs_reused")
-            return program
-        program = build()
-        with self._lock:
-            program = self._programs.setdefault(key, program)
-            self._programs.move_to_end(key)
-            while len(self._programs) > self.maxsize:
-                self._programs.popitem(last=False)
-        count("programs_built")
-        return program
-
-    def clear(self) -> None:
-        with self._lock:
-            self._programs.clear()

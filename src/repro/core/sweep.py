@@ -955,12 +955,13 @@ def _wall_clock_timers(
     spec: SweepSpec, instances: Mapping[str, InstanceSpec], uids: Iterable[str]
 ) -> Dict[str, Timer]:
     """Rebuild wall-clock backends for a resumed engine chunk (callables do
-    not serialize; everything derives from the spec)."""
+    not serialize; everything derives from the spec), each warmed under
+    ``session.warmup`` as a fresh one is."""
     timers: Dict[str, Timer] = {}
     for uid in uids:
         inst = instances[uid]
         flops, _, build_workloads = instance_entry(inst)
-        timers[uid] = WallClockTimer(build_workloads())
+        timers[uid] = build_timer(spec, inst, flops, build_workloads)
     return timers
 
 
@@ -1020,9 +1021,9 @@ def run_chunked_campaign(
     candidate filter) inside ``build_s``; ``sample_s`` (``session.sample``:
     the timer's samples) and ``analyse_s`` (``session.analyse``: Procedure
     2-3) inside ``step_s``. Also ``steps`` / ``records`` counts, the
-    ``programs_built`` / ``programs_reused`` counts of the program caches,
+    ``programs_built`` / ``programs_reused`` counts of the program cache
     and ``warm_calls``, one per algorithm warmed
-    (:func:`repro.core.measure.warm`). Pure observability — nothing here
+    (:mod:`repro.core.programs`). Pure observability — nothing here
     feeds back into measurements or records.
 
     ``faults`` is the chaos hook: the ``campaign.step`` injection site is

@@ -102,11 +102,14 @@ def micro_points_wall_clock(
     :func:`repro.explain.decompose.build_kernel_workload`)."""
     import time
 
+    from repro.core.programs import warm
+
     from .decompose import build_kernel_workload
 
     points: List[CalibrationPoint] = []
     for n in sizes:
         fn = build_kernel_workload(KernelSpec("gemm", (n, n, n)), seed=seed)
+        warm({"gemm": fn})
         samples = []
         for _ in range(max(3, reps)):
             t0 = time.perf_counter()

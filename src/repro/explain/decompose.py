@@ -274,12 +274,17 @@ def kernels_from_record(record: Mapping[str, Any]) -> Dict[str, List[KernelSpec]
 
 
 def build_kernel_workload(kernel: KernelSpec, seed: int = 0) -> Callable[[], Any]:
-    """A zero-arg jitted JAX callable executing ONE kernel in isolation on
-    fresh random operands (blocking, warmed up) — the wall-clock backend's
-    segment re-measurement. Imports jax lazily."""
+    """A zero-arg blocking callable executing ONE kernel in isolation on
+    fresh random operands — the wall-clock backend's segment
+    re-measurement — returned unwarmed (:func:`repro.core.programs.warm`).
+    Its program ``jit_kernel_<op>`` is kept once per process, keyed by the
+    op (``jax.jit`` compiles each shape under it once). Imports jax
+    lazily."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from repro.core.programs import program, runner
 
     def normal(key, shape):
         return jax.random.normal(key, shape, jnp.float32) / np.sqrt(max(shape[-1], 1))
@@ -331,10 +336,4 @@ def build_kernel_workload(kernel: KernelSpec, seed: int = 0) -> Callable[[], Any
     else:  # pragma: no cover - _OPS and this table are kept in sync
         raise ValueError(f"no workload builder for op {op!r}")
 
-    jitted = jax.jit(fn)
-    jax.block_until_ready(jitted(*args))  # compile outside timed regions
-
-    def run() -> Any:
-        return jax.block_until_ready(jitted(*args))
-
-    return run
+    return runner(program(f"kernel_{op}", fn), *args)

@@ -16,7 +16,7 @@ Backends follow the census: a ``cost_model``/``simulated`` census is
 explained on the same synthetic machine (segment costs reconstructed from
 the record's ``kernels``/``flops``/``base_seed`` pointers — zero census
 re-runs, zero jax imports); a ``wall_clock`` census re-measures each kernel
-in isolation with fresh jitted workloads.
+in isolation, on fresh operands.
 """
 
 from __future__ import annotations
@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.family import get_family
 from repro.core.faults import FaultPlan, active_plan
 from repro.core.measure import (
     CostModelTimer,
@@ -38,6 +39,7 @@ from repro.core.measure import (
     WallClockTimer,
     device_kind,
 )
+from repro.core.programs import warm
 from repro.core.session import MeasurementSession
 from repro.core.sweep import (
     LINE_CRC_MISMATCH,
@@ -372,18 +374,6 @@ def _build_timer(
     return SimulatedTimer(profiles, seed=noise_seed)
 
 
-def _whole_algorithm_workloads(
-    inst: InstanceSpec, involved: Sequence[str]
-) -> Dict[str, Callable[[], Any]]:
-    """Jitted+warmed workloads for ONLY the involved algorithms, resolved
-    through the family registry (families with large enumerations — chains
-    — override ``explain_workloads`` to build the involved pair
-    selectively instead of compiling everything)."""
-    from repro.core.family import get_family
-
-    return get_family(inst.family).explain_workloads(inst, involved)
-
-
 def _wall_clock_workloads(
     sweep_spec: SweepSpec,
     record: Mapping[str, Any],
@@ -402,11 +392,12 @@ def _wall_clock_workloads(
             "that measured it"
         )
     inst = record_to_instance(sweep_spec, record)
-    out = _whole_algorithm_workloads(inst, involved)
+    out = get_family(inst.family).explain_workloads(inst, involved)
     seed = int(record["index"])
-    for alg in involved:
-        for i, k in enumerate(kernels[alg]):
-            out[kernel_name(alg, i, k)] = build_kernel_workload(k, seed=seed)
+    segments = {kernel_name(alg, i, k): build_kernel_workload(k, seed=seed)
+                for alg in involved for i, k in enumerate(kernels[alg])}
+    warm(segments)
+    out.update(segments)
     return out
 
 

@@ -17,8 +17,7 @@ from typing import TYPE_CHECKING
 #: attribute name -> defining submodule
 _EXPORTS = {
     # algorithms (imports jax)
-    "build_algorithm_fn": "algorithms",
-    "build_workloads": "algorithms",
+    "chain_site": "algorithms",
     "make_chain_inputs": "algorithms",
     "reference_product": "algorithms",
     "verify_algorithms": "algorithms",
@@ -35,8 +34,6 @@ _EXPORTS = {
     "tree_label": "chain",
     # generalized (jax deferred to workload build time)
     "FAMILIES": "generalized",
-    "ExpressionFamily": "generalized",
-    "ExpressionVariant": "generalized",
     "bilinear_family": "generalized",
     "distributive_family": "generalized",
     "gram_family": "generalized",
@@ -74,8 +71,7 @@ def __dir__():
 
 if TYPE_CHECKING:  # pragma: no cover - static analysis only
     from .algorithms import (
-        build_algorithm_fn,
-        build_workloads,
+        chain_site,
         make_chain_inputs,
         reference_product,
         verify_algorithms,
@@ -94,8 +90,6 @@ if TYPE_CHECKING:  # pragma: no cover - static analysis only
     )
     from .generalized import (
         FAMILIES,
-        ExpressionFamily,
-        ExpressionVariant,
         bilinear_family,
         distributive_family,
         gram_family,

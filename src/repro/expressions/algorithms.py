@@ -1,36 +1,37 @@
 """Executable JAX implementations of chain algorithms.
 
 Each :class:`~repro.expressions.chain.ChainAlgorithm` lowers to a sequence of
-``jnp.dot`` calls executed in the algorithm's instruction order. The builder
-returns a zero-argument callable that blocks on the result
-(``block_until_ready``), suitable for :class:`repro.core.WallClockTimer`.
+``jnp.dot`` calls executed in the algorithm's instruction order.
+:func:`chain_site` makes a chain's algorithms a
+:class:`~repro.core.programs.VariantSite`, whose table of blocking, warmed
+thunks :class:`repro.core.WallClockTimer` measures.
 
 An algorithm's jitted program depends only on its name and its steps, never
-on the dims or the data, so each is built once per process
-(:func:`chain_program`) and every instance of the same chain length shares
-it; ``jax.jit`` still compiles one executable per shape signature.
+on the dims or the data, so it is kept under both
+(:func:`repro.core.programs.program`) and every instance of the same chain
+length shares it; ``jax.jit`` still compiles one executable per shape
+signature.
 
 Note on instruction order under XLA: independent GEMMs inside one jitted
 function may be reordered by the compiler, so two instruction orders of the
 same parenthesization typically compile to identical HLO — i.e. they are
 *equivalent algorithms*, which is exactly the situation the paper's
 three-way comparison is designed to detect (they should land in one
-performance class). The ``jit=False`` mode executes ops eagerly in the given
-order for settings where order effects (cache warmth) are under study.
+performance class). :func:`verify_algorithms` runs the steps eagerly, in
+order, as the reference of each algorithm's product.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Mapping, Optional, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.measure import warm
-from repro.core.spans import ProgramCache, named
+from repro.core.programs import Variant, VariantSite, program, runner
 
-from .chain import ChainAlgorithm, Step
+from .chain import ChainAlgorithm, Step, generate_chain_algorithms
 
 
 def make_chain_inputs(
@@ -70,62 +71,22 @@ def algorithm_fn(alg: ChainAlgorithm) -> Callable[..., jax.Array]:
     return fn
 
 
-#: Keyed by ``(alg.name, alg.steps)``: the 6 algorithms of a 4-matrix chain
-#: are 6 keys, and the chains of 6-8 matrices a few hundred.
-_PROGRAMS = ProgramCache(maxsize=256)
+def chain_site(dims: Sequence[int]) -> VariantSite:
+    """The chain ``dims`` as a variant site: one variant per algorithm
+    (:func:`~repro.expressions.chain.generate_chain_algorithms`), each the
+    program ``jit_chain_<name>``; inputs ``make_chain_inputs(dims, seed)``."""
+    dims = tuple(int(d) for d in dims)
 
+    def build(alg: ChainAlgorithm) -> Callable[..., Callable[[], jax.Array]]:
+        return lambda *mats: runner(
+            program(f"chain_{alg.name}", algorithm_fn(alg), alg.steps), *mats)
 
-def chain_program(alg: ChainAlgorithm) -> Callable[..., jax.Array]:
-    """``jax.jit`` of the algorithm as the program ``jit_chain_<name>``,
-    built once per process for each ``(alg.name, alg.steps)`` (at most 256
-    kept, the least recently used dropped first). The name is in the key
-    because the device trace reads it."""
-    return _PROGRAMS.get(
-        (alg.name, alg.steps),
-        lambda: jax.jit(named(f"chain_{alg.name}", algorithm_fn(alg))))
-
-
-def build_algorithm_fn(
-    alg: ChainAlgorithm,
-    matrices: Sequence[jax.Array],
-    jit: bool = True,
-) -> Callable[[], jax.Array]:
-    """Zero-arg callable running one algorithm to completion: with ``jit``
-    the process's shared :func:`chain_program` on these matrices, else the
-    steps eagerly in order."""
-    operands = {f"M{i}": m for i, m in enumerate(matrices)}
-
-    if jit:
-        jitted = chain_program(alg)
-        mats = tuple(matrices)
-
-        def run() -> jax.Array:
-            return jax.block_until_ready(jitted(*mats))
-
-        return run
-
-    def run_eager() -> jax.Array:
-        return jax.block_until_ready(_execute_steps(alg.steps, operands))
-
-    return run_eager
-
-
-def build_workloads(
-    algs: Sequence[ChainAlgorithm],
-    matrices: Sequence[jax.Array],
-    jit: bool = True,
-    warmup: bool = True,
-) -> Dict[str, Callable[[], jax.Array]]:
-    """name -> callable table for :class:`repro.core.WallClockTimer`.
-
-    With ``warmup=True`` each callable is executed once here
-    (:func:`repro.core.measure.warm`) so that jit compilation ("library
-    overheads", paper Sec. I step 1) never lands inside a timed region.
-    """
-    table = {alg.name: build_algorithm_fn(alg, matrices, jit=jit) for alg in algs}
-    if warmup:
-        warm(table)
-    return table
+    return VariantSite(
+        name=f"chain{list(dims)}",
+        variants=tuple(Variant(alg.name, float(alg.flops), build(alg))
+                       for alg in generate_chain_algorithms(dims)),
+        make_inputs=lambda seed: make_chain_inputs(dims, seed=seed),
+    )
 
 
 def reference_product(matrices: Sequence[jax.Array]) -> jax.Array:
@@ -145,6 +106,7 @@ def verify_algorithms(
     """Assert every algorithm computes the same product (mathematical
     equivalence — distinct parenthesizations differ only by fp rounding)."""
     ref = np.asarray(reference_product(matrices), dtype=np.float64)
+    operands = {f"M{i}": m for i, m in enumerate(matrices)}
     for alg in algs:
-        out = np.asarray(build_algorithm_fn(alg, matrices, jit=False)())
+        out = np.asarray(_execute_steps(alg.steps, operands))
         np.testing.assert_allclose(out, ref, rtol=rtol, atol=atol, err_msg=alg.name)

@@ -194,7 +194,14 @@ def generate_chain_algorithms(dims: Sequence[int]) -> List[ChainAlgorithm]:
     Algorithms are numbered in (FLOPs, tree-enumeration, extension) order so
     that ``algorithm0`` always computes the least FLOPs — mirroring the
     paper's convention that the minimum-FLOPs variants carry the low indices.
+    The enumeration of the 16 latest dims is kept: a census instance reads
+    its FLOP table and builds its programs from the same dims.
     """
+    return list(_chain_algorithms(tuple(int(d) for d in dims)))
+
+
+@functools.lru_cache(maxsize=16)
+def _chain_algorithms(dims: Tuple[int, ...]) -> Tuple[ChainAlgorithm, ...]:
     n = len(dims) - 1
     trees = enumerate_trees(n)
     # Stable sort trees by FLOPs so min-FLOPs algorithms get low indices.
@@ -205,7 +212,7 @@ def generate_chain_algorithms(dims: Sequence[int]) -> List[ChainAlgorithm]:
         tree_algs = algorithms_for_tree(tree, dims, idx)
         algs.extend(tree_algs)
         idx += len(tree_algs)
-    return algs
+    return tuple(algs)
 
 
 def dp_optimal_flops(dims: Sequence[int]) -> int:

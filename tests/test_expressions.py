@@ -8,7 +8,7 @@ from repro.core import relative_flops
 from repro.expressions import (
     ANOMALY_331,
     FIG3_75,
-    build_workloads,
+    chain_site,
     dp_optimal_flops,
     enumerate_trees,
     flops_table,
@@ -74,9 +74,8 @@ def test_instruction_orders_are_valid_toposorts():
 
 def test_workloads_block_and_run():
     inst = get_instance("fig3_75", smoke=True)
-    algs = inst.algorithms()
     mats = make_chain_inputs(inst.dims, seed=0)
-    table = build_workloads(algs, mats, jit=True, warmup=True)
+    table = chain_site(inst.dims).workloads(seed=0)
     ref = np.asarray(reference_product(mats))
     for name, fn in table.items():
         np.testing.assert_allclose(np.asarray(fn()), ref, rtol=2e-3, atol=2e-3)
@@ -89,7 +88,7 @@ def test_solve_family_flops_ordering():
     # variants compute the same solution
     import jax.numpy as jnp
 
-    w = fam.workloads(size=64, seed=0)
+    w = solve_family(64).workloads(seed=0)
     outs = {k: np.asarray(v()) for k, v in w.items()}
     for k in ("solve_lu", "solve_chol"):
         np.testing.assert_allclose(outs[k], outs["solve_inverse"], rtol=2e-2, atol=2e-2)

@@ -323,23 +323,33 @@ print("OK")
 # ------------------------------------------------------------ explainer ---
 
 def test_explain_workloads_defaults_to_entry_filter():
+    """An explanation builds, and warms, only the involved variants of the
+    family's site, on the instance's seed."""
+    from repro.core.programs import Variant, VariantSite
+
+    built, seeds = [], []
+
+    def variant(name, out):
+        def build(x):
+            built.append(name)
+            return lambda: out + x
+        return Variant(name, 1.0, build)
+
     class Toy(AlgorithmFamily):
         name = "toy-test-family"
         description = "toy"
 
-        def entry(self, inst):
-            wl = {"a": lambda: 1, "b": lambda: 2, "c": lambda: 3}
-            return ({"a": 1.0, "b": 1.0, "c": 1.0},
-                    {"size": 1, "dims": None, "kernels": {}},
-                    lambda: wl)
+        def variant_site(self, params):
+            return VariantSite("toy", (variant("a", 1), variant("b", 2), variant("c", 3)),
+                               lambda seed: seeds.append(seed) or [10])
 
     fam = Toy()
     out = fam.explain_workloads(
-        InstanceSpec(index=0, uid="t", family="toy-test-family", params={}),
-        ["b", "c"],
+        InstanceSpec(index=0, uid="t", family="toy-test-family", params={"seed": 4}),
+        ["c", "b"],
     )
-    assert sorted(out) == ["b", "c"]
-    assert out["b"]() == 2
+    assert list(out) == ["c", "b"] and built == ["c", "b"] and seeds == [4]
+    assert out["b"]() == 12
 
 
 # ------------------------------------------------------------ store kinds ---

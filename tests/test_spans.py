@@ -13,7 +13,8 @@ import sys
 
 import pytest
 
-from repro.core.spans import ProgramCache, collect, count, named, span
+from repro.core.programs import ProgramCache, named
+from repro.core.spans import collect, count, span
 from repro.core.sweep import SweepSpec, run_shard
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -174,30 +175,17 @@ def _chain_workloads(lo, hi, seed):
     return build
 
 
-def test_a_second_chain_instance_traces_nothing():
-    """The six programs of a 4-matrix chain are built once per process: a
-    second instance of the same dims, with other data, traces nothing."""
-    from repro.expressions import algorithms
-
-    algorithms._PROGRAMS.clear()
-    first, t1, traced1 = _traced_while(_chain_workloads(32, 32, seed=1))
-    second, t2, traced2 = _traced_while(_chain_workloads(32, 32, seed=2))
-    assert t1 == {"programs_built": 6, "warm_calls": 6}
-    assert sorted(n for n in traced1 if n.startswith("chain_")) == sorted(
-        f"chain_{name}" for name in first)
-    assert t2 == {"programs_reused": 6, "warm_calls": 6} and traced2 == []
-    assert set(first) == set(second) and len(second) == 6
-
-
 def test_a_shared_chain_program_retraces_per_shape():
     """Other dims reuse the programs kept under the same name and steps
     (names follow the FLOPs order, so a few steps differ), which trace again
     for the new shapes and still compute the chain's product."""
     import numpy as np
 
+    from repro.core.programs import PROGRAMS
     from repro.expressions.algorithms import make_chain_inputs, reference_product
     from repro.expressions.instances import random_instance
 
+    PROGRAMS.clear()
     _traced_while(_chain_workloads(32, 32, seed=1))
     table, t, traced = _traced_while(_chain_workloads(8, 24, seed=3))
     assert t["programs_reused"] >= 1 and t["programs_built"] + t["programs_reused"] == 6
@@ -212,19 +200,6 @@ def test_a_shared_chain_program_retraces_per_shape():
         np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
-def test_a_generalized_family_built_again_traces_nothing():
-    """The generalized families' programs are built once per name: a second
-    build of one family with another seed traces nothing."""
-    from repro.expressions import generalized
-
-    generalized._PROGRAMS.clear()
-    family = generalized.FAMILIES["gram"](n=32)
-    _, t1, traced1 = _traced_while(lambda: family.workloads(32, seed=1))
-    _, t2, traced2 = _traced_while(lambda: family.workloads(32, seed=2))
-    assert t1 == {"programs_built": 3, "warm_calls": 3} and traced1
-    assert t2 == {"programs_reused": 3, "warm_calls": 3} and traced2 == []
-
-
 def test_a_layer_instances_spans_carry_its_layer():
     from repro.core.session import MeasurementSession
     from repro.core.spans import instance_args
@@ -237,31 +212,91 @@ def test_a_layer_instances_spans_carry_its_layer():
     assert session._span_args() == {"uid": "u", "layer": "sliding"}
 
 
-def test_a_second_attention_layer_instance_builds_nothing(tiny_attention_model):
-    """The attention layer site's programs are built once per process: a
-    second instance of the layer, with other data, builds and traces
-    nothing; each flash variant's build counts its grid steps."""
-    from repro.autotune import variants
+def _gram(seed):
+    from repro.expressions.generalized import FAMILIES
+
+    return FAMILIES["gram"](n=32).workloads(seed)
+
+
+def _attention_layer(seed):
     from repro.core.family import InstanceSpec, get_family
 
-    def layer(seed):
-        inst = InstanceSpec(index=0, uid=f"attn-{seed}", family="kernel_variants", params={
-            "site": "attention", "config": "tiny-attention", "layer": "sliding",
-            "size": 512, "seed": seed})
-        return get_family("kernel_variants").entry(inst)[2]
+    inst = InstanceSpec(index=0, uid=f"attn-{seed}", family="kernel_variants", params={
+        "site": "attention", "config": "tiny-attention", "layer": "sliding",
+        "size": 512, "seed": seed})
+    return get_family("kernel_variants").entry(inst)[2]()
 
-    variants._ATTENTION_PROGRAMS.clear()
-    first, t1, traced1 = _traced_while(layer(1))
-    second, t2, traced2 = _traced_while(layer(2))
-    n = len(first)
-    assert n == 7 and t1["programs_built"] == n
-    assert sorted(x for x in traced1 if x.startswith("attention_")) == sorted(
-        f"attention_{name}" for name in first)
-    assert t2.pop("programs_reused") == n and "programs_built" not in t2 and traced2 == []
-    assert t2.pop("warm_calls") == n == t1["warm_calls"]
-    # 8 heads; 4 + 2 + 1 steps of the capped tilings, all live at s=512
-    assert t2 == {"flash_grid_steps": 56, "flash_live_steps": 56} == {
-        k: t1[k] for k in ("flash_grid_steps", "flash_live_steps")}
+
+def _matmul_site(seed):
+    from repro.autotune.variants import matmul_blocks_site
+
+    return matmul_blocks_site(m=128, k=128, n=128, blocks=((128, 128, 128),)).workloads(seed)
+
+
+def _moe_site(seed):
+    from repro.autotune.variants import moe_dispatch_site
+
+    return moe_dispatch_site(tokens=64, d=32, e=4, top_k=2, d_ff=16).workloads(seed)
+
+
+def _ssd_site(seed):
+    from repro.autotune.variants import ssd_chunk_site
+
+    return ssd_chunk_site(b=1, s=64, h=2, p=8, n=8, chunks=(16, 32)).workloads(seed)
+
+
+def _explainer_segments(seed):
+    """Two kernel segments of an explanation, warmed as the explainer warms
+    them."""
+    from repro.core.programs import warm
+    from repro.explain.decompose import KernelSpec, build_kernel_workload
+
+    segments = {k.label: build_kernel_workload(k, seed=seed)
+                for k in (KernelSpec("gemm", (8, 4, 2)), KernelSpec("syrk", (8, 4)))}
+    warm(segments)
+    return segments
+
+
+#: case -> (the table of instance ``seed``, the program names of a table)
+SECOND_INSTANCES = {
+    "chain": (lambda seed: _chain_workloads(32, 32, seed)(),
+              lambda table: [f"chain_{name}" for name in table]),
+    "gram": (_gram, list),
+    "attention_layer": (_attention_layer,
+                        lambda table: [f"attention_{name}" for name in table]),
+    "matmul_site": (_matmul_site, lambda table: ["matmul", "xla_dot"]),
+    "moe_site": (_moe_site, list),
+    "ssd_site": (_ssd_site, list),
+    "explainer_segment": (_explainer_segments, lambda table: ["kernel_gemm", "kernel_syrk"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SECOND_INSTANCES))
+def test_a_second_instance_builds_nothing(case, request):
+    """Every program is built once per process: a second instance of a
+    site, with other data, builds and traces nothing, and each table warms
+    every algorithm once. Each flash variant's build counts its grid steps."""
+    from repro.core.programs import PROGRAMS
+
+    if case == "attention_layer":
+        request.getfixturevalue("tiny_attention_model")
+    build, programs = SECOND_INSTANCES[case]
+    PROGRAMS.clear()
+    first, t1, traced1 = _traced_while(lambda: build(1))
+    second, t2, traced2 = _traced_while(lambda: build(2))
+    names = programs(first)
+    assert set(first) == set(second) and len(first) >= 2
+    assert t1.pop("programs_built") == len(names) and "programs_reused" not in t1
+    assert sorted(x for x in traced1 if x in names) == sorted(names)
+    assert t2.pop("programs_reused") == len(names) and "programs_built" not in t2
+    assert traced2 == []
+    assert t1.pop("warm_calls") == t2.pop("warm_calls") == len(first)
+    if case == "attention_layer":
+        # 8 heads; 4 + 2 + 1 steps of the capped tilings, all live at s=512
+        assert len(first) == 7
+        assert t2 == {"flash_grid_steps": 56, "flash_live_steps": 56} == t1
+    else:
+        assert t1 == t2 == {}
 
 
 # -------------------------------------------------------------- campaign ---
@@ -339,4 +374,4 @@ def test_spans_and_program_names_reach_the_profiler_trace(tmp_path):
         assert any(ev[1] == name for ev in events), name
     modules = {stats.get("hlo_module") for *_, stats in events if "hlo_op" in stats}
     chains = {m for m in modules if m and m.startswith("jit_chain_algorithm")}
-    assert chains and "jit_xla_dot" in modules, sorted(m for m in modules if m)
+    assert chains and {"jit_xla_dot", "jit_matmul"} <= modules, sorted(m for m in modules if m)

@@ -29,10 +29,9 @@ from repro.distributed.sharding import (  # noqa: E402
     tree_shardings,
 )
 from repro.expressions import (  # noqa: E402
-    build_workloads,
+    chain_site,
     flops_table,
     get_instance,
-    make_chain_inputs,
 )
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import (  # noqa: E402
@@ -141,7 +140,7 @@ def test_full_paper_pipeline_on_chain_instance():
     inst = get_instance("fig3_75", smoke=True)
     algs = inst.algorithms()
     flops = flops_table(algs)
-    workloads = build_workloads(algs, make_chain_inputs(inst.dims), warmup=True)
+    workloads = chain_site(inst.dims).workloads(seed=0)
     timer = WallClockTimer(workloads)
     single = {n: timer.measure(n) for n in workloads}
     res = measure_and_rank(

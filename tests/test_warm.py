@@ -1,14 +1,15 @@
-"""One warm run per algorithm, made in one place: the variant and algorithm
-builders return unwarmed thunks, and each table builder's
-``workloads(warmup=True)`` calls every program exactly once. Calls are
-counted through a wrapped program, never through timings."""
+"""One warm run per algorithm, made in one place: every variant's build
+returns an unwarmed thunk, and the table builder
+(:meth:`repro.core.programs.VariantSite.workloads`) calls every program
+exactly once. Calls are counted through the programs the process's cache
+hands out, never through timings."""
 
 import collections
 import dataclasses
-import importlib
 
 import pytest
 
+from repro.core import programs
 from repro.core.spans import collect
 
 
@@ -22,40 +23,6 @@ def _counting(calls, key, program):
     return counted
 
 
-def _matmul_blocks(monkeypatch, calls, request):
-    """The matmul site, interpreted on the CPU: the Pallas kernel counted
-    per tiling, XLA's dot under its own name."""
-    from repro.autotune import variants
-
-    ops = importlib.import_module("repro.kernels.matmul.ops")
-    real = ops.matmul
-
-    def matmul(a, b, *, block_m, block_n, block_k, **kw):
-        calls[f"blocks_{block_m}x{block_n}x{block_k}"] += 1
-        return real(a, b, block_m=block_m, block_n=block_n, block_k=block_k, **kw)
-
-    monkeypatch.setattr(ops, "matmul", matmul)
-    monkeypatch.setattr(variants, "_xla_dot", _counting(calls, "xla_dot", variants._xla_dot))
-    site = variants.matmul_blocks_site(m=256, k=256, n=256,
-                                       blocks=((128, 128, 128), (256, 256, 256)))
-    return lambda warmup: site.workloads(seed=1, warmup=warmup)
-
-
-def _attention_layer(monkeypatch, calls, request):
-    """A sliding layer of the tiny attention model through its family."""
-    request.getfixturevalue("tiny_attention_model")
-    from repro.autotune import variants
-    from repro.core.family import get_family
-
-    real = variants._attention_program
-    monkeypatch.setattr(variants, "_attention_program", lambda name, fn, **static: (
-        _counting(calls, name, real(name, fn, **static))))
-    site = get_family("kernel_variants").variant_site({
-        "site": "attention", "config": "tiny-attention", "layer": "sliding",
-        "size": 512, "seed": 1})
-    return lambda warmup: site.workloads(seed=1, warmup=warmup)
-
-
 class _CountingCache:
     """A program cache whose programs count their calls under their key."""
 
@@ -66,46 +33,69 @@ class _CountingCache:
         return _counting(self.calls, key, self.cache.get(key, build))
 
 
-def _gram(monkeypatch, calls, request):
-    from repro.expressions import generalized
+def _matmul_blocks(request):
+    """The matmul site, interpreted on the CPU: one program per tiling and
+    XLA's dot."""
+    from repro.autotune.variants import matmul_blocks_site
 
-    monkeypatch.setattr(generalized, "_PROGRAMS",
-                        _CountingCache(generalized._PROGRAMS, calls))
-    family = generalized.FAMILIES["gram"](n=32)
-    return lambda warmup: family.workloads(32, seed=1, warmup=warmup)
-
-
-def _chain(monkeypatch, calls, request):
-    from repro.expressions import algorithms
-    from repro.expressions.instances import random_instance
-
-    real = algorithms.chain_program
-    monkeypatch.setattr(algorithms, "chain_program",
-                        lambda alg: _counting(calls, alg.name, real(alg)))
-    chain = random_instance(4, 8, 24, seed=3)
-    mats = algorithms.make_chain_inputs(chain.dims, seed=3)
-    algs = chain.algorithms()
-    return lambda warmup: algorithms.build_workloads(algs, mats, warmup=warmup)
+    return matmul_blocks_site(m=256, k=256, n=256, blocks=((128, 128, 128), (256, 256, 256)))
 
 
-TABLE_BUILDERS = {"matmul_blocks": _matmul_blocks, "attention_layer": _attention_layer,
-                  "gram": _gram, "chain": _chain}
+def _attention_layer(request):
+    """A sliding layer of the tiny attention model through its family."""
+    request.getfixturevalue("tiny_attention_model")
+    from repro.core.family import get_family
+
+    return get_family("kernel_variants").variant_site({
+        "site": "attention", "config": "tiny-attention", "layer": "sliding",
+        "size": 512, "seed": 1})
 
 
-@pytest.mark.parametrize("builder", sorted(TABLE_BUILDERS))
+def _gram(request):
+    from repro.expressions.generalized import FAMILIES
+
+    return FAMILIES["gram"](n=32)
+
+
+def _chain(request):
+    from repro.core.family import get_family
+
+    return get_family("chain").variant_site({"n_matrices": 4, "lo": 8, "hi": 24, "seed": 3})
+
+
+def _moe_dispatch(request):
+    from repro.autotune.variants import moe_dispatch_site
+
+    return moe_dispatch_site(tokens=64, d=32, e=4, top_k=2, d_ff=16)
+
+
+def _ssd_chunk(request):
+    from repro.autotune.variants import ssd_chunk_site
+
+    return ssd_chunk_site(b=1, s=64, h=2, p=8, n=8, chunks=(16, 32))
+
+
+SITES = {"matmul_blocks": _matmul_blocks, "attention_layer": _attention_layer,
+         "gram": _gram, "chain": _chain, "moe_dispatch": _moe_dispatch,
+         "ssd_chunk": _ssd_chunk}
+
+
+@pytest.mark.parametrize("builder", sorted(SITES))
 def test_a_table_builder_warms_each_program_once(builder, monkeypatch, request):
     calls = collections.Counter()
-    workloads = TABLE_BUILDERS[builder](monkeypatch, calls, request)
+    monkeypatch.setattr(programs, "PROGRAMS", _CountingCache(programs.PROGRAMS, calls))
+    site = SITES[builder](request)
 
     with collect({}) as t:
-        table = workloads(True)
+        table = site.workloads(seed=1)
     assert len(table) >= 2
     assert list(calls.values()) == [1] * len(table), dict(calls)
     assert t["warm_calls"] == len(table)
 
     calls.clear()
+    inputs = site.make_inputs(1)
     with collect({}) as t:
-        cold = workloads(False)
+        cold = {v.name: v.build(*inputs) for v in site.variants}
     assert not calls and "warm_calls" not in t
     for thunk in cold.values():
         thunk()
