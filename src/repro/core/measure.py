@@ -23,9 +23,11 @@ import dataclasses
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
+from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.core.spans import count
 
 
 def rng_state(rng: np.random.Generator) -> Dict[str, Any]:
@@ -204,6 +206,12 @@ class WallClockTimer(Timer):
     clears the floor (capped at :data:`MAX_INNER_REPEATS`). The chosen
     counts are surfaced via :attr:`inner_repeats` so records can carry
     them. ``min_time_s=0`` disables the guard (every ``r`` is 1).
+
+    The calibration call is timed as a sample is (clock, one call, clock).
+    Where it settles on ``r == 1`` it is kept as the workload's first
+    sample, so each workload is called once, untimed, by its warm run and
+    then only for samples. Where ``r > 1`` it is discarded: a single-call
+    time is not mixed with the mean-of-``r`` samples.
     """
 
     #: Post-call block must exceed BOTH the timed call and this floor
@@ -269,11 +277,11 @@ class WallClockTimer(Timer):
     def measure(self, name: str) -> float:
         return self.measure_many(name, 1)[0]
 
-    def _calibrate(self, name: str, fn: Callable[[], object]) -> int:
+    def _calibrate(self, name: str, fn: Callable[[], object]) -> Tuple[int, float]:
         """First-touch calibration: one timed call (doubling as the
-        blocking-contract check) decides the inner-repeat count. The
-        calibration sample is discarded — a sub-floor single-call sample
-        must not be mixed in with the mean-of-``r`` samples it mandates."""
+        blocking-contract check) decides the inner-repeat count ``r``.
+        Returns ``r`` and the call's time, which :meth:`measure_many` keeps
+        as the first sample when ``r == 1`` and discards otherwise."""
         if self._check_blocking and name not in self._blocking_checked:
             self._blocking_checked.add(name)
             t = self._checked_first_measure(name, fn)
@@ -286,20 +294,25 @@ class WallClockTimer(Timer):
             r = min(self.MAX_INNER_REPEATS,
                     max(1, math.ceil(self._min_time_s / max(t, 1e-9))))
         self._inner_repeats[name] = int(r)
-        return int(r)
+        return int(r), t
 
     def measure_many(self, name: str, m: int) -> List[float]:
         """Batched sampling: one workload lookup (and one calibration /
         blocking-contract check, ever) per workload — the per-sample loop
         is just clock/call/clock, or clock/r-calls/clock divided by ``r``
-        for workloads under the minimum-measurable floor."""
+        for workloads under the minimum-measurable floor. A calibration
+        call that settles on ``r == 1`` is the batch's first sample, counted
+        as ``calibration_samples_kept`` in the active span sink."""
         fn = self._workloads[name]
         out: List[float] = []
         if m <= 0:
             return out
         r = self._inner_repeats.get(name)
         if r is None:
-            r = self._calibrate(name, fn)
+            r, t = self._calibrate(name, fn)
+            if r == 1:
+                out.append(t)
+                count("calibration_samples_kept")
         perf = time.perf_counter
         if r == 1:
             while len(out) < m:

@@ -1017,14 +1017,17 @@ def run_chunked_campaign(
     ``predict_s`` (``campaign.predict``), plus what the sessions' own spans
     add inside them: ``warmup_s`` (``session.warmup``: inputs, jit and the
     one warm call per algorithm of a wall-clock timer's workloads) and
-    ``first_s`` (``session.first``: the one timed call per algorithm and the
+    ``first_s`` (``session.first``: the one timed call per algorithm, the
+    timer's calibration call where that settles on ``r == 1``, and the
     candidate filter) inside ``build_s``; ``sample_s`` (``session.sample``:
     the timer's samples) and ``analyse_s`` (``session.analyse``: Procedure
     2-3) inside ``step_s``. Also ``steps`` / ``records`` counts, the
     ``programs_built`` / ``programs_reused`` counts of the program cache
     and ``warm_calls``, one per algorithm warmed
-    (:mod:`repro.core.programs`). Pure observability — nothing here
-    feeds back into measurements or records.
+    (:mod:`repro.core.programs`), and ``calibration_samples_kept``, one per
+    algorithm whose calibration call was kept as its first sample
+    (:class:`repro.core.measure.WallClockTimer`). Pure observability —
+    nothing here feeds back into measurements or records.
 
     ``faults`` is the chaos hook: the ``campaign.step`` injection site is
     poked once per engine step (sigkill / stall ops — see

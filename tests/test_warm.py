@@ -102,10 +102,11 @@ def test_a_table_builder_warms_each_program_once(builder, monkeypatch, request):
     assert list(calls.values()) == [1] * len(cold), dict(calls)
 
 
-def test_a_sweep_session_runs_each_thunk_three_times_before_its_first_step(monkeypatch):
-    """Before the first Procedure-4 step each thunk has run three times:
-    the table builder's warm run, the timer's calibration call and the
-    first measurement."""
+def test_a_sweep_session_runs_each_thunk_twice_before_its_first_step(monkeypatch):
+    """Before the first Procedure-4 step each thunk has run twice: the
+    table builder's warm run, then the timer's calibration call, which is
+    kept as the first measurement because it settles on one call per
+    sample."""
     from repro.core.family import get_family
     from repro.core.measure import WallClockTimer
     from repro.core.sweep import SweepSpec, build_sweep_session
@@ -134,4 +135,5 @@ def test_a_sweep_session_runs_each_thunk_three_times_before_its_first_step(monke
     names = set(session.meta["flops"])
     assert len(names) >= 2 and t["warm_calls"] == len(names)
     assert session.timer.inner_repeats == {name: 1 for name in names}
-    assert calls == {name: 3 for name in names}
+    assert calls == {name: 2 for name in names}
+    assert t["calibration_samples_kept"] == len(names)
