@@ -31,6 +31,7 @@ _EXPORTS = {
     "attention_site": "variants",
     "matmul_blocks_site": "variants",
     "moe_dispatch_site": "variants",
+    "moe_layer_site": "variants",
     "ssd_chunk_site": "variants",
 }
 
@@ -71,5 +72,6 @@ if TYPE_CHECKING:  # pragma: no cover - static analysis only
         attention_site,
         matmul_blocks_site,
         moe_dispatch_site,
+        moe_layer_site,
         ssd_chunk_site,
     )

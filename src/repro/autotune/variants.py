@@ -18,6 +18,11 @@ FLOP count, so the FLOPs-discriminant test applies directly:
 * ``moe_dispatch``       — gather vs dense: identical outputs, dense costs
   ~E/top_k x the FLOPs but has no scatter/gather — FLOPs *should*
   discriminate; when it doesn't, that's a textbook anomaly.
+* ``moe_layer``          — one MoE sublayer of a model at its widths, on a
+  host-routed skewed routing: dense, a capacity gather sized so that
+  nothing drops, ``ragged_dot`` over the sorted assignments, and the Pallas
+  grouped matmul at three m tiles, each with its own executed FLOPs (every
+  token x expert, the padded slots, the assignments, the visited tiles).
 * ``ssd_chunk``          — Mamba-2 chunk length: equal leading-order FLOPs.
 * ``matmul_blocks``      — Pallas GEMM tile shapes: equal FLOPs exactly;
   native on a TPU, interpreted elsewhere (:func:`pallas_interpret`).
@@ -29,13 +34,15 @@ keyed by its static values, so a second instance of a site builds nothing.
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.programs import Variant, VariantSite, program, runner
-from repro.core.spans import count
+from repro.core.spans import count, span
 
 
 def pallas_interpret(interpret: Optional[bool] = None) -> bool:
@@ -285,6 +292,228 @@ def moe_dispatch_site(
             Variant("gather", f_gather, build("gather", moe_gather)),
             Variant("dense", f_dense, build("dense", moe_dense)),
         ),
+        make_inputs=inputs,
+    )
+
+
+# ------------------------------------------------------- MoE layer site ----
+
+#: m tiles of the ``gmm_<tm>`` variants (the megablox grouped matmul)
+GMM_TM = (128, 256, 512)
+#: (k tile, n tile) of every ``gmm_<tm>`` variant, capped at the GEMM's dims
+GMM_TK_TN = (512, 512)
+#: tokens per step of the ``dense`` variant: at 128 experts of width 1024
+#: over d 2048, ~1 GB of float32 intermediates per step
+DENSE_BLOCK = 512
+#: the per-layer expert bias b = MOE_BIAS_SCALE * z (z standard normal),
+#: added to standard-normal router logits' sigmoid scores for selection:
+#: the routing skew of a sequence whose topic loads a few experts
+MOE_BIAS_SCALE = 0.05
+#: the seed of a model's MoE weights and expert biases, with the layer index
+MOE_WEIGHT_SEED = 0
+
+
+def moe_algorithms() -> List[str]:
+    """The MoE layer's algorithms, every one exact (no token dropped)."""
+    return ["dense", "gather_capacity", "ragged_dot"] + [f"gmm_{tm}" for tm in GMM_TM]
+
+
+@dataclass(frozen=True)
+class MoeRouting:
+    """One instance's routing: expert ids [T, k] int32 and weights [T, k]
+    float32 per token, and the rows per expert [E]."""
+
+    ids: np.ndarray
+    weights: np.ndarray
+    loads: np.ndarray
+
+    @property
+    def capacity(self) -> int:
+        """The ``gather_capacity`` slots per expert: the smallest power of
+        two at or above the hottest expert's load, so nothing drops."""
+        return 1 << max(int(self.loads.max()) - 1, 0).bit_length()
+
+
+def sigmoid_topk(logits: np.ndarray, bias: np.ndarray, k: int, route_scale: float,
+                 norm_topk: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+    """The published router (DeepSeek-V3's, AFMoE's) on the host, in
+    float64: scores sigmoid(logits) [T, E]; the top ``k`` of scores + bias,
+    ties to the lower index; the weights the chosen scores, over their sum
+    + 1e-20 where ``norm_topk``, times ``route_scale`` (the bias never enters
+    them). Returns (ids [T, k] int32, weights [T, k] float32)."""
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    ids = np.argsort(-(scores + bias), axis=1, kind="stable")[:, :k]
+    chosen = np.take_along_axis(scores, ids, axis=1)
+    if norm_topk:
+        chosen = chosen / (chosen.sum(axis=1, keepdims=True) + 1e-20)
+    return ids.astype(np.int32), (chosen * route_scale).astype(np.float32)
+
+
+@functools.lru_cache(maxsize=8)
+def moe_routing(t: int, e: int, k: int, seed: int, layer: int, bias_scale: float,
+                route_scale: float, norm_topk: bool) -> MoeRouting:
+    """One instance's routing by :func:`sigmoid_topk`, on logits drawn in
+    place of x W_r: standard normal [T, E] from ``seed``; the layer's expert
+    bias ``bias_scale * z``, z standard normal [E] from
+    (:data:`MOE_WEIGHT_SEED`, ``layer``). Once per instance (kept), in the
+    span ``moe.route``; counts ``moe_assignments`` (T k) and
+    ``moe_peak_load`` (the hottest expert's rows)."""
+    with span("moe.route", "route_s"):
+        logits = np.random.default_rng(seed).standard_normal((t, e))
+        bias = bias_scale * np.random.default_rng([MOE_WEIGHT_SEED, layer]).standard_normal(e)
+        ids, weights = sigmoid_topk(logits, bias, k, route_scale, norm_topk)
+        routing = MoeRouting(ids, weights, np.bincount(ids.ravel(), minlength=e))
+    count("moe_assignments", t * k)
+    count("moe_peak_load", int(routing.loads.max()))
+    return routing
+
+
+def moe_executed_rows(name: str, t: int, e: int, k: int, routing: MoeRouting) -> int:
+    """Rows one variant runs through each routed expert GEMM: every token
+    through every expert (``dense``), every expert's padded slots
+    (``gather_capacity``), the assignments (``ragged_dot``: the math
+    count, whatever XLA executes), and the m tiles the kernel visits times
+    its m tile (``gmm_<tm>``, counted as ``make_group_metadata`` counts)."""
+    from repro.kernels.gmm.gmm import tiles_visited
+
+    if name == "dense":
+        return e * t
+    if name == "gather_capacity":
+        return e * routing.capacity
+    if name == "ragged_dot":
+        return t * k
+    if name.startswith("gmm_"):
+        tm = int(name[len("gmm_"):])
+        return tiles_visited(routing.loads, tm) * tm
+    raise ValueError(f"unknown MoE variant {name!r}")
+
+
+def moe_rows_moved(name: str, t: int, e: int, k: int, routing: MoeRouting) -> int:
+    """Rows a variant gathers into expert order, and again back to token
+    order in the combine: none for ``dense``."""
+    if name == "dense":
+        return 0
+    return e * routing.capacity if name == "gather_capacity" else t * k
+
+
+def check_moe_size(t: int, k: int) -> None:
+    """Raise unless the assignments fill whole m tiles of every gmm variant
+    and the dense variant's blocks."""
+    if (t * k) % max(GMM_TM) or t % min(DENSE_BLOCK, t):
+        raise ValueError(f"MoE size {t} x top-{k} is not a multiple of the gmm m tile "
+                         f"{max(GMM_TM)} and the dense block {DENSE_BLOCK}")
+
+
+def _expert_weights(key, d: int, f: int):
+    """One expert's (wg, wi [d, f], wo [f, d]) in bfloat16: normal float32
+    scaled by 1/sqrt(fan-in), from three keys split from ``key``."""
+    kg, ki, ko = jax.random.split(key, 3)
+    scale = ((d, f, d), (d, f, d), (f, d, f))
+    return tuple((jax.random.normal(kk, (a, b), jnp.float32) / np.sqrt(fan)).astype(jnp.bfloat16)
+                 for kk, (a, b, fan) in zip((kg, ki, ko), scale))
+
+
+@functools.lru_cache(maxsize=4)
+def moe_layer_weights(layer: int, d: int, e: int, f: int, shared_f: int):
+    """One MoE layer's weights on the device, made once per process and held
+    (four layers, as a pipeline stage holds its layers): routed experts
+    ``wg``/``wi`` [E, d, f], ``wo`` [E, f, d] and the shared expert [d, f_s]
+    / [f_s, d] in bfloat16. Expert ``i`` draws from
+    fold_in(fold_in(PRNGKey(MOE_WEIGHT_SEED), layer), i), the shared expert
+    as expert ``E``."""
+
+    def make(layer):
+        key = jax.random.fold_in(jax.random.PRNGKey(MOE_WEIGHT_SEED), layer)
+        wg, wi, wo = jax.lax.map(
+            lambda i: _expert_weights(jax.random.fold_in(key, i), d, f), jnp.arange(e))
+        sg, si, so = _expert_weights(jax.random.fold_in(key, e), d, shared_f)
+        return {"wg": wg, "wi": wi, "wo": wo}, {"wg": sg, "wi": si, "wo": so}
+
+    return program("moe_layer_weights", make, d, e, f, shared_f)(layer)
+
+
+def moe_flops(name: str, *, t: int, d: int, e: int, k: int, f: int, shared_f: int,
+              routing: MoeRouting) -> float:
+    """Executed FLOPs of one call: 6 d f per executed row of the routed
+    experts (three GEMMs of 2 d f), and 6 d f_s per token of the shared
+    expert (the paper counts the GEMMs' multiply-adds; silu, the products
+    and the combine's adds are not counted)."""
+    return 6.0 * d * f * moe_executed_rows(name, t, e, k, routing) + 6.0 * d * shared_f * t
+
+
+def moe_layer_site(
+    t: int, d: int, e: int, k: int, f: int, shared_f: int, layer: int, seed: int,
+    route_scale: float, norm_topk: bool = True, bias_scale: float = MOE_BIAS_SCALE,
+    interpret: Optional[bool] = None,
+) -> VariantSite:
+    """One MoE sublayer at a model's widths, for the instance ``seed`` (the
+    routing, and so the FLOP table, is the seed's): T tokens x [T, d] in
+    bfloat16 (standard normal, from the seed), routed to ``k`` of ``e`` experts of
+    width ``f`` by the host router (:func:`moe_routing`) plus one ungated
+    shared expert of width ``shared_f``; the layer's weights from
+    :func:`moe_layer_weights`. Each variant takes (weights, x, ids, routing
+    weights), returns [T, d] float32 under the precision contract of
+    :mod:`repro.models.moe`, drops no token, and carries its executed FLOPs
+    (6 d f per executed row, :func:`moe_executed_rows`, plus the shared
+    expert's 6 d f_s per token). The router GEMM x W_r is not timed. A gmm
+    variant's build counts ``gmm_assigned_rows`` (T k) and
+    ``gmm_executed_rows`` into the active span sink."""
+    from repro.kernels.gmm.gmm import gmm
+    from repro.models.moe import (experts_capacity, experts_dense, experts_grouped,
+                                  ragged_matmul, shared_expert)
+
+    check_moe_size(t, k)
+    interpret = pallas_interpret(interpret)
+    route = functools.partial(moe_routing, t, e, k, layer=layer, bias_scale=bias_scale,
+                              route_scale=route_scale, norm_topk=norm_topk)
+
+    def inputs(seed: int):
+        routing = route(seed)
+        x = jax.random.normal(jax.random.PRNGKey(seed), (t, d), jnp.float32).astype(jnp.bfloat16)
+        return [x, jnp.asarray(routing.ids), jnp.asarray(routing.weights), routing]
+
+    def gmm_matmul(tm):
+        def matmul(lhs, rhs, sizes):
+            kk, n = rhs.shape[1:]
+            tiling = (tm, min(GMM_TK_TN[0], kk), min(GMM_TK_TN[1], n))
+            return gmm(lhs, rhs, sizes, tiling=tiling, interpret=interpret)
+
+        return matmul
+
+    def layer_fn(experts):
+        def fn(w, x, ids, wts):
+            routed, shared = w
+            return experts(x, ids, wts, routed) + shared_expert(x, shared)
+
+        return fn
+
+    def build(name):
+        def make(x, ids, wts, routing):
+            if name == "dense":
+                fn, key = layer_fn(functools.partial(experts_dense, block=DENSE_BLOCK)), ()
+            elif name == "gather_capacity":
+                c = routing.capacity
+                fn, key = layer_fn(functools.partial(experts_capacity, capacity=c)), (c,)
+            elif name == "ragged_dot":
+                fn, key = layer_fn(functools.partial(experts_grouped, matmul=ragged_matmul)), ()
+            else:
+                tm = int(name[len("gmm_"):])
+                fn = layer_fn(functools.partial(experts_grouped, matmul=gmm_matmul(tm)))
+                key = (interpret,)
+                count("gmm_assigned_rows", t * k)
+                count("gmm_executed_rows", moe_executed_rows(name, t, e, k, routing))
+            weights = moe_layer_weights(layer, d, e, f, shared_f)
+            return runner(program(f"moe_{name}", fn, *key), weights, x, ids, wts)
+
+        return make
+
+    routing = route(seed)
+    return VariantSite(
+        name=f"moe[T{t} d{d} E{e} k{k} f{f} shared{shared_f} layer{layer} seed{seed}]",
+        variants=tuple(
+            Variant(name, moe_flops(name, t=t, d=d, e=e, k=k, f=f, shared_f=shared_f,
+                                    routing=routing), build(name))
+            for name in moe_algorithms()),
         make_inputs=inputs,
     )
 

@@ -3,11 +3,21 @@ d_ff=6144 vocab=200192, MoE 128e top-8 (expert hidden 1024), 1 shared
 expert; sliding-window attention (2048) with global attention at every 4th
 layer: 24 sliding + 8 full. [hf:arcee-ai/Trinity-Mini config.json]
 
+The MoE sublayer is the published AFMoE one (DeepSeek-V3's router and
+combine under Trinity's key names): sigmoid scores per expert
+(``score_func``), the top 8 chosen on the scores plus a per-layer expert
+bias that never enters the weights (aux-loss-free balancing; ``n_group`` 1
+limits nothing), the chosen scores renormalised (``route_norm``) and scaled
+by ``route_scale`` 2.826, plus one shared expert of width 1024 added with no
+gate. No token is dropped (``moe_drop_tokens`` False): the model's
+``gather`` dispatch sorts the assignments by expert and runs grouped GEMMs
+over exactly the active rows.
+
 Notes: the published stack starts with 2 dense layers
-(``num_dense_layers``) and routes by sigmoid scores (``score_func``,
-``route_scale`` 2.826); this config's MoE sublayers are the repo's softmax
-top-k MoE on every layer. The attention fields are as published, which is
-what the ``kernel_variants`` attention site reads.
+(``num_dense_layers`` 2); this config's stack has an MoE sublayer in every
+layer, as the model stack has no leading dense layers. The attention and
+MoE fields are as published, which is what the ``kernel_variants``
+attention and moe sites read.
 """
 
 from repro.models import ModelConfig
@@ -27,6 +37,11 @@ FULL = ModelConfig(
     n_shared_experts=1,
     moe_d_ff=1024,
     shared_d_ff=1024,
+    moe_norm_topk=True,
+    moe_score_func="sigmoid",
+    moe_route_scale=2.826,
+    moe_shared_gate=False,
+    moe_drop_tokens=False,
     sliding_window=2048,
     global_attn_every_n_layers=4,
     rope_theta=10000.0,

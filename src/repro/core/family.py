@@ -284,8 +284,11 @@ class GeneralizedFamily(AlgorithmFamily):
 
 # ------------------------------------------------- kernel_variants family ---
 
-#: sites the family can census, in CLI order
-KERNEL_SITES = ("matmul", "attention", "ssd")
+#: sites the family can census, in CLI order; ``moe`` runs a named model
+#: config's MoE layers only
+KERNEL_SITES = ("matmul", "attention", "ssd", "moe")
+#: the sites of a grid that names none: those with toy instances
+TOY_KERNEL_SITES = ("matmul", "attention", "ssd")
 
 
 def _kernel_site_config(site: str, size: int) -> Dict[str, Any]:
@@ -418,11 +421,85 @@ def _attention_layer_config(params: Mapping[str, Any]) -> Dict[str, Any]:
     }
 
 
+def moe_layers(config: str) -> List[int]:
+    """Indices of a registered model config's layers that hold an MoE FFN."""
+    from repro.configs import get_config
+    from repro.models.config import FFNKind
+
+    model = get_config(config)
+    unit = model.pattern_unit()
+    return [i for i in range(model.n_layers) if unit[i % len(unit)].ffn is FFNKind.MOE]
+
+
+#: instance params that restate a model's MoE widths and routing
+_MOE_PARAMS = ("d_model", "experts", "top_k", "expert_d_ff", "shared_d_ff", "route_scale")
+
+
+def _moe_widths(config: str) -> Dict[str, Any]:
+    """A registered model config's MoE widths and route scale, by the names
+    of :data:`_MOE_PARAMS`; raises unless its MoE is the layer the moe site
+    runs."""
+    from repro.configs import get_config
+
+    model = get_config(config)
+    if (model.n_experts == 0 or model.n_shared_experts != 1 or model.moe_shared_gate
+            or model.moe_score_func != "sigmoid"):
+        raise ValueError(f"{config}'s MoE is not a sigmoid-routed layer with one ungated "
+                         "shared expert, the layer the moe site runs")
+    return {"d_model": model.d_model, "experts": model.n_experts, "top_k": model.top_k,
+            "expert_d_ff": model.resolved_moe_d_ff, "shared_d_ff": model.resolved_shared_d_ff,
+            "route_scale": model.moe_route_scale}
+
+
+def _moe_layer_config(params: Mapping[str, Any]) -> Dict[str, Any]:
+    """Metadata of one MoE sublayer of a registered model config at its
+    published widths (params ``config``, ``layer_index``, ``size`` = tokens,
+    ``seed``, ``bias_scale`` the routing skew): algorithm names, each
+    variant's kernels (executed rows by the instance's own routing,
+    :func:`repro.autotune.variants.moe_executed_rows`), the shapes
+    (``dims``), and the site with its arguments. The routing is computed
+    on the host once per instance. Params that restate a width must agree
+    with the config."""
+    from repro.autotune.variants import (MOE_BIAS_SCALE, check_moe_size, moe_algorithms,
+                                         moe_executed_rows, moe_routing, moe_rows_moved)
+    from repro.configs import get_config
+    from repro.explain.decompose import decompose_moe
+
+    config, layer, t = str(params["config"]), int(params["layer_index"]), int(params["size"])
+    widths = _moe_widths(config)
+    model = get_config(config)
+    layers = moe_layers(config)
+    if layer not in layers:
+        raise ValueError(f"{config}'s layer {layer} has no MoE FFN; one of {layers}")
+    stated = {k: params[k] for k in _MOE_PARAMS if k in params}
+    if any(stated[k] != widths[k] for k in stated):
+        raise ValueError(f"instance widths {stated} disagree with {config}'s {widths}")
+    d, e, k, f, sf = (widths[n] for n in _MOE_PARAMS[:5])
+    check_moe_size(t, k)
+    bias_scale = float(params.get("bias_scale", MOE_BIAS_SCALE))
+    routing = moe_routing(t, e, k, int(params["seed"]), layer=layer, bias_scale=bias_scale,
+                          route_scale=model.moe_route_scale, norm_topk=model.moe_norm_topk)
+    names = moe_algorithms()
+    return {
+        "names": names,
+        "kernels": {n: decompose_moe(t, d, f, sf, e, moe_executed_rows(n, t, e, k, routing),
+                                     moe_rows_moved(n, t, e, k, routing))
+                    for n in names},
+        "dims": {"t": t, **widths, "layer_index": layer, "bias_scale": bias_scale},
+        "site": "moe_layer_site",
+        "site_kwargs": {"t": t, "d": d, "e": e, "k": k, "f": f, "shared_f": sf, "layer": layer,
+                        "seed": int(params["seed"]), "route_scale": model.moe_route_scale,
+                        "norm_topk": model.moe_norm_topk, "bias_scale": bias_scale},
+    }
+
+
 def _instance_site_config(params: Mapping[str, Any]) -> Dict[str, Any]:
     """The site metadata of one instance row, with ``kernels`` per variant."""
     site = str(params["site"])
     if site == "attention" and "config" in params:
         return _attention_layer_config(params)
+    if site == "moe":
+        return _moe_layer_config(params)
     cfg = _kernel_site_config(site, int(params["size"]))
     return {**cfg, "kernels": {n: list(cfg["kernels"]) for n in cfg["names"]}, "dims": None}
 
@@ -456,8 +533,10 @@ class KernelVariantsFamily(AlgorithmFamily):
     def expand_grid(self, grid: Mapping[str, Any]) -> List[InstanceSpec]:
         """``sites`` x ``sizes`` x ``per_size`` seeds; with a ``config`` (a
         registered model config) the attention site runs that model's
-        layers instead, one instance per attention layer kind and seed."""
-        sites = [str(x) for x in grid.get("sites", KERNEL_SITES)]
+        layers instead, one instance per attention layer kind and seed, and
+        the moe site its MoE layers, one instance per layer index and seed
+        (the moe site has no toy instances)."""
+        sites = [str(x) for x in grid.get("sites", TOY_KERNEL_SITES)]
         sizes = [int(s) for s in grid.get("sizes", ())]
         per_size = int(grid.get("per_size", 1))
         config = grid.get("config")
@@ -469,6 +548,11 @@ class KernelVariantsFamily(AlgorithmFamily):
                 )
             if site == "attention" and config:
                 out.extend(self._layer_instances(str(config), sizes, per_size))
+                continue
+            if site == "moe":
+                if not config:
+                    raise ValueError("the moe site runs a model's MoE layers: name a config")
+                out.extend(self._moe_instances(str(config), sizes, per_size))
                 continue
             for size in sizes:
                 _kernel_site_config(site, size)  # validate shape constraints
@@ -499,10 +583,29 @@ class KernelVariantsFamily(AlgorithmFamily):
                     ))
         return out
 
+    def _moe_instances(self, config: str, sizes: Sequence[int],
+                       per_size: int) -> List[InstanceSpec]:
+        from repro.autotune.variants import check_moe_size
+
+        widths = _moe_widths(config)
+        out: List[InstanceSpec] = []
+        for size in sizes:
+            check_moe_size(int(size), widths["top_k"])
+            for layer in moe_layers(config):
+                for s in range(per_size):
+                    params = {"site": "moe", "config": config, "layer": "moe",
+                              "layer_index": layer, "size": int(size), "seed": s, **widths}
+                    out.append(InstanceSpec(
+                        index=0,
+                        uid=f"kernel_variants-moe-{config}-l{layer:02d}-n{size}-s{s:03d}",
+                        family=self.name, params=params,
+                    ))
+        return out
+
     def grid_from_args(self, args: Any) -> Optional[Dict[str, Any]]:
         sites = [s for s in getattr(args, "kernel_sites", "").split(",") if s]
         grid = {
-            "sites": sites or list(KERNEL_SITES),
+            "sites": sites or list(TOY_KERNEL_SITES),
             "sizes": args.sizes,
             "per_size": args.per_size,
         }

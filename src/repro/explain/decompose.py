@@ -55,6 +55,14 @@ _OPS: Dict[str, Tuple[Callable[..., float], Callable[..., float]]] = {
     # (n,): one triangular solve with a vector RHS
     "trsv": (lambda n: 1.0 * n * n,
              lambda n: float(_ELEM_BYTES) * (n * n + 2 * n)),
+    # (g, m, k, n): C[m,n] = A[m,k] @ B[group(row),k,n], the rows of A in g
+    # runs, each run times its own B (the grouped GEMM of a mixture of experts)
+    "ggemm": (lambda g, m, k, n: 2.0 * m * k * n,
+              lambda g, m, k, n: float(_ELEM_BYTES) * (m * k + g * k * n + m * n)),
+    # (m, n): m rows of n elements gathered (or scattered) by index: moved
+    # bytes, no FLOPs
+    "gather": (lambda m, n: 0.0,
+               lambda m, n: float(_ELEM_BYTES) * 2 * m * n),
 }
 
 #: op -> result elements (the intermediate a following kernel may reuse).
@@ -68,6 +76,8 @@ _OUT_ELEMS: Dict[str, Callable[..., float]] = {
     "getrf": lambda n: float(n * n),
     "potrf": lambda n: float(n * n),
     "trsv": lambda n: float(n),
+    "ggemm": lambda g, m, k, n: float(m * n),
+    "gather": lambda m, n: float(m * n),
 }
 
 
@@ -201,6 +211,24 @@ def decompose_attention(b: int, h: int, d: int, rows: int, cols: int) -> List[Ke
             KernelSpec("gemm", (b * h * rows, cols, d))]   # output  P @ V
 
 
+def decompose_moe(t: int, d: int, f: int, shared_f: int, experts: int, rows: int,
+                  moved: int) -> List[KernelSpec]:
+    """Kernels of one MoE layer variant over ``t`` tokens of width ``d``
+    (the site's counting rule, :func:`repro.autotune.variants.moe_executed_rows`):
+    the ``rows`` it runs through the routed experts' gate, up and down GEMMs
+    (grouped over ``experts``), framed by the gathers of ``moved`` rows into
+    expert order and back (none for ``dense``), then the shared expert's
+    three GEMMs over every token. silu(g) * h and the weighted combine are
+    not kernels here, as they carry no FLOPs in the paper's accounting."""
+    routed = [KernelSpec("ggemm", (experts, rows, d, f)),      # gate  x Wg
+              KernelSpec("ggemm", (experts, rows, d, f)),      # up    x Wi
+              KernelSpec("ggemm", (experts, rows, f, d))]      # down  a Wo
+    if moved:
+        routed = [KernelSpec("gather", (moved, d)), *routed, KernelSpec("gather", (moved, d))]
+    return routed + [KernelSpec("gemm", (t, d, shared_f)), KernelSpec("gemm", (t, d, shared_f)),
+                     KernelSpec("gemm", (t, shared_f, d))]
+
+
 def decompose_chain_dims(dims: Sequence[int]) -> Dict[str, List[KernelSpec]]:
     """Kernels of EVERY algorithm of a chain instance (lazy import: the
     enumeration layer is pure python). Memoized per dims tuple — an
@@ -311,6 +339,16 @@ def build_kernel_workload(kernel: KernelSpec, seed: int = 0) -> Callable[[], Any
         m, n = shape
         args = [normal(keys[0], (m, n)), normal(keys[1], (m, n))]
         fn = lambda a, b: a + b
+    elif op == "ggemm":
+        g, m, k, n = shape
+        sizes = np.full(g, m // g, np.int32)
+        sizes[-1] += m - int(sizes.sum())
+        args = [normal(keys[0], (m, k)), normal(keys[1], (g, k, n)), jnp.asarray(sizes)]
+        fn = jax.lax.ragged_dot
+    elif op == "gather":
+        m, n = shape
+        args = [normal(keys[0], (m, n)), jax.random.permutation(keys[1], m)]
+        fn = lambda a, i: a[i]
     elif op in ("inv", "getrf", "potrf", "trsv"):
         (n,) = shape
         a = normal(keys[0], (n, n))

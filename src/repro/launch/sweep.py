@@ -89,12 +89,13 @@ def add_grid_args(p: argparse.ArgumentParser) -> None:
     g.add_argument("--per-size", type=int, default=5,
                    help="seeded instances per (family, size)")
     g.add_argument("--kernel-sites", default="matmul,attention,ssd",
-                   help="kernel_variants sites (comma list); only read when "
-                   "--families includes kernel_variants")
+                   help="kernel_variants sites (comma list of matmul, attention, ssd, "
+                   "moe); only read when --families includes kernel_variants")
     g.add_argument("--kernel-config", default="",
                    help="a model config (e.g. trinity-mini): the attention site "
-                   "runs its attention layer kinds at the published widths, "
-                   "--sizes giving the sequence lengths")
+                   "runs its attention layer kinds and the moe site its MoE layers "
+                   "at the published widths, --sizes giving the sequence lengths "
+                   "(tokens)")
     g.add_argument("--shards", type=int, default=8)
     g.add_argument("--backend", default="cost_model",
                    choices=["cost_model", "simulated", "wall_clock"])

@@ -70,7 +70,17 @@ class ModelConfig:
     moe_layer_period: int = 1            # MoE every k-th sublayer
     moe_layer_offset: int = 0
     moe_norm_topk: bool = True           # renormalise top-k weights
+    # router scores: softmax over the experts, or sigmoid per expert with a
+    # per-expert bias added for selection only (aux-loss-free balancing,
+    # DeepSeek-V3 / AFMoE); the top-k weights are scaled by route_scale
+    moe_score_func: str = "softmax"      # softmax | sigmoid
+    moe_route_scale: float = 1.0
+    moe_shared_gate: bool = True         # qwen2-moe: sigmoid gate on the shared expert
     moe_capacity_factor: float = 1.25    # gather-dispatch capacity factor
+    # GShard capacity: the gather dispatch drops the tokens that overflow an
+    # expert's capacity; False (AFMoE) drops none: it sorts the assignments
+    # by expert and runs grouped GEMMs over exactly the active rows
+    moe_drop_tokens: bool = True
     router_aux_loss_coef: float = 0.001
 
     # -- Mamba-2 (SSD) -------------------------------------------------------

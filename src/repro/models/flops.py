@@ -52,8 +52,12 @@ def _moe_params(cfg: ModelConfig) -> Dict[str, int]:
     routed_each = _mlp_params(cfg, f)
     shared = 0
     if cfg.n_shared_experts > 0:
-        shared = _mlp_params(cfg, cfg.resolved_shared_d_ff) + cfg.d_model
+        shared = _mlp_params(cfg, cfg.resolved_shared_d_ff)
+        if cfg.moe_shared_gate:
+            shared += cfg.d_model
     router = cfg.d_model * cfg.n_experts
+    if cfg.moe_score_func == "sigmoid":
+        router += cfg.n_experts                   # the selection bias
     total = router + cfg.n_experts * routed_each + shared
     active = router + cfg.top_k * routed_each + shared
     return {"total": total, "active": active}

@@ -17,6 +17,7 @@ from repro.core.family import (
     AlgorithmFamily,
     InstanceSpec,
     KERNEL_SITES,
+    TOY_KERNEL_SITES,
     family_names,
     get_family,
     register_family,
@@ -169,8 +170,10 @@ def test_matmul_site_tiles_are_tpu_legal(size, tiles):
 def test_kernel_variants_flop_identical_by_construction():
     """Every variant of an instance carries the same analytic FLOP count
     and the same kernel decomposition (the shared math), so the whole
-    instance sits in S_F and can never be RT-filtered apart."""
-    for site in KERNEL_SITES:
+    instance sits in S_F and can never be RT-filtered apart. (The moe site
+    has no toy instances: it runs a named model's layers.)"""
+    assert set(KERNEL_SITES) - set(TOY_KERNEL_SITES) == {"moe"}
+    for site in TOY_KERNEL_SITES:
         for size in (32, 64):
             inst = _kv_inst(site, size)
             flops, meta, _ = instance_entry(inst)

@@ -117,3 +117,19 @@ def test_chain_workload_compiles_at_4096(chip_shape):
     alg = generate_chain_algorithms(dims)[0]
     mats = [chip_shape((dims[i], dims[i + 1]), jnp.float32) for i in range(4)]
     assert "dot" in _compiled_text(algorithm_fn(alg), *mats)
+
+
+@pytest.mark.parametrize("tm", [128, 256, 512])
+def test_grouped_matmul_compiles_at_trinity_mini_widths(chip_shape, tm):
+    """The moe site's grouped matmul at each m tile over Trinity-Mini's
+    assignments: 8192 tokens x top-8 rows of 2048 against 128 experts'
+    [2048, 1024] weights, bf16; the Pallas call carries its name."""
+    import functools
+
+    from repro.kernels.gmm.gmm import gmm, kernel_name
+
+    lhs = chip_shape((8192 * 8, 2048), jnp.bfloat16)
+    rhs = chip_shape((128, 2048, 1024), jnp.bfloat16)
+    sizes = chip_shape((128,), jnp.int32)
+    text = _compiled_text(functools.partial(gmm, tiling=(tm, 512, 512)), lhs, rhs, sizes)
+    assert "tpu_custom_call" in text and kernel_name(tm, 512, 512) in text
